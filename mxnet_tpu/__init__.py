@@ -16,6 +16,16 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
+# cpu() contexts — the default context among them — live on JAX's host
+# backend, so it has to be loaded beside the accelerator. A platform list
+# that names only the accelerator (JAX_PLATFORMS=tpu on a machine with a
+# chip) gets ",cpu" appended; the accelerator stays first, so it stays the
+# default backend, and a listed platform that cannot start still fails.
+_platforms = _jax.config.jax_platforms
+if _platforms and "cpu" not in _platforms.split(","):
+    _jax.config.update("jax_platforms", _platforms + ",cpu")
+del _platforms
+
 from .base import MXNetError, AttrScope, NameManager, Prefix
 from .context import Context, cpu, gpu, tpu, cpu_pinned, current_context, num_gpus
 

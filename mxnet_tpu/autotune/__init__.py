@@ -265,12 +265,9 @@ def lookup_or_tune(op, key, dtype=None, ctx=None):
     val = cache.lookup(op, key, dtype)
     if val is not None or mode() != 1:
         return val
-    try:
-        from jax.core import trace_state_clean
+    import jax
 
-        if not trace_state_clean():
-            return None
-    except Exception:
+    if not jax.core.trace_ctx.is_top_level():
         return None
     # the guard above proves we are OUTSIDE any jax trace here; resolve
     # the tuner through getattr so the static traced-closure analysis

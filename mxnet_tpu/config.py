@@ -49,9 +49,12 @@ Flags currently honored:
     the T x T score matrix in the backward.
 
 ``MXNET_FLASH_BLOCK_Q`` / ``MXNET_FLASH_BLOCK_K`` (default 1024)
-    Upper bounds for the forward kernel's q/k block sizes (the largest
-    divisor of T at or below the bound is used). Defaults from the
-    round-5 on-chip sweep at T=4096 on v5e.
+    Upper bounds for the forward kernel's q/k block sizes. The tile
+    that runs is the whole sequence when it fits under the bound, else
+    the largest divisor of T that is a multiple of 128 (any divisor in
+    the Pallas interpreter); a T with no such tile lowers the dense
+    formula (a static decline, docs/flash_attention.md). Defaults from
+    the round-5 on-chip sweep at T=4096 on v5e.
 
 ``MXNET_FLASH_BWD_BLOCK_Q`` / ``MXNET_FLASH_BWD_BLOCK_K`` (default 512)
     Same bounds for the backward kernels. The backward holds more live
@@ -233,8 +236,11 @@ Flags currently honored:
     Block-bound defaults of the fused matmul + epilogue Pallas kernels
     (parallel/fused.py): tile upper bounds for the output rows/cols and
     the contraction depth.  A tuned ``fusion.blocks`` cache entry for
-    the shape bucket wins (docs/autotune.md); largest divisors at or
-    below the bounds are what actually run.
+    the shape bucket wins (docs/autotune.md).  What runs is a tile the
+    TPU lowering accepts: the whole dimension when it fits under its
+    bound, else the largest divisor that is a multiple of 8 (rows) /
+    128 (columns, depth); a shape with none declines to the reference
+    composition by a static rule (docs/fusion.md).
 
 ``MXNET_FUSION_KERNEL`` (default 1)
     Lower eligible fused regions through the Pallas kernel family on
@@ -692,6 +698,30 @@ def set_flag(name, value):
 
 def flag_doc():
     return __doc__
+
+
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache for an entry point
+    (``chip_smoke.py``, ``bench.py``, ``bench_all.py`` call this before
+    their first jit) and return the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
+    nothing is set in code — the cache can be placed from outside. Else
+    the cache lives at ``<checkout>/.jax_cache``: a FIXED path, never one
+    built from a temporary name, pid or time, because a directory that
+    moves between runs never hits. The library itself never calls this:
+    importing ``mxnet_tpu`` leaves caching as the process found it (the
+    test suite's compile-count tests depend on that)."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # env-set appliers take effect at import (flag levers that configure

@@ -2,17 +2,33 @@
 
 Mirrors the reference's ``python/mxnet/context.py`` (Context, cpu(), gpu(),
 current_context) but resolves onto JAX devices: ``cpu(i)`` maps to host CPU
-devices; ``gpu(i)`` / ``tpu(i)`` map to the i-th accelerator chip reported by
-``jax.devices()``. On a CPU-only test environment (JAX_PLATFORMS=cpu with
-``--xla_force_host_platform_device_count=N``) accelerator contexts resolve onto
-the virtual CPU devices, which is exactly how the reference's multi-device
+devices; ``gpu(i)`` maps to the i-th accelerator chip reported by
+``jax.local_devices()`` and ``tpu(i)`` to the i-th TPU chip. On a CPU-only
+test environment (JAX_PLATFORMS=cpu with
+``--xla_force_host_platform_device_count=N``) ``gpu(i)`` resolves onto the
+virtual CPU devices, which is exactly how the reference's multi-device
 tests map ctx groups onto cpu(0)/cpu(1) (tests/python/unittest/test_multi_device_exec.py).
+``tpu(i)`` never does: it names the hardware, and raises where there is none,
+so a measurement that asks for the chip cannot run on the host unnoticed.
 """
 from __future__ import annotations
 
 import threading
 
-__all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context", "num_gpus"]
+from .base import MXNetError
+
+__all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
+           "num_gpus", "DEVICE_PEAKS", "device_peaks"]
+
+#: Published per-chip peaks, keyed by jax ``device_kind`` — the ONE table
+#: utilization figures divide by (bench.py, chip_smoke.py). A device that
+#: is not here is an error, never a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,
+        "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e"'},
+}
 
 _thread_state = threading.local()
 
@@ -75,6 +91,16 @@ class Context:
 
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = [d for d in jax.local_devices(backend="cpu")]
+        elif self.device_type == "tpu":
+            devs = [d for d in jax.local_devices() if d.platform == "tpu"]
+            if not devs:
+                raise MXNetError(
+                    "%s: no TPU device — jax found platform %r (%s). "
+                    "mx.tpu() never falls back to the host; use mx.gpu() "
+                    "for the CPU test harness." % (
+                        self, jax.default_backend(),
+                        ", ".join(sorted({d.device_kind
+                                          for d in jax.local_devices()}))))
         else:
             devs = _accelerator_devices()
         if self.device_id >= len(devs):
@@ -88,7 +114,7 @@ class Context:
 def _accelerator_devices():
     """Non-CPU jax devices, falling back to (possibly virtualized) CPU devices.
 
-    The fallback makes gpu()/tpu() contexts usable in the CPU test harness where
+    The fallback makes gpu() contexts usable in the CPU test harness where
     --xla_force_host_platform_device_count provides N virtual devices.
     """
     import jax
@@ -110,8 +136,22 @@ def gpu(device_id=0):
 
 
 def tpu(device_id=0):
-    """Return a TPU context."""
+    """Return a TPU context. Resolves to TPU-platform devices only and
+    raises where jax found none — benchmarks and ``chip_smoke.py`` use it
+    so that a missing chip is an error, not a slow run on the host."""
     return Context("tpu", device_id)
+
+
+def device_peaks(device_kind):
+    """The :data:`DEVICE_PEAKS` row for ``device_kind``; unknown kinds
+    raise (a utilization against a guessed peak is worse than none)."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise MXNetError(
+            "no published peaks for device_kind %r (known: %s) — add its "
+            "row, with a source, to mxnet_tpu.context.DEVICE_PEAKS"
+            % (device_kind, sorted(DEVICE_PEAKS))) from None
 
 
 def cpu_pinned(device_id=0):

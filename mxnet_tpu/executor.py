@@ -887,25 +887,63 @@ class Executor:
 
     def fused_regions(self):
         """Fusion-region summaries of the compiled inference program —
-        ``[{name, base_op, members}]`` per ``_FusedRegion`` node the
-        fuse pass carved at bind (graph_pass/fuse.py, docs/fusion.md).
-        Empty when the pass is off or nothing matched; the program-
-        level twin of the pass report, readable without a flight-
-        recorder dump (tests, tools/fuse_smoke.py)."""
+        ``[{name, base_op, members, lowering, reason}]`` per
+        ``_FusedRegion`` node the fuse pass carved at bind
+        (graph_pass/fuse.py, docs/fusion.md). ``lowering`` is "kernel"
+        (the Pallas fused kernel) or "reference" (the unfused
+        composition) with the ``reason`` — the same static
+        ``ops.fused.kernel_decision`` the trace-time lowering asks, at
+        this executor's bound shapes and dtypes. Empty when the pass is
+        off or nothing matched; the program-level twin of the pass
+        report, readable without a flight-recorder dump (tests,
+        tools/fuse_smoke.py, chip_smoke.py)."""
         import json as _json
 
+        import jax
+
+        from .ops import fused as _fused
+
+        nodes = [n for n in self._prog.topo if n.op == "_FusedRegion"]
+        if not nodes:
+            return []
+        bound = dict(self.aux_dict)
+        bound.update(self.arg_dict)
+        internals = self._prog.symbol.get_internals()
+        feed = [n for n in internals.list_arguments() if n in bound]
+        shapes = internals.infer_shape_partial(
+            **{n: tuple(bound[n].shape) for n in feed})[1]
+        dtypes = internals.infer_type(
+            **{n: bound[n].dtype for n in feed})[1]
+        avals = {}
+        for (node, idx), shape, dtype in zip(internals._outputs, shapes,
+                                             dtypes):
+            key = node.name if node.is_variable else (id(node), idx)
+            src = bound.get(node.name) if node.is_variable else None
+            avals[key] = (src if src is not None else
+                          None if shape is None else
+                          jax.ShapeDtypeStruct(tuple(shape), dtype))
+        wants_kernel, interpret, why_off = _fused.use_kernel()
         out = []
-        for node in self._prog.topo:
-            if node.op != "_FusedRegion":
-                continue
+        for node in nodes:
             attrs = node.parsed_attrs()
             try:
                 members = _json.loads(
                     node.user_attrs.get("__fused_members__", "[]"))
             except ValueError:
                 members = []
+            ins = [avals.get(n.name if n.is_variable else (id(n), i))
+                   for n, i in node.inputs]
+            if not wants_kernel:
+                plan, why = None, why_off
+            elif None in ins:
+                plan, why = None, "input shapes not inferable"
+            else:
+                plan, why = _fused.kernel_decision(attrs, ins, interpret)
             out.append({"name": node.name, "base_op": attrs.base_op,
-                        "members": members})
+                        "members": members,
+                        "lowering": "reference" if plan is None
+                        else "kernel",
+                        "reason": why})
         return out
 
     def named_health_arrays(self):

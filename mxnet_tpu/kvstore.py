@@ -62,22 +62,11 @@ def _ensure_distributed():
     src/kvstore/kvstore_dist.h + ps-lite Van)."""
     import jax
 
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None and is_init():
+    # a second dist-store create must reuse the live client: re-running
+    # initialize() after computations have executed trips "must be
+    # called before any JAX computations"
+    if jax.distributed.is_initialized():
         return
-    if is_init is None:
-        # jax<0.5 has no public is_initialized; the client handle on the
-        # global state is the same truth. Without this, a second
-        # dist-store create re-runs initialize() after computations have
-        # executed and trips "must be called before any JAX
-        # computations" (the 2 seed dist_kvstore failures).
-        try:
-            from jax._src.distributed import global_state
-
-            if getattr(global_state, "client", None) is not None:
-                return
-        except Exception:
-            pass
     coord = os.environ.get("MXTPU_COORDINATOR")
     nworkers = os.environ.get("MXTPU_NUM_WORKERS")
     worker_id = os.environ.get("MXTPU_WORKER_ID")

@@ -2,11 +2,18 @@
 toolchain and loaded over ctypes — the TPU build's equivalent of the
 reference's compiled core (dmlc recordio framing, src/io/).
 
-Build artifacts are cached next to the sources; when no compiler is
-available the callers fall back to pure-Python implementations, so the
-package never hard-fails.
+Build artifacts are cached next to the sources, one per source *content*:
+``lib<name>.<sha256 of the .cc, 12 hex>.so``. A library built from another
+revision of the source has another name and is never loaded (a checkout
+holds only what git commits, so the ``.so`` files are always built where
+they run). When no compiler is available the callers fall back to
+pure-Python implementations, so the package never hard-fails;
+:func:`status` says which of the two each component is using.
 """
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -15,6 +22,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _LOCK = threading.Lock()
 _LIBS = {}  # guarded-by: _LOCK
 
+NAMES = ("recordio", "libsvmparse", "prefetch")
 
 # per-library extra compile flags
 _FLAGS = {"prefetch": ["-pthread"]}
@@ -22,12 +30,21 @@ _FLAGS = {"prefetch": ["-pthread"]}
 
 def _build(name):
     src = os.path.join(_HERE, name + ".cc")
-    so = os.path.join(_HERE, "lib%s.so" % name)
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    so = os.path.join(_HERE, "lib%s.%s.so" % (name, digest))
+    if not os.path.exists(so):
+        # build under a private name, then rename: a concurrent process
+        # never dlopens a half-written library
+        tmp = "%s.%d.tmp" % (so, os.getpid())
         cmd = (["g++", "-O2", "-std=c++14", "-fPIC", "-shared", src]
-               + _FLAGS.get(name, []) + ["-o", so])
+               + _FLAGS.get(name, []) + ["-o", tmp])
         subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, so)
+        for stale in glob.glob(os.path.join(_HERE, "lib%s.*so" % name)):
+            if stale != so:
+                with contextlib.suppress(FileNotFoundError):
+                    os.remove(stale)  # another process may get there first
     return so
 
 
@@ -43,6 +60,14 @@ def load(name):
             lib = None
         _LIBS[name] = lib
         return lib
+
+
+def status():
+    """``{component: "native" | "python"}`` — whether each native library
+    loaded (building it if needed) or its pure-Python stand-in is in
+    use."""
+    return {name: "native" if load(name) is not None else "python"
+            for name in NAMES}
 
 
 def recordio_lib():

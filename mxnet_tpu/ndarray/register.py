@@ -97,7 +97,8 @@ def invoke(opdef, args, kwargs):
     # src/engine/profiler.cc SetOprStart/SetOprEnd). The host-side
     # dispatch cost (t1 - t0: attr parsing, tracing, enqueue RTT) vs the
     # device-compute remainder (t2 - t1: block_until_ready delta) is THE
-    # eager-gap decomposition VERDICT.md asks for — see PERF_NOTES.md.
+    # eager-gap decomposition the round-5 review asked for — see
+    # PERF_NOTES.md.
     import jax
 
     t0 = _profiler._now_us()

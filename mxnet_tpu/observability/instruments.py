@@ -8,7 +8,7 @@ registry (metrics.py):
   ``jit.compile_count`` and feeds ``jit.compile_ms``; every jaxpr trace
   feeds ``jit.trace_count``/``jit.trace_ms``. A steady-state training
   loop must show a FLAT compile count — a climbing one is the recompile
-  storm VERDICT.md's bucketing ask wants ruled out. With
+  storm the round-5 review's bucketing ask wants ruled out. With
   ``MXNET_TELEMETRY_RETRACE=1`` the hooks also flip jax's
   ``explain_cache_misses`` and keep the most recent cause strings
   (``retrace_causes()``), which ``dump_metrics()`` appends as comments.

@@ -3,9 +3,9 @@
 The reference framework exposes engine/op timing only through the
 profiler; operational counters (how many eager dispatches? how many XLA
 compiles? what is the HBM watermark?) had no home. This registry is that
-home — the numeric substrate VERDICT.md's perf asks require (a measured
-dispatch-vs-compute split, a compile-count that proves "no recompile
-storm", a step-time distribution instead of a single mean).
+home — the numeric substrate the round-5 review's perf asks require (a
+measured dispatch-vs-compute split, a compile-count that proves "no
+recompile storm", a step-time distribution instead of a single mean).
 
 Design rules:
 

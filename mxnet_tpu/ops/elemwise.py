@@ -66,7 +66,7 @@ def _register_unary_ops():
         "ceil": lambda jnp, x: jnp.ceil(x),
         "floor": lambda jnp, x: jnp.floor(x),
         "trunc": lambda jnp, x: jnp.trunc(x),
-        "fix": lambda jnp, x: jnp.fix(x),
+        "fix": lambda jnp, x: jnp.trunc(x),
         "square": lambda jnp, x: jnp.square(x),
         "sqrt": lambda jnp, x: jnp.sqrt(x),
         "rsqrt": lambda jnp, x: jax.lax.rsqrt(x),

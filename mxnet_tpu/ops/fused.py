@@ -9,7 +9,9 @@ vec / res — the grammar in docs/fusion.md).  Extra epilogue operands
 (residual tensors, per-channel rescale vectors, the int8 island's fp32
 bias) ride as additional node inputs after the base op's own.
 
-Lowering, decided statically at trace time:
+Lowering, decided by :func:`kernel_decision` — a static function of the
+node's attrs and its inputs' shapes and dtypes, asked at trace time and
+again by the executor's region report, which names the reason:
 
 * **Pallas fused kernel** (parallel/fused.py) when the base is a
   float matmul-shaped op on TPU (or under ``MXNET_FUSION_INTERPRET``):
@@ -19,7 +21,8 @@ Lowering, decided statically at trace time:
   composition (recompute — the flash-attention escape-hatch shape).
 * **Reference composition** otherwise (general convolutions, int8
   islands whose exact int32 accumulation XLA owns, shapes with no
-  usable tiling, non-TPU backends): the SAME registry ops the unfused
+  tiling the TPU lowering accepts, non-TPU backends): the SAME registry
+  ops the unfused
   graph would run, applied in the same order inside this one node —
   numerically identical to the unfused subgraph by construction, and
   the mid-trace-safe fallback the pass contract requires.
@@ -28,11 +31,14 @@ from __future__ import annotations
 
 import json
 
+import numpy as np
+
 from ..base import MXNetError
 from .param import Int, Str
 from .registry import get_op, register_op
 
-__all__ = ["EPILOGUE_ACTS", "fused_region_parts"]
+__all__ = ["EPILOGUE_ACTS", "fused_region_parts", "kernel_decision",
+           "use_kernel"]
 
 # activation kinds the fuse pass may carve (kernel + reference agree;
 # parallel/fused.py _ACTS is the kernel-side twin, asserted in tests)
@@ -87,207 +93,194 @@ def _apply_reference(base, battrs, steps, base_inputs, extras):
     return out
 
 
-def _kernel_epilogue(steps, out_ndim):
-    """Translate graph steps into the kernel's static epilogue tuples,
-    or None when a step has no kernel form."""
+def _kernel_epilogue(steps):
+    """Translate graph steps into the kernel's static epilogue tuples:
+    ``(tuples, None)``, or ``(None, reason)`` when a step has no kernel
+    form."""
     from ..parallel import fused as F
 
     out = []
     for step in steps:
         kind = step["kind"]
+        why = None
         if kind == "act":
-            if not F.supported_act(step["act"]):
-                return None
-            out.append(("act", step["act"]))
+            if F.supported_act(step["act"]):
+                out.append(("act", step["act"]))
+            else:
+                why = "activation %s" % step["act"]
         elif kind == "scalar":
             out.append(("scalar", step["op"], float(step["scalar"])))
         elif kind == "cast":
-            if step["dtype"] not in _FLOATS:
-                return None
-            out.append(("cast", step["dtype"]))
+            if step["dtype"] in _FLOATS:
+                out.append(("cast", step["dtype"]))
+            else:
+                why = "cast to %s" % step["dtype"]
         elif kind == "res":
-            if step["op"] not in ("elemwise_add", "elemwise_mul"):
-                return None
-            out.append(("res", step["op"]))
+            if step["op"] in ("elemwise_add", "elemwise_mul"):
+                out.append(("res", step["op"]))
+            else:
+                why = "residual op %s" % step["op"]
         elif kind == "vec":
-            if step.get("bshape") == "full":
-                if step["op"] == "broadcast_add":
-                    out.append(("res", "elemwise_add"))
-                elif step["op"] == "broadcast_mul":
-                    out.append(("res", "elemwise_mul"))
-                else:
-                    return None
-            elif step.get("bshape") == "lastdim" and \
-                    step["op"] == "broadcast_add":
-                out.append(("vadd",))
-            elif step.get("bshape") == "lastdim" and \
-                    step["op"] == "broadcast_mul":
-                out.append(("vmul",))
+            form = {("full", "broadcast_add"): ("res", "elemwise_add"),
+                    ("full", "broadcast_mul"): ("res", "elemwise_mul"),
+                    ("lastdim", "broadcast_add"): ("vadd",),
+                    ("lastdim", "broadcast_mul"): ("vmul",),
+                    }.get((step.get("bshape"), step["op"]))
+            if form is not None:
+                out.append(form)
             else:
                 # a channel vector on a non-last axis (NCHW conv) has no
                 # kernel form — the reference composition handles it
-                return None
+                why = "%s over a non-last axis" % step["op"]
         else:
-            return None
-    return tuple(out)
+            why = "step kind %s" % kind
+        if why is not None:
+            return None, "epilogue has no kernel form: " + why
+    return tuple(out), None
 
 
-def _kernel_matmul_form(base, battrs, steps, base_inputs, extras,
-                        out_shape):
-    """(x2d, w, wt, kernel_extras, extra_epilogue_prefix, reshape_back)
-    for the dense 2-d kernel, or None when this base has no matmul
-    form.  The base op's own bias becomes a leading ("bias",) step."""
+def _matmul_dims(base, battrs, shapes):
+    """``((batch, M, K, N, wt, has_bias), None)`` — the contraction this
+    base op is, read off its input SHAPES — or ``(None, reason)`` when it
+    has no matmul form. ``batch`` is None for the dense 2-d kernel."""
     name = base.name
-    prefix = []
+    data, weight = tuple(shapes[0]), tuple(shapes[1])
     if name == "FullyConnected":
-        data, weight = base_inputs[0], base_inputs[1]
-        x = data.reshape(data.shape[0], -1) if battrs.flatten else \
-            data.reshape(-1, data.shape[-1])
-        if not battrs.no_bias:
-            prefix.append(("bias",))
-            extras = [base_inputs[2]] + list(extras)
-        return x, weight, True, extras, prefix, tuple(out_shape)
-    if name == "dot":
+        rows = data[0] if battrs.flatten else int(np.prod(data[:-1]))
+        return (None, int(rows), int(np.prod(data)) // int(rows),
+                weight[0], True, not battrs.no_bias), None
+    if name in ("dot", "batch_dot"):
+        nd = 2 if name == "dot" else 3
         if battrs.get("transpose_a") or battrs.get("transpose_b"):
-            return None
-        x, w = base_inputs[0], base_inputs[1]
-        if x.ndim != 2 or w.ndim != 2:
-            return None
-        return x, w, False, list(extras), prefix, tuple(out_shape)
+            return None, "%s with a transposed operand" % name
+        if len(data) != nd or len(weight) != nd:
+            return None, "%s operands are not %d-d" % (name, nd)
+        return ((data[0] if nd == 3 else None), data[-2], data[-1],
+                weight[-1], False, False), None
     if name == "Convolution":
         layout = battrs.layout or ""
-        if (tuple(battrs.kernel) != (1, 1) or not layout.endswith("C")
+        if len(data) != 4 or not layout.endswith("C"):
+            return None, ("Convolution layout %s is not channels-last"
+                          % (layout or "NCHW"))
+        if (tuple(battrs.kernel) != (1, 1)
                 or tuple(battrs.stride or (1, 1)) != (1, 1)
                 or tuple(battrs.pad or (0, 0)) != (0, 0)
                 or int(battrs.num_group or 1) != 1
                 or bool(battrs.get("dilate") and
                         tuple(battrs.dilate) != (1, 1))):
-            return None
-        data, weight = base_inputs[0], base_inputs[1]
-        if data.ndim != 4:
-            return None
-        N, H, W, C = data.shape
-        x = data.reshape(N * H * W, C)
-        w = weight.reshape(C, int(battrs.num_filter))  # HWIO, 1x1
-        if not battrs.no_bias:
-            prefix.append(("bias",))
-            extras = [base_inputs[2]] + list(extras)
-        return x, w, False, extras, prefix, tuple(out_shape)
-    return None
+            return None, "Convolution is not a dense 1x1 stride-1 matmul"
+        return (None, int(np.prod(data[:3])), data[3],
+                int(battrs.num_filter), False, not battrs.no_bias), None
+    return None, "base op %s" % name
 
 
-def _try_kernel(base, battrs, steps, base_inputs, extras, out_aval,
-                interpret):
-    """The Pallas lowering, or None (caller composes the reference)."""
+def kernel_decision(attrs, in_avals, interpret=False):
+    """THE static kernel-vs-reference decision for one ``_FusedRegion``
+    node: ``(plan, None)`` when the Pallas kernel applies, ``(None,
+    reason)`` when the region lowers its reference composition. A
+    function of the node's attrs and its inputs' shapes and dtypes only
+    (``in_avals``: anything with ``.shape``/``.dtype``) — it builds and
+    traces nothing, so the executor's region report
+    (``Executor.fused_regions``) and the trace-time lowering below ask
+    the same question and get the same answer."""
     from ..parallel import fused as F
 
-    if any(str(t.dtype) not in _FLOATS
-           for t in list(base_inputs) + list(extras)):
-        return None
-    kern_steps = _kernel_epilogue(steps, len(out_aval.shape))
+    base, battrs, steps, n_base = fused_region_parts(attrs)
+    for t in in_avals:
+        if str(t.dtype) not in _FLOATS:
+            return None, "%s operand (int8 islands stay with XLA)" % t.dtype
+    kern_steps, why = _kernel_epilogue(steps)
     if kern_steps is None:
-        return None
-    name = base.name
-    if name == "batch_dot":
-        if battrs.get("transpose_a") or battrs.get("transpose_b"):
-            return None
-        x, w = base_inputs[0], base_inputs[1]
-        if x.ndim != 3 or w.ndim != 3:
-            return None
-        B, M, _ = x.shape
-        N = w.shape[2]
-        res = [e.reshape(B, M, N) for e in extras]
-        return F.fused_batch_matmul(x, w, extras=res, epilogue=kern_steps,
-                                    out_dtype=out_aval.dtype,
-                                    interpret=interpret)
-    form = _kernel_matmul_form(base, battrs, steps, base_inputs, extras,
-                               out_aval.shape)
-    if form is None:
-        return None
-    x, w, wt, kextras, prefix, out_shape = form
-    M = x.shape[0]
-    N = w.shape[0] if wt else w.shape[1]
-    shaped = []
-    for step, arr in zip(list(prefix) + list(
-            _kernel_extra_tuples(kern_steps)), kextras):
-        if step[0] == "res":
-            shaped.append(arr.reshape(M, N))
-        else:
-            shaped.append(arr.reshape(-1))
-    out = F.fused_matmul(x, w, extras=shaped,
-                         epilogue=tuple(prefix) + kern_steps, wt=wt,
-                         out_dtype=out_aval.dtype, interpret=interpret)
-    if out is None:
-        return None
-    return out.reshape(out_shape)
+        return None, why
+    dims, why = _matmul_dims(base, battrs, [t.shape for t in
+                                            in_avals[:n_base]])
+    if dims is None:
+        return None, why
+    batch, M, K, N, wt, has_bias = dims
+    extra_shapes = [t.shape for t in in_avals[n_base:]]
+    if has_bias:
+        kern_steps = (("bias",),) + kern_steps
+        extra_shapes = [in_avals[2].shape] + extra_shapes
+    tiles, why = F.kernel_plan(M, N, K, in_avals[0].dtype, kern_steps,
+                               extra_shapes, batch=batch,
+                               interpret=interpret)
+    if tiles is None:
+        return None, "no TPU tiling: " + why
+    return {"batch": batch, "M": M, "K": K, "N": N, "wt": wt,
+            "has_bias": has_bias, "epilogue": kern_steps,
+            "tiles": tiles}, None
 
 
-def _kernel_extra_tuples(kern_steps):
-    return [s for s in kern_steps if s[0] in ("bias", "vmul", "vadd",
-                                              "res")]
+def _run_kernel(plan, base_inputs, extras, out_aval, interpret):
+    """The Pallas lowering of a region :func:`kernel_decision` accepted."""
+    from ..parallel import fused as F
+
+    x, w = base_inputs[0], base_inputs[1]
+    if plan["has_bias"]:
+        extras = [base_inputs[2]] + list(extras)
+    bm, bn, bk = plan["tiles"]
+    kw = dict(extras=extras, epilogue=plan["epilogue"],
+              block_m=bm, block_n=bn, block_k=bk,
+              out_dtype=out_aval.dtype, interpret=interpret)
+    if plan["batch"] is not None:
+        out = F.fused_batch_matmul(x, w, **kw)
+    else:
+        out = F.fused_matmul(x.reshape(plan["M"], plan["K"]),
+                             w.reshape((plan["N"], plan["K"]) if plan["wt"]
+                                       else (plan["K"], plan["N"])),
+                             wt=plan["wt"], **kw)
+    return out.reshape(out_aval.shape)
 
 
-def _use_kernel():
+def use_kernel():
+    """``(lower regions to the Pallas kernel?, interpreted?, why not)`` —
+    the TPU backend compiles it, ``MXNET_FUSION_INTERPRET`` interprets it
+    anywhere, everything else composes the reference."""
     import jax
 
     from ..config import get_flag
 
     if get_flag("MXNET_FUSION_INTERPRET"):
-        return True, True
+        return True, True, None
     if not get_flag("MXNET_FUSION_KERNEL"):
-        return False, False
-    return jax.default_backend() == "tpu", False
+        return False, False, "MXNET_FUSION_KERNEL=0"
+    if jax.default_backend() != "tpu":
+        return False, False, ("backend %s composes the reference"
+                              % jax.default_backend())
+    return True, False, None
 
 
 def _fused_region(attrs, *inputs):
     import jax
 
     base, battrs, steps, n_base = fused_region_parts(attrs)
-    base_inputs = list(inputs[:n_base])
-    extras = list(inputs[n_base:])
-    use_kernel, interpret = _use_kernel()
+    wants_kernel, interpret, _ = use_kernel()
 
     def reference(*ins):
         return _apply_reference(base, battrs, steps, list(ins[:n_base]),
                                 list(ins[n_base:]))
 
-    if not use_kernel:
+    plan = None
+    if wants_kernel:
+        plan, _ = kernel_decision(attrs, inputs, interpret)
+    if plan is None:
+        # not on a kernel backend, or no kernel form at this shape/dtype
+        # (a static decision): lower the unfused composition — flash
+        # attention's prime-T rule applied to fusion regions
         return reference(*inputs)
     out_aval = jax.eval_shape(reference, *inputs)
-
-    def kernel_or_ref(*ins):
-        ka = _try_kernel(base, battrs, steps, list(ins[:n_base]),
-                         list(ins[n_base:]), out_aval, interpret)
-        return ka if ka is not None else reference(*ins)
-
-    # eligibility probe under eval_shape: the decision (shapes, dtypes,
-    # tiling) is static, and probing ABSTRACTLY keeps the pallas_call
-    # out of any surrounding autodiff trace — only the custom_vjp call
-    # below ever executes it (its backward is the reference recompute)
-    try:
-        probed = jax.eval_shape(
-            lambda *ins: _try_kernel(base, battrs, steps,
-                                     list(ins[:n_base]),
-                                     list(ins[n_base:]), out_aval,
-                                     interpret), *inputs)
-        has_kernel = probed is not None
-    except Exception:
-        has_kernel = False
-    if not has_kernel:
-        # no kernel form at this shape/dtype — the mid-trace-safe
-        # fallback: lower the unfused composition (flash attention's
-        # prime-T rule applied to fusion regions)
-        return reference(*inputs)
 
     # Pallas forward, reference-recompute backward: the custom_vjp keeps
     # training binds differentiable without a hand-written backward per
     # epilogue combination (the residuals are just the region inputs)
-    @jax.custom_vjp
-    def f(*ins):
-        return kernel_or_ref(*ins)
+    def kernel(*ins):
+        return _run_kernel(plan, list(ins[:n_base]), list(ins[n_base:]),
+                           out_aval, interpret)
+
+    f = jax.custom_vjp(kernel)
 
     def fwd(*ins):
-        return kernel_or_ref(*ins), ins
+        return kernel(*ins), ins
 
     def bwd(res, g):
         _, vjp = jax.vjp(reference, *res)

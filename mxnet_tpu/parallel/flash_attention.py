@@ -21,9 +21,13 @@ tensor in HBM.
 
 Standard flash-attention recurrence (Dao et al. 2022, public algorithm);
 the kernel implementation is original. Falls back to the XLA reference
-implementation when the sequence length has no usable block divisor, and
-to XLA autodiff of the dense formula for the backward when
-``MXNET_FLASH_ATTENTION_BWD=0`` (see config.py for the knobs).
+implementation when the sequence length has no TPU-legal block (a static
+rule, ``_pick_block``), and to XLA autodiff of the dense formula for the
+backward when ``MXNET_FLASH_ATTENTION_BWD=0`` (see config.py for the
+knobs). Every kernel traces through :func:`.pallas_common.pallas_call`
+(x64 scoped off) and moves its per-row softmax statistics (lse, delta)
+as lane-dense (1, bq) row blocks of (B*H, 1, T) arrays, a block shape
+the TPU lowering accepts where (1, bq) of a 2-d (B*H, T) array is not.
 """
 from __future__ import annotations
 
@@ -34,6 +38,7 @@ import numpy as np
 from ..autotune import cost_model as _tune_cost
 from ..autotune.registry import declare as _declare_tunable
 from ..config import get_flag
+from .pallas_common import LANES, aligned_block, pallas_call
 
 __all__ = ["flash_attention", "paged_decode_attention",
            "paged_verify_attention"]
@@ -70,11 +75,11 @@ _declare_tunable(
         "more live tiles per grid step than the forward.")
 
 
-def _compiler_params(pltpu, **kw):
-    # renamed upstream: CompilerParams (new) vs TPUCompilerParams (0.4.x)
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _tuned_block(value):
@@ -87,20 +92,26 @@ def _tuned_block(value):
     return value if value > 0 else None
 
 
-def _pick_block(T, bound):
-    for b in range(min(bound, T), 0, -1):
-        if T % b == 0:
-            return b
-    return 1
+def _pick_block(T, bound, interpret):
+    """Sequence tile under ``bound``, or None (the caller lowers the dense
+    formula). Compiled, a tile is the whole sequence or a multiple of 128
+    dividing it — it is the lane dimension of the lse/delta row blocks
+    and of the score tile; the interpreter takes any divisor. Prime-ish T
+    (only tiny divisors) declines either way: ``aligned_block``."""
+    return aligned_block(T, bound, 1 if interpret else LANES)
 
 
-def _positions(q_idx, kv_idx, bq, bk):
+def _visible(q_idx, kv_idx, bq, bk, transposed=False):
+    """Causal visibility (query position >= key position) of one score
+    tile: (bq, bk), or (bk, bq) when ``transposed``."""
     import jax
     import jax.numpy as jnp
 
-    q_pos = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return q_pos, k_pos
+    shape, q_dim = ((bk, bq), 1) if transposed else ((bq, bk), 0)
+    q_pos = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                   1 - q_dim)
+    return q_pos >= k_pos
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
@@ -135,8 +146,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            q_pos, k_pos = _positions(q_idx, kv_idx, bq, bk)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            s = jnp.where(_visible(q_idx, kv_idx, bq, bk), s, -jnp.inf)
         m_prev = m_ref[...]                       # (bq, 1)
         block_max = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, block_max)
@@ -158,51 +168,23 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         # the O(T) softmax residual: lse = m + log(l). -inf rows (fully
         # masked — only reachable through ring blocks above the causal
-        # diagonal) stay -inf: -inf + log(eps) = -inf
-        lse_ref[0] = (m_ref[...]
-                      + jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, 0]
-
-
-def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-               scale, causal, q_idx, kv_idx):
-    """Recompute one (q_block, k_block) tile of p and ds from residuals.
-
-    Shared by both backward passes: p = exp(s - lse) is the EXACT softmax
-    (no renormalization needed — lse is the forward's true row
-    logsumexp), ds = p * (do.v^T - delta) with delta = rowsum(do * o)
-    (+ any lse cotangent, folded into delta by the caller).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    qs = q_ref[0].astype(jnp.float32) * scale           # (bq, d)
-    k = k_ref[0].astype(jnp.float32)                    # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]                           # (bq, 1)
-    delta = delta_ref[0][:, None]
-    s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        q_pos, k_pos = _positions(q_idx, kv_idx, bq, bk)
-        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-    # fully-masked rows have lse = -inf; exp(s - 0) would explode, so
-    # zero them explicitly (s is -inf there too, but -inf - -inf is nan)
-    lse_safe = jnp.where(jnp.isneginf(lse), 0.0, lse)
-    p = jnp.exp(s - lse_safe)
-    p = jnp.where(jnp.isneginf(s) | jnp.isneginf(lse), 0.0, p)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    return qs, k, do, p, ds
+        # diagonal) stay -inf: -inf + log(eps) = -inf. The (bq, 1) column
+        # leaves as a lane-dense (1, bq) row of a (B*H, 1, T) array
+        lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0, 0] = lse[:, 0]
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_k, seq_len):
     """dq pass: grid (batch*head, q_block, k_block); k is the sequential
-    axis, dq accumulates in fp32 scratch across it."""
+    axis, dq accumulates in fp32 scratch across it.
+
+    Recomputes the (bq, bk) tile of p and ds from the residuals: p =
+    exp(s - lse) is the EXACT softmax (no renormalization needed — lse
+    is the forward's true row logsumexp), ds = p * (do.v^T - delta) with
+    delta = rowsum(do * o) (+ any lse cotangent, folded into delta by
+    the caller). lse/delta arrive as lane-dense (1, bq) rows of
+    (B*H, 1, T) arrays and turn into columns here."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -220,9 +202,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        _, k, _, _, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, causal=causal, q_idx=q_idx, kv_idx=kv_idx)
+        qs, k, v, do = _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale)
+        lse = jnp.expand_dims(lse_ref[0, 0], -1)            # (bq, 1)
+        delta = jnp.expand_dims(delta_ref[0, 0], -1)
+        s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if causal:
+            s = jnp.where(_visible(q_idx, kv_idx, bq, bk), s, -jnp.inf)
+        ds = _softmax_grad_tile(s, lse, delta, do, v, transposed=False)[1]
         # ds/dq_i = scale * sum_j ds_ij k_j
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -233,11 +220,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
+def _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale):
+    """fp32 (scale * q, k, v, do) tiles of one backward grid step."""
+    import jax.numpy as jnp
+
+    return (q_ref[0].astype(jnp.float32) * scale,           # (bq, d)
+            k_ref[0].astype(jnp.float32),                   # (bk, d)
+            v_ref[0].astype(jnp.float32),
+            do_ref[0].astype(jnp.float32))
+
+
+def _softmax_grad_tile(s, lse, delta, do, v, transposed):
+    """(p, ds) of one masked score tile ``s`` — (bq, bk) with lse/delta
+    as (bq, 1) columns, or (bk, bq) with (1, bq) rows when ``transposed``
+    — shared by both backward passes."""
+    import jax
+    import jax.numpy as jnp
+
+    # fully-masked rows have lse = -inf; exp(s - 0) would explode, so
+    # zero them explicitly (s is -inf there too, but -inf - -inf is nan)
+    lse_safe = jnp.where(jnp.isneginf(lse), 0.0, lse)
+    p = jnp.exp(s - lse_safe)
+    p = jnp.where(jnp.isneginf(s) | jnp.isneginf(lse), 0.0, p)
+    lhs, rhs = (v, do) if transposed else (do, v)
+    dp = jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta)
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale, causal, block_q, seq_len):
     """dk/dv pass: grid (batch*head, k_block, q_block); q is the
-    sequential axis, dk and dv accumulate in fp32 scratch across it."""
+    sequential axis, dk and dv accumulate in fp32 scratch across it.
+
+    Works on the TRANSPOSED (bk, bq) score tile s^T = k.q^T, so dv =
+    p^T.do and dk = ds^T.q are plain row-major matmuls (no transposed-LHS
+    contraction for Mosaic to transpose) and lse/delta broadcast along
+    sublanes straight from their (1, bq) rows."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -256,16 +276,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        qs, _, do, p, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, causal=causal, q_idx=q_idx, kv_idx=kv_idx)
+        qs, k, v, do = _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale)
+        lse = lse_ref[0]                                    # (1, bq)
+        delta = delta_ref[0]
+        s_t = jax.lax.dot_general(k, qs, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        if causal:
+            s_t = jnp.where(_visible(q_idx, kv_idx, bq, bk, transposed=True),
+                            s_t, -jnp.inf)
+        p_t, ds_t = _softmax_grad_tile(s_t, lse, delta, do, v,
+                                       transposed=True)
         # dv_j = sum_i p_ij do_i ; dk_j = sum_i ds_ij (scale q_i) — qs is
         # already scaled, so no extra factor here
         dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p_t, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())),
+            ds_t, qs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(q_idx == (seq_len // block_q) - 1)
@@ -279,8 +306,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     interpret=False, return_lse=False):
     """Blocked attention; q/k/v: (batch, heads, T, d).
 
-    Block arguments are upper bounds; the largest divisors of T at or
-    below them are used. Unset bounds resolve through the autotuner
+    Block arguments are upper bounds; the largest TPU-legal tiles at or
+    below them are used (``_pick_block``: the whole sequence or a
+    multiple of 128 dividing T when compiled, any divisor interpreted; a
+    T with no such tile lowers the dense XLA formula). Unset bounds
+    resolve through the autotuner
     first — a persistent per-device tuning-cache entry for this
     (shape-bucket, dtype) wins (docs/autotune.md; a miss with
     MXNET_TUNE=1 outside a trace runs the measured sweep on the spot) —
@@ -334,20 +364,17 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                       or get_flag("MXNET_FLASH_BWD_BLOCK_Q"))
     block_k_bwd = int(block_k_bwd or _tuned_block(tuned_bwd.get("block_k"))
                       or get_flag("MXNET_FLASH_BWD_BLOCK_K"))
-    # block sizes are upper bounds: the largest divisor of T at or below
-    # the bound is used. When T has no reasonable divisor (prime-ish), a
-    # "block" would balloon toward T and defeat the kernel — fall back to
-    # the XLA formula instead.
-    bq_req, bk_req = min(block_q, T), min(block_k, T)
-    block_q = _pick_block(T, block_q)
-    block_k = _pick_block(T, block_k)
-    if block_q * 8 < bq_req or block_k * 8 < bk_req:
-        # prime-ish T: only tiny divisors exist; tiny blocks waste the
-        # MXU and the grid explodes — the XLA formula is faster
+    # block sizes are upper bounds; _pick_block turns each into a tile
+    # the TPU lowering accepts or declines (no 128-multiple divisor
+    # compiled, or prime-ish T with only tiny divisors) — a declined
+    # shape lowers the XLA formula instead, a static decision.
+    block_q = _pick_block(T, block_q, interpret)
+    block_k = _pick_block(T, block_k, interpret)
+    block_q_bwd = _pick_block(T, block_q_bwd, interpret)
+    block_k_bwd = _pick_block(T, block_k_bwd, interpret)
+    if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale)
         return (out, lse) if return_lse else out
-    block_q_bwd = _pick_block(T, min(block_q_bwd, T))
-    block_k_bwd = _pick_block(T, min(block_k_bwd, T))
 
     def _flash_fwd_impl(q, k, v):
         qf = q.reshape(B * H, T, D)
@@ -356,28 +383,21 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         grid = (B * H, T // block_q, T // block_k)
         kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                    block_k=block_k, seq_len=T)
-        out, lse = pl.pallas_call(
+        out, lse = pallas_call(
             kernel,
             grid=grid,
             in_specs=[
-                # j * 0 (not a literal 0): under jax_enable_x64 a literal
-                # becomes an i64 constant and Mosaic rejects the
-                # mixed-width index tuple
-                pl.BlockSpec((1, block_q, D),
-                             lambda b, i, j: (b, i, j * 0)),
-                pl.BlockSpec((1, block_k, D),
-                             lambda b, i, j: (b, j, i * 0)),
-                pl.BlockSpec((1, block_k, D),
-                             lambda b, i, j: (b, j, i * 0)),
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D),
-                             lambda b, i, j: (b, i, j * 0)),
-                pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-                jax.ShapeDtypeStruct((B * H, T), jnp.float32),
+                jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), jnp.float32),
@@ -385,28 +405,32 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_compiler_params(
-                pltpu, dimension_semantics=("parallel", "parallel",
-                                            "arbitrary")),
+            compiler_params=_compiler_params(),
+            name="flash_attention_fwd",
         )(qf, kf, vf)
         return out.reshape(B, H, T, D), lse.reshape(B, H, T)
 
     def _flash_bwd_impl(q, k, v, o, lse, do, dlse):
         bq, bk = block_q_bwd, block_k_bwd
         qf, kf, vf, dof = (a.reshape(B * H, T, D) for a in (q, k, v, do))
-        lsef = lse.reshape(B * H, T)
         # delta_i = rowsum(do_i * o_i); an lse cotangent adds
         # glse_i * p_ij to ds_ij, which folds in as delta - glse
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1).reshape(B * H, T)
+                        axis=-1)
         if dlse is not None:
-            delta = delta - dlse.astype(jnp.float32).reshape(B * H, T)
+            delta = delta - dlse.astype(jnp.float32)
+        # per-row residuals ride as lane-dense rows of (B*H, 1, T)
+        # arrays — a (1, bq) block of a 2-d (B*H, T) array is not a legal
+        # TPU block shape. The dq pass turns its row into a column
+        # in-kernel; the dk/dv pass uses it as a row
+        lse_row = lse.reshape(B * H, 1, T)
+        delta_row = delta.reshape(B * H, 1, T)
         # dq pass grid is (b, q_idx, kv_idx): q/do/rows follow dim 1,
         # k/v follow dim 2
-        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, j * 0))
-        k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, i * 0))
-        row_spec = pl.BlockSpec((1, bq), lambda b, i, j: (b, i))
-        dq = pl.pallas_call(
+        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
+        k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
+        row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        dq = pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                               block_k=bk, seq_len=T),
             grid=(B * H, T // bq, T // bk),
@@ -415,15 +439,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interpret,
-            compiler_params=_compiler_params(
-                pltpu, dimension_semantics=("parallel", "parallel",
-                                            "arbitrary")),
-        )(qf, kf, vf, dof, lsef, delta)
+            compiler_params=_compiler_params(),
+            name="flash_attention_bwd_dq",
+        )(qf, kf, vf, dof, lse_row, delta_row)
         # dk/dv pass: grid dim 1 walks k blocks, dim 2 scans q blocks
-        q_spec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, j * 0))
-        k_spec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, i * 0))
-        row_spec2 = pl.BlockSpec((1, bq), lambda b, j, i: (b, i))
-        dk, dv = pl.pallas_call(
+        q_spec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
+        k_spec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+        row_spec2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
+        dk, dv = pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                               block_q=bq, seq_len=T),
             grid=(B * H, T // bk, T // bq),
@@ -435,10 +458,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
             interpret=interpret,
-            compiler_params=_compiler_params(
-                pltpu, dimension_semantics=("parallel", "parallel",
-                                            "arbitrary")),
-        )(qf, kf, vf, dof, lsef, delta)
+            compiler_params=_compiler_params(),
+            name="flash_attention_bwd_dkv",
+        )(qf, kf, vf, dof, lse_row, delta_row)
         return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
                 dv.reshape(B, H, T, D))
 

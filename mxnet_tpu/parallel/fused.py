@@ -17,11 +17,12 @@ docs/autotune.md) with the analytic VMEM/roofline pruning in
 Two entry points:
 
 * :func:`fused_matmul` — (M, K) x (K, N) [or the FullyConnected
-  (N, K) weight layout] with a static epilogue spec; returns None at
-  trace time when the shape has no usable block tiling — the caller
-  (ops/fused.py) then lowers the unfused reference composition instead,
-  exactly like flash attention's prime-T fallback.  Mid-trace safe: the
-  decision is static (shapes are known under jit).
+  (N, K) weight layout] with a static epilogue spec; returns None when
+  :func:`kernel_plan` declines the shape (no tiling the TPU lowering
+  accepts) — the caller (ops/fused.py, which asks ``kernel_plan`` first
+  and reports the reason) then lowers the unfused reference composition
+  instead, exactly like flash attention's prime-T fallback.  The
+  decision is a static function of shapes, dtype and epilogue.
 * :func:`fused_batch_matmul` — the (B, M, K) x (B, K, N) batch_dot
   variant (leading batch dim rides the grid, the flash-attention B*H
   pattern).
@@ -44,9 +45,11 @@ import functools
 import numpy as np
 
 from ..config import get_flag
+from .pallas_common import LANES, SUBLANES, aligned_block, pallas_call
 
 __all__ = ["fused_matmul", "fused_batch_matmul", "supported_act",
-           "pick_blocks", "resolve_blocks", "fused_shape_key"]
+           "kernel_plan", "pick_blocks", "resolve_blocks",
+           "fused_shape_key"]
 
 # activations the kernel applies on the fp32 accumulator; anything else
 # keeps the region on the reference composition path
@@ -86,21 +89,6 @@ def _apply_scalar(y, op, v):
     if op == "_rminus_scalar":
         return v - y
     raise ValueError("unsupported fused scalar op %r" % (op,))
-
-
-def _compiler_params(pltpu, **kw):
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
-def _pick_block(n, bound):
-    """Largest divisor of n at or below bound (the flash-attention
-    block-bound convention)."""
-    for b in range(min(int(bound), int(n)), 0, -1):
-        if n % b == 0:
-            return b
-    return 1
 
 
 def fused_shape_key(M, N, K):
@@ -146,23 +134,60 @@ def resolve_blocks(M, N, K, dtype="float32", dtype_bytes=4, block_m=None,
     return block_m, block_n, block_k
 
 
-def pick_blocks(M, N, K, block_m, block_n, block_k):
-    """Concrete tile sizes (largest divisors at or below the bounds), or
-    None when the shape tiles so poorly the kernel would waste the MXU
-    (the prime-T fallback rule: an 8x shortfall against the requested
-    bound means only tiny divisors exist)."""
-    bm = _pick_block(M, block_m)
-    bn = _pick_block(N, block_n)
-    bk = _pick_block(K, block_k)
-    if (bm * 8 < min(block_m, M) or bn * 8 < min(block_n, N)
-            or bk * 8 < min(block_k, K)):
-        return None
-    return bm, bn, bk
+def pick_blocks(M, N, K, block_m, block_n, block_k, interpret=False):
+    """``((bm, bn, bk), None)`` — concrete tiles under the bounds — or
+    ``(None, reason)`` when the kernel declines this shape. A static rule
+    of shapes only (:func:`.pallas_common.aligned_block`): compiled, bm
+    is a multiple of 8 sublanes and bn/bk of 128 lanes, or the whole
+    dimension when it fits under its bound; the interpreter takes any
+    divisor; a dimension with only tiny divisors (the flash-attention
+    prime-T rule) declines."""
+    aligns = (1, 1, 1) if interpret else (SUBLANES, LANES, LANES)
+    tiles = []
+    for name, n, bound, align in zip("MNK", (M, N, K),
+                                     (block_m, block_n, block_k), aligns):
+        b = aligned_block(n, bound, align)
+        if b is None:
+            return None, ("%s=%d has no divisor in [%d, %d] that is a "
+                          "multiple of %d" % (name, n, -(-bound // 8),
+                                              bound, align))
+        tiles.append(b)
+    return tuple(tiles), None
 
 
 def _epilogue_extras(epilogue):
     """Which steps consume an extra input, in order."""
     return [s for s in epilogue if s[0] in ("bias", "vmul", "vadd", "res")]
+
+
+def kernel_plan(M, N, K, dtype, epilogue=(), extra_shapes=(), batch=None,
+                block_m=None, block_n=None, block_k=None, interpret=False):
+    """THE static lowering decision of :func:`fused_matmul` (and, with
+    ``batch``, :func:`fused_batch_matmul`): ``((bm, bn, bk), None)`` or
+    ``(None, reason)``. A function of shapes, dtype and the epilogue
+    only, so ops/fused.py decides kernel-vs-reference before it builds
+    anything and the region report (``Executor.fused_regions``) can name
+    the reason."""
+    extra_steps = _epilogue_extras(epilogue)
+    if len(extra_steps) != len(extra_shapes):
+        raise ValueError("fused kernel: %d extra inputs for %d "
+                         "extra-consuming steps"
+                         % (len(extra_shapes), len(extra_steps)))
+    for step, shape in zip(extra_steps, extra_shapes):
+        if step[0] != "res" and batch is not None:
+            return None, ("%s step: vector epilogues belong to the dense "
+                          "conv/FC kernel, not batch_dot" % step[0])
+        want = (batch or 1) * M * N if step[0] == "res" else N
+        if int(np.prod(shape)) != want:
+            return None, ("%s operand of shape %s does not cover the "
+                          "%d-element %s" % (
+                              step[0], tuple(shape), want,
+                              "output" if step[0] == "res" else "last axis"))
+    dtype = np.dtype(dtype)
+    bounds = resolve_blocks(M, N, K, dtype=str(dtype),
+                            dtype_bytes=dtype.itemsize, block_m=block_m,
+                            block_n=block_n, block_k=block_k)
+    return pick_blocks(M, N, K, *bounds, interpret=interpret)
 
 
 def _mm_kernel(*refs, n_extras, wt, epilogue, n_k, out_dtype):
@@ -227,10 +252,9 @@ def fused_matmul(x, w, extras=(), epilogue=(), wt=True, block_m=None,
     (N, K) when ``wt`` (the FullyConnected weight layout) else (K, N).
 
     ``extras`` supplies one array per extra-consuming epilogue step in
-    order: (N,)-vectors for bias/vmul/vadd, (M, N) for res.  Returns the
-    (M, N) result, or **None** when the shape has no usable tiling —
-    the caller then lowers its unfused reference composition (the
-    mid-trace-safe fallback; the decision is static under jit).
+    order: N-element vectors for bias/vmul/vadd, M*N elements for res.
+    Returns the (M, N) result, or **None** when :func:`kernel_plan`
+    declines (the caller then lowers its unfused reference composition).
     """
     import jax
     import jax.numpy as jnp
@@ -240,42 +264,32 @@ def fused_matmul(x, w, extras=(), epilogue=(), wt=True, block_m=None,
     M, K = x.shape
     N = w.shape[0] if wt else w.shape[1]
     out_dtype = jnp.dtype(out_dtype or x.dtype)
-    block_m, block_n, block_k = resolve_blocks(
-        M, N, K, dtype=str(x.dtype), dtype_bytes=x.dtype.itemsize,
-        block_m=block_m, block_n=block_n, block_k=block_k)
-    picked = pick_blocks(M, N, K, block_m, block_n, block_k)
-    if picked is None:
+    tiles, _ = kernel_plan(
+        M, N, K, x.dtype, epilogue, [e.shape for e in extras],
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=interpret)
+    if tiles is None:
         return None
-    bm, bn, bk = picked
+    bm, bn, bk = tiles
 
-    extra_steps = _epilogue_extras(epilogue)
-    if len(extra_steps) != len(extras):
-        raise ValueError("fused_matmul: %d extra inputs for %d "
-                         "extra-consuming steps"
-                         % (len(extras), len(extra_steps)))
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
         (pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)) if wt
          else pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))),
     ]
     extra_arrays = []
-    for step, arr in zip(extra_steps, extras):
+    for step, arr in zip(_epilogue_extras(epilogue), extras):
         if step[0] == "res":
-            if tuple(arr.shape) != (M, N):
-                return None
-            extra_arrays.append(arr)
+            extra_arrays.append(arr.reshape(M, N))
             in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
         else:
-            if int(np.prod(arr.shape)) != N:
-                return None
             extra_arrays.append(arr.reshape(1, N))
-            in_specs.append(
-                pl.BlockSpec((1, bn), lambda i, j, k: (i * 0, j)))
+            in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
 
     kernel = functools.partial(
         _mm_kernel, n_extras=len(extra_arrays), wt=wt,
         epilogue=tuple(epilogue), n_k=K // bk, out_dtype=out_dtype)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(M // bm, N // bn, K // bk),
         in_specs=in_specs,
@@ -283,9 +297,9 @@ def fused_matmul(x, w, extras=(), epilogue=(), wt=True, block_m=None,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel",
-                                        "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="fused_matmul",
     )(x, w, *extra_arrays)
 
 
@@ -337,8 +351,8 @@ def fused_batch_matmul(x, w, extras=(), epilogue=(), block_m=None,
                        interpret=False):
     """The batch_dot region: x (B, M, K) @ w (B, K, N) with a
     scalar/act/residual epilogue (vector steps belong to the dense
-    conv/FC path and are rejected here).  Returns (B, M, N) or None
-    when the shape has no usable tiling."""
+    conv/FC path and are declined here).  Returns (B, M, N) or None
+    when :func:`kernel_plan` declines."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -347,35 +361,24 @@ def fused_batch_matmul(x, w, extras=(), epilogue=(), block_m=None,
     B, M, K = x.shape
     N = w.shape[2]
     out_dtype = jnp.dtype(out_dtype or x.dtype)
-    if any(s[0] in ("bias", "vmul", "vadd") for s in epilogue):
+    tiles, _ = kernel_plan(
+        M, N, K, x.dtype, epilogue, [e.shape for e in extras], batch=B,
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=interpret)
+    if tiles is None:
         return None
-    block_m, block_n, block_k = resolve_blocks(
-        M, N, K, dtype=str(x.dtype), dtype_bytes=x.dtype.itemsize,
-        block_m=block_m, block_n=block_n, block_k=block_k)
-    picked = pick_blocks(M, N, K, block_m, block_n, block_k)
-    if picked is None:
-        return None
-    bm, bn, bk = picked
+    bm, bn, bk = tiles
 
-    extra_steps = _epilogue_extras(epilogue)
-    if len(extra_steps) != len(extras):
-        raise ValueError("fused_batch_matmul: %d extra inputs for %d "
-                         "extra-consuming steps"
-                         % (len(extras), len(extra_steps)))
     in_specs = [
         pl.BlockSpec((1, bm, bk), lambda b, i, j, k: (b, i, k)),
         pl.BlockSpec((1, bk, bn), lambda b, i, j, k: (b, k, j)),
-    ]
-    for step, arr in zip(extra_steps, extras):
-        if tuple(arr.shape) != (B, M, N):
-            return None
-        in_specs.append(
-            pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j)))
+    ] + [pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j))
+         for _ in extras]
 
     kernel = functools.partial(
         _bmm_kernel, n_extras=len(extras), epilogue=tuple(epilogue),
         n_k=K // bk, out_dtype=out_dtype)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(B, M // bm, N // bn, K // bk),
         in_specs=in_specs,
@@ -383,7 +386,8 @@ def fused_batch_matmul(x, w, extras=(), epilogue=(), block_m=None,
         out_shape=jax.ShapeDtypeStruct((B, M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("arbitrary", "parallel", "parallel",
-                                        "arbitrary")),
-    )(x, w, *extras)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel", "parallel",
+                                 "arbitrary")),
+        name="fused_batch_matmul",
+    )(x, w, *[e.reshape(B, M, N) for e in extras])

@@ -70,10 +70,7 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp",
     """
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_stages = mesh.shape[axis]
@@ -105,13 +102,4 @@ def _mark_varying(x, axes):
     match the varying-axes type of the loop body outputs)."""
     from jax import lax
 
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        try:
-            return pcast(x, axes, to="varying")
-        except TypeError:
-            pass
-    pvary = getattr(lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axes)
-    return x
+    return lax.pcast(x, axes, to="varying")

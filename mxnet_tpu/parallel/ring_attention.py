@@ -201,10 +201,7 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None,
     (tests on CPU).
     """
     import jax
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..config import get_flag
@@ -233,8 +230,8 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None,
                           use_flash=use_flash, interpret=interpret),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         # pallas_call has no shard_map replication rule; the flash body
-        # is per-device SPMD anyway, so skip the rep check there
-        check_rep=not use_flash)
+        # is per-device SPMD anyway, so skip the varying-axes check there
+        check_vma=not use_flash)
     from ..observability import counter, trace_span
 
     # host span = the whole sharded dispatch; per-ring-step attribution

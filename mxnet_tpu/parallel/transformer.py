@@ -319,6 +319,11 @@ class TransformerParallel:
 
                 host[k] = np.asarray(
                     multihost_utils.process_allgather(v, tiled=True))
+            if host[k].dtype.kind == "V":
+                # bfloat16 is not a dtype .npz can name (it comes back
+                # as raw void bytes); it round-trips exactly through
+                # fp32, and load_checkpoint casts to the model's dtype
+                host[k] = host[k].astype(np.float32)
         from .mesh import write_and_fence
 
         write_and_fence(
@@ -392,10 +397,7 @@ def _local_attention(q, k, v, mesh=None):
         ndp, ntp = axes.get("dp", 1), axes.get("tp", 1)
         sharded = {a for a, s in axes.items() if s > 1}
         if sharded <= {"dp", "tp"} and B % ndp == 0 and H % ntp == 0:
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             spec = P("dp" if ndp > 1 else None,
@@ -403,7 +405,7 @@ def _local_attention(q, k, v, mesh=None):
             fn = shard_map(
                 lambda q, k, v: flash_attention(q, k, v, causal=True),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_rep=False)
+                check_vma=False)
             return fn(q, k, v)
     from .ring_attention import attention_reference
 

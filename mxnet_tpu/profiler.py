@@ -222,12 +222,16 @@ def profiler_set_state(state="stop"):
         with _trace_lock:
             try:
                 jax.profiler.start_trace(trace_dir)
-            except Exception:  # device trace best-effort (tunnel backends)
-                trace_dir = None
+            except BaseException:
+                # no device trace, no session: a caller that asked for a
+                # profile must hear that it is not getting one
+                with _lock:
+                    _state["running"] = False
+                raise
             with _lock:
                 _state["trace_dir"] = trace_dir
                 still_running = _state["running"]
-        if trace_dir and not still_running:
+        if not still_running:
             # a concurrent stop won the race before our trace_dir was
             # visible to it; the stop is on us
             _stop_device_trace(jax)
@@ -241,10 +245,7 @@ def _stop_device_trace(jax):
         with _lock:
             trace_dir, _state["trace_dir"] = _state.get("trace_dir"), None
         if trace_dir:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            jax.profiler.stop_trace()
 
 
 def pause():

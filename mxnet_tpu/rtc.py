@@ -237,16 +237,7 @@ class PallasKernel(object):
             call = self._build_call(grid, in_arrays, out_shapes, scalars,
                                     interpret, n_in, n_out)
             self._calls[key] = call
-        # the package enables jax x64 globally (fp64 op parity); Mosaic's
-        # grid/index lowering wants i32 indices, so kernels trace with
-        # x64 scoped off (kernel dtypes come from the signature and are
-        # unaffected)
-        # jax.enable_x64 moved out of jax.experimental after 0.4.x
-        scoped_x64 = getattr(jax, "enable_x64", None)
-        if scoped_x64 is None:
-            from jax.experimental import enable_x64 as scoped_x64
-        with scoped_x64(False):
-            outs = call(*in_arrays, *seed_arrays)
+        outs = call(*in_arrays, *seed_arrays)
         if len(out_shapes) == 1:
             outs = (outs,)
         for nd_out, val in zip(out_nds, outs):
@@ -258,6 +249,8 @@ class PallasKernel(object):
         import functools
 
         from jax.experimental import pallas as pl
+
+        from .parallel.pallas_common import pallas_call
 
         user_fn = (functools.partial(self._fn, **scalars) if scalars
                    else self._fn)
@@ -290,7 +283,9 @@ class PallasKernel(object):
                                       or not isinstance(out_specs,
                                                         (list, tuple))
                                       else out_specs[0])
-        return pl.pallas_call(
+        # pallas_common.pallas_call: the kernel traces with x64 scoped
+        # off (kernel dtypes come from the signature and are unaffected)
+        return pallas_call(
             kernel,
             out_shape=(out_shapes if len(out_shapes) != 1
                        else out_shapes[0]),

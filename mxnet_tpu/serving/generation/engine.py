@@ -498,7 +498,14 @@ class Generator:
                 * self._draft_pool_dtype.itemsize)
         else:
             self.draft_bytes_per_token = 0
-        self._device = list(model.mesh.devices.flat)[0]
+        # fresh pools are placed exactly as a program returns them —
+        # replicated over the model's mesh. An aval carries its mesh, so
+        # a pool placed on a bare device would give the first warmed
+        # bucket a different jit key from the one traffic then presents
+        # (a program's output), and that bucket would compile twice
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        self._pool_sharding = NamedSharding(model.mesh, PartitionSpec())
         self._pools = self._fresh_pools()  # guarded-by: self._pages_lock
         if self._quant_kv:
             # provenance: crash dumps must say this engine's programs
@@ -594,7 +601,7 @@ class Generator:
                                    self._draft_pool_dtype)
             pools["dv"] = np.zeros(self._draft_pool_shape,
                                    self._draft_pool_dtype)
-        return jax.device_put(pools, self._device)
+        return jax.device_put(pools, self._pool_sharding)
 
     def _recover_pools(self, err):
         """After a FAILED donated prefill/decode call the old pool

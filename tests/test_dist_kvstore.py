@@ -3,15 +3,12 @@ tests/nightly/dist_sync_kvstore.py pattern: N local processes (here wired by
 jax.distributed over the CPU backend instead of ps-lite ZMQ), asserting
 dist_sync push/pull semantics and sync-SGD parity with single-process.
 
-These workers create SEVERAL dist stores per process on purpose: that was
-the seed's 2 tier-1 failures. Root cause (not a concurrency bug — triaged
-with graftlint G005/G006 over kvstore.py/kvstore_server.py, which came
-back clean here): jax<0.5 has no ``jax.distributed.is_initialized``, so
-``_ensure_distributed``'s idempotence guard silently vanished and the
-second ``mx.kv.create("dist_sync")`` re-ran ``initialize()`` after
-computations had executed ("must be called before any JAX computations").
-The guard now reads the client handle off ``jax._src.distributed
-.global_state``; the kv2/kv3/kv4/kv5 creates below are the regression."""
+These workers create SEVERAL dist stores per process on purpose: a second
+``mx.kv.create("dist_sync")`` must reuse the live client rather than re-run
+``initialize()`` after computations have executed ("must be called before
+any JAX computations"). ``_ensure_distributed`` guards on
+``jax.distributed.is_initialized()``; the kv2/kv3/kv4/kv5 creates below are
+the regression."""
 import os
 import sys
 import textwrap

@@ -233,23 +233,26 @@ def test_ring_attention_flash_path(causal):
         pytest.skip("needs >= 2 cpu devices")
     mesh = Mesh(np.array(jax.devices("cpu")[:n]), ("sp",))
     q, k, v = _qkv(B=2, H=4, T=32, D=8, seed=6)
+    # under jit, as the transformer step runs it: the eager shard_map
+    # interpreter executes the same ring op by op and takes ~7x as long
     with jax.default_matmul_precision("highest"):
-        out = ring_attention(q, k, v, mesh, causal=causal, use_flash=True,
-                             interpret=True)
+        def ring(q, k, v):
+            return ring_attention(q, k, v, mesh, causal=causal,
+                                  use_flash=True, interpret=True)
+
+        out = jax.jit(ring)(q, k, v)
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5)
 
         def ring_loss(q, k, v):
-            return jnp.sum(ring_attention(q, k, v, mesh, causal=causal,
-                                          use_flash=True,
-                                          interpret=True) ** 2)
+            return jnp.sum(ring(q, k, v) ** 2)
 
         def ref_loss(q, k, v):
             return jnp.sum(attention_reference(q, k, v,
                                                causal=causal) ** 2)
 
-        g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
+        g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
         g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

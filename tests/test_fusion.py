@@ -147,6 +147,9 @@ def test_fused_parity_fp32(name):
     assert regions and all(r["base_op"] in
                            ("Convolution", "FullyConnected", "dot",
                             "batch_dot") for r in regions)
+    # ... and says how each lowers, and why: the CPU composes the reference
+    assert all(r["lowering"] == "reference" and "cpu" in r["reason"]
+               for r in regions), regions
 
 
 @pytest.mark.parametrize("name", ["conv_residual", "transformer_block"])
@@ -154,10 +157,16 @@ def test_fused_kernel_path_parity(name, kernel_path, own_tune_cache):
     builder = ZOO[name]
     _sym, dshape, args, auxs, x = _materialize(builder)
     _m0, ref = _predict(builder, "default,-fuse", args, auxs, x, dshape)
-    _m1, fused = _predict(builder, "default", args, auxs, x, dshape)
+    m1, fused = _predict(builder, "default", args, auxs, x, dshape)
     # the Pallas kernel accumulates fp32 and applies the epilogue on the
     # accumulator — documented tolerance (docs/fusion.md)
     np.testing.assert_allclose(fused, ref, rtol=2e-5, atol=1e-5)
+    # the region report asks the same static decision the lowering did:
+    # some region took the kernel, and every reference one names a reason
+    regions = m1._exec_group.execs[0].fused_regions()
+    took_kernel = [r["name"] for r in regions if r["lowering"] == "kernel"]
+    assert bool(took_kernel) == (name == "transformer_block"), regions
+    assert all(r["reason"] for r in regions if r["lowering"] == "reference")
 
 
 def test_residual_region_carved():
@@ -359,11 +368,12 @@ def test_fused_matmul_tiling_fallback():
 
     # a dim SMALLER than the bound always tiles (the dim itself is a
     # divisor — one full block)
-    assert pick_blocks(97, 89, 101, 128, 128, 512) is not None
+    assert pick_blocks(97, 89, 101, 128, 128, 512) == ((97, 89, 101), None)
     # a prime dim LARGER than its bound has only tiny divisors: the
     # kernel declines and the op falls back to the unfused composition
     # (mid-trace safe, the flash-attention prime-T rule)
-    assert pick_blocks(1009, 89, 1013, 128, 128, 512) is None
+    tiles, why = pick_blocks(1009, 89, 1013, 128, 128, 512, interpret=True)
+    assert tiles is None and "M=1009" in why
     x = np.zeros((1009, 1013), np.float32)
     w = np.zeros((89, 1013), np.float32)
     assert fused_matmul(x, w, epilogue=(("act", "relu"),), wt=True,

@@ -276,10 +276,16 @@ def test_flash_attention_prime_seq_falls_back():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_transformer_parallel_checkpoint_resume(tmp_path):
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_transformer_parallel_checkpoint_resume(tmp_path, dtype):
     """tp/ep-sharded parameters checkpoint whole and reload onto the
-    mesh with identical continued training (sharded-state resume)."""
+    mesh with identical continued training (sharded-state resume) —
+    bfloat16 included, which .npz cannot name (first met on the chip:
+    the bf16 LM's checkpoint -> Generator handoff, PR 21)."""
     import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(dtype)
 
     from mxnet_tpu.parallel import TransformerParallel
     from mxnet_tpu.parallel.mesh import make_mesh
@@ -290,7 +296,7 @@ def test_transformer_parallel_checkpoint_resume(tmp_path):
     mesh = make_mesh({"dp": 1, "tp": 2, "ep": 2},
                      devices=jax.devices("cpu")[:4])
     tr = TransformerParallel(mesh, vocab=16, d_model=8, n_heads=2,
-                             n_layers=1, d_ff=16, n_experts=2)
+                             n_layers=1, d_ff=16, n_experts=2, dtype=dtype)
     params = tr.init(seed=1)
     tok_s, tgt_s = tr.shard_batch(toks, tgts)
     step = tr.step_fn(lr=0.2)
@@ -302,8 +308,9 @@ def test_transformer_parallel_checkpoint_resume(tmp_path):
         params, loss_ref = step(params, tok_s, tgt_s)
 
     tr2 = TransformerParallel(mesh, vocab=16, d_model=8, n_heads=2,
-                              n_layers=1, d_ff=16, n_experts=2)
+                              n_layers=1, d_ff=16, n_experts=2, dtype=dtype)
     resumed = tr2.load_checkpoint(path)
+    assert resumed["l0_wq"].dtype == dtype
     # shardings restored, not just values
     assert resumed["l0_wq"].sharding.spec == params["l0_wq"].sharding.spec
     step2 = tr2.step_fn(lr=0.2)

@@ -1,0 +1,169 @@
+"""Every Pallas kernel lowers — and compiles — for TPU from the CPU sandbox.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas TPU lowering —
+the block-shape rule (last two block dimensions divisible by 8 and 128, or
+the full dimension) lives there — without a chip. The shapes are
+chip_smoke.py's real ones plus the misaligned ones the old tile rule got
+wrong, so an (8,128) regression fails tier-1 on the CPU.
+
+Mosaic itself only runs when XLA compiles for a TPU, and some refusals are
+Mosaic's alone: under the package's global x64 a literal in an index map
+is an i64 and ``func.return (i32, i32, i64)`` "fails to legalize", which no
+lowering sees. libtpu is installed beside jax, so the last test compiles
+the kernels ahead of time for an abstract v5e
+(``jax.experimental.topologies``) — XLA:TPU and Mosaic, no chip. It is ONE
+test because libtpu admits one process at a time."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu.parallel.flash_attention import flash_attention
+from mxnet_tpu.parallel.fused import (fused_batch_matmul, fused_matmul,
+                                      kernel_plan)
+from mxnet_tpu.parallel.pallas_common import aligned_block
+
+
+def _tpu_calls(fn, *avals):
+    """Number of Mosaic custom calls in ``fn`` lowered for TPU."""
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    return exported.mlir_module().count("tpu_custom_call")
+
+
+def _aval(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 8, 2048, 64), "bfloat16"),   # the LM step (bench_all, chip_smoke)
+    ((1, 8, 1024, 64), "bfloat16"),   # chip_smoke's parity shape
+    ((1, 8, 128, 64), "bfloat16"),    # the smallest prefill bucket
+    ((2, 4, 100, 64), "float32"),     # misaligned, fits one block
+    ((1, 2, 1920, 64), "float32"),    # 15 x 128: no power-of-two block
+])
+def test_flash_kernels_lower_for_tpu(shape, dtype):
+    q = _aval(shape, dtype)
+    assert jax.config.jax_enable_x64  # the package default the kernels face
+    assert _tpu_calls(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                      q, q, q) == 1
+
+    def loss(q, k, v):
+        out, lse = flash_attention(q, k, v, causal=True, return_lse=True)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    # forward + dq + dk/dv — and not the dense formula
+    assert _tpu_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+
+
+def test_flash_declines_a_length_with_no_legal_block():
+    # 1000 > the backward bound 512 and no multiple of 128 divides it: a
+    # static decline to the dense formula, not a lowering error
+    q = _aval((1, 2, 1000, 64), "bfloat16")
+    assert _tpu_calls(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                      q, q, q) == 0
+
+
+# (M, K, N): ResNet-50 NHWC 1x1 convs at bs32/bs8 (chip_smoke's list,
+# thinned), M=392 the old rule tiled 98 x 512, and odd full-dim shapes
+@pytest.mark.parametrize("M,K,N", [
+    (100352, 64, 256), (100352, 256, 64), (25088, 512, 128),
+    (6272, 1024, 512), (1568, 2048, 512), (392, 512, 2048),
+    (392, 2048, 512), (97, 101, 89)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_fused_matmul_lowers_for_tpu(M, K, N, dtype):
+    epilogue = (("res", "elemwise_add"), ("act", "relu"))
+    tiles, why = kernel_plan(M, N, K, dtype, epilogue, [(M, N)])
+    assert tiles is not None, why
+    assert _tpu_calls(
+        lambda x, w, r: fused_matmul(x, w, extras=[r], epilogue=epilogue,
+                                     wt=False),
+        _aval((M, K), dtype), _aval((K, N), dtype),
+        _aval((M, N), dtype)) == 1
+
+
+@pytest.mark.parametrize("M", [32, 8])
+def test_classifier_is_declined_by_a_named_static_rule(M):
+    # N=1000 under the default 128 bound: the old rule tiled it 512 x 125
+    # and the lowering refused; now the plan declines before anything is
+    # built, fused_matmul returns None and the caller composes the reference
+    epilogue = (("bias",), ("act", "relu"))
+    tiles, why = kernel_plan(M, 1000, 2048, "bfloat16", epilogue, [(1000,)])
+    assert tiles is None and "N=1000" in why and "128" in why
+    x, w, b = (np.zeros(s, np.float32)
+               for s in ((M, 2048), (1000, 2048), (1000,)))
+    assert fused_matmul(x, w, extras=[b], epilogue=epilogue) is None
+    # under a bound that holds the whole dimension it is one legal block
+    assert _tpu_calls(
+        lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue,
+                                     block_n=1024),
+        _aval((M, 2048), "bfloat16"), _aval((1000, 2048), "bfloat16"),
+        _aval((1000,), "bfloat16")) == 1
+
+
+def test_fused_batch_matmul_lowers_for_tpu():
+    epilogue = (("scalar", "_mul_scalar", 0.125), ("res", "elemwise_add"))
+    B, M, K, N = 64, 512, 64, 512  # chip_smoke's attention-shaped batch
+    assert _tpu_calls(
+        lambda x, w, r: fused_batch_matmul(x, w, extras=[r],
+                                           epilogue=epilogue),
+        _aval((B, M, K), "bfloat16"), _aval((B, K, N), "bfloat16"),
+        _aval((B, M, N), "bfloat16")) == 1
+
+
+def test_aligned_block_rule():
+    assert aligned_block(392, 512, 8) == 392       # fits: the whole axis
+    assert aligned_block(392, 128, 8) == 56        # largest 8-multiple divisor
+    assert aligned_block(1000, 512, 128) is None   # no 128-multiple divides
+    assert aligned_block(2048, 1024, 128) == 1024
+    assert aligned_block(1920, 1024, 128) == 640
+    assert aligned_block(96, 32, 1) == 32          # the interpreter: any divisor
+    assert aligned_block(1009, 128, 1) is None     # prime: only tiny tiles
+
+
+def _abstract_v5e(monkeypatch):
+    """An abstract v5e device to compile for, or skip: libtpu describes
+    the topology without any chip attached."""
+    from jax.experimental import topologies
+
+    for key, value in (("TPU_SKIP_MDS_QUERY", "1"),
+                       ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                       ("TPU_WORKER_HOSTNAMES", "localhost")):
+        monkeypatch.setenv(key, value)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as err:  # no libtpu here, or another process holds it
+        pytest.skip("no abstract TPU topology: %s" % str(err)[:200])
+    return topo.devices[0]
+
+
+def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
+    from jax.sharding import SingleDeviceSharding
+
+    on_chip = SingleDeviceSharding(_abstract_v5e(monkeypatch))
+
+    def compiled_calls(fn, *avals):
+        avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip)
+                 for a in avals]
+        lowered = jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",))
+        return lowered.compile().as_text().count("tpu_custom_call")
+
+    assert jax.config.jax_enable_x64
+    q = _aval((1, 8, 1024, 64), "bfloat16")   # chip_smoke's parity shape
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) ** 2)
+
+    assert compiled_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+    epilogue = (("bias",), ("act", "relu"))
+    assert compiled_calls(
+        lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue),
+        _aval((392, 2048), "bfloat16"), _aval((512, 2048), "bfloat16"),
+        _aval((512,), "bfloat16")) == 1
+    res = (("scalar", "_mul_scalar", 0.125), ("res", "elemwise_add"))
+    assert compiled_calls(
+        lambda x, w, r: fused_batch_matmul(x, w, extras=[r], epilogue=res),
+        _aval((8, 512, 64), "bfloat16"), _aval((8, 64, 512), "bfloat16"),
+        _aval((8, 512, 512), "bfloat16")) == 1

@@ -111,18 +111,15 @@ def main():
         "gflops_per_sample_fwd": round(
             lstm_ptb_forward_flops() / 1e9, 3)}
 
-    # relate the recorded BENCH_ALL rates to the measured ceiling
+    # relate the rates of the last bench_all.py run to the measured
+    # ceiling. BENCH_ALL.json is that run's output, not a committed
+    # record: a fresh checkout has none, and the anchors then stand alone
     bench_path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "BENCH_ALL.json")
-    repo_bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              os.pardir, "BENCH_ALL.json")
-    for path in (bench_path, repo_bench):
-        if os.path.exists(path):
-            with open(path) as f:
-                recorded = json.load(f).get("configs", {})
-            break
-    else:
-        recorded = {}
+    recorded = {}
+    if os.path.exists(bench_path):
+        with open(bench_path) as f:
+            recorded = json.load(f).get("configs", {})
 
     def relate(key, cfg_key, g_per_item, train_mult):
         rate = recorded.get(cfg_key, {}).get("value")

@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """Trace analysis: top-K ops/phases by time from a profiler dump.
 
-The per-HLO time budget VERDICT.md's roofline ask demands, as a tool:
+The per-HLO time budget the round-5 review's roofline ask demands, as a
+tool:
 feed it any chrome://tracing JSON — the framework profiler's
 ``dump_profile()`` output, or the ``*.trace.json.gz`` the JAX/XLA
 profiler (XPlane) writes under ``<filename>_trace/`` — and it prints the
