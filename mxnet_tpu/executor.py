@@ -271,6 +271,8 @@ class _GraphProgram:
         node are device_put onto its assigned device first — the
         _CrossDeviceCopy insertion of the PlaceDevice pass; eager jax
         dispatch then runs the op on that device."""
+        from .observability.tracing import device_scope
+
         env = {}
         aux_updates = {}
         rng_i = [0]
@@ -310,8 +312,12 @@ class _GraphProgram:
             if opdef.needs_rng:
                 rng = rngs[rng_i[0]]
                 rng_i[0] += 1
-            outs, new_aux = opdef.apply(attrs, ins, auxs, is_train=is_train,
-                                        rng=rng)
+            # the reference profiler's per-operator naming
+            # (src/engine/profiler.cc): the node's name rides in the
+            # op_name of every HLO operation it lowers to
+            with device_scope(node.name):
+                outs, new_aux = opdef.apply(attrs, ins, auxs,
+                                            is_train=is_train, rng=rng)
             for i, o in enumerate(outs):
                 env[(id(node), i)] = o
                 if callback is not None:

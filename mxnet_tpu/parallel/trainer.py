@@ -168,6 +168,7 @@ class ShardedTrainer:
 
         prog = self._prog
         opt_opdef = self._opt_opdef
+        from ..observability import device_scope
         from ..ops.registry import OpAttrs
 
         def step(params, aux, opt_state, batch, lr, step_i):
@@ -180,7 +181,8 @@ class ShardedTrainer:
             def loss_fn(p):
                 arg_d = dict(batch)
                 arg_d.update(p)
-                outs, aux_upd = prog._eval(arg_d, aux, rngs, True)
+                with device_scope("forward"):
+                    outs, aux_upd = prog._eval(arg_d, aux, rngs, True)
                 return tuple(outs), aux_upd
 
             from ..executor import _maybe_mirror
@@ -192,16 +194,17 @@ class ShardedTrainer:
 
             new_params = {}
             new_opt = {}
-            for name in self.param_names:
-                w, g = params[name], grads[name]
-                states = opt_state[name]
-                (new_w,), new_states = opt_opdef.apply(
-                    opt_attrs, (w, g.astype(w.dtype)), states)
-                # keep the carried weight dtype stable (bf16 weights with
-                # fp32 optimizer state = the mp_sgd master-copy pattern,
-                # src/operator/optimizer_op.cc mp_sgd_update)
-                new_params[name] = new_w.astype(w.dtype)
-                new_opt[name] = tuple(new_states)
+            with device_scope("update"):
+                for name in self.param_names:
+                    w, g = params[name], grads[name]
+                    states = opt_state[name]
+                    (new_w,), new_states = opt_opdef.apply(
+                        opt_attrs, (w, g.astype(w.dtype)), states)
+                    # keep the carried weight dtype stable (bf16 weights
+                    # with fp32 optimizer state = the mp_sgd master-copy
+                    # pattern, src/operator/optimizer_op.cc mp_sgd_update)
+                    new_params[name] = new_w.astype(w.dtype)
+                    new_opt[name] = tuple(new_states)
             new_aux = dict(aux)
             new_aux.update(aux_upd)
             return new_params, new_aux, new_opt, outs
@@ -248,20 +251,25 @@ class ShardedTrainer:
         scan as a per-step vector)."""
         import numpy as np
 
-        key = ("multi", n_steps)
-        if not hasattr(self, "_multi_fns"):
-            self._multi_fns = {}
-        if key not in self._multi_fns:
-            self._multi_fns[key] = self._build_multi_step(n_steps)
+        from ..observability import trace_span
+
         step0 = state["step"]
-        lrs = np.asarray(
-            [self._lr(step0 + i) if callable(self._lr) else self._lr
-             for i in range(n_steps)], dtype=np.float32)
-        params, aux, opt, outs = self._multi_fns[key](
-            state["params"], state["aux"], state["opt"], batch,
-            lrs, np.int32(step0))
-        return ({"params": params, "aux": aux, "opt": opt,
-                 "step": step0 + n_steps}, outs)
+        with trace_span("sharded_trainer.multi_step", "parallel",
+                        step=step0, steps=n_steps):
+            key = ("multi", n_steps)
+            if not hasattr(self, "_multi_fns"):
+                self._multi_fns = {}
+            if key not in self._multi_fns:
+                self._multi_fns[key] = self._build_multi_step(n_steps)
+            lrs = np.asarray(
+                [self._lr(step0 + i) if callable(self._lr) else self._lr
+                 for i in range(n_steps)], dtype=np.float32)
+            with trace_span("sharded_trainer.enqueue", "parallel"):
+                params, aux, opt, outs = self._multi_fns[key](
+                    state["params"], state["aux"], state["opt"], batch,
+                    lrs, np.int32(step0))
+            return ({"params": params, "aux": aux, "opt": opt,
+                     "step": step0 + n_steps}, outs)
 
     def lower_step(self, state, batch):
         """``jax.jit(...).lower(...)`` of the fused train step, for HLO
@@ -278,14 +286,20 @@ class ShardedTrainer:
         """Run one training step; returns (new_state, outputs).
 
         ``batch``: dict of sharded arrays from :meth:`shard_batch`."""
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
-        lr = self._lr(state["step"]) if callable(self._lr) else self._lr
-        params, aux, opt, outs = self._step_fn(
-            state["params"], state["aux"], state["opt"], batch,
-            np.float32(lr), np.int32(state["step"]))
-        return ({"params": params, "aux": aux, "opt": opt,
-                 "step": state["step"] + 1}, outs)
+        from ..observability import trace_span
+
+        with trace_span("sharded_trainer.step", "parallel",
+                        step=state["step"]):
+            if self._step_fn is None:
+                with trace_span("sharded_trainer.build", "parallel"):
+                    self._step_fn = self._build_step()
+            lr = self._lr(state["step"]) if callable(self._lr) else self._lr
+            with trace_span("sharded_trainer.enqueue", "parallel"):
+                params, aux, opt, outs = self._step_fn(
+                    state["params"], state["aux"], state["opt"], batch,
+                    np.float32(lr), np.int32(state["step"]))
+            return ({"params": params, "aux": aux, "opt": opt,
+                     "step": state["step"] + 1}, outs)
 
     # --- checkpoint / resume ------------------------------------------------
     def save_checkpoint(self, state, prefix, epoch=0):

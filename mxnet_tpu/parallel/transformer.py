@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..observability import device_scope, trace_span
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerParallel"]
@@ -41,6 +42,7 @@ class TransformerParallel:
         self.axes = set(mesh.axis_names)
         self._step_jit = None   # ONE compiled step; lr is a traced arg
         self._step_cache = {}   # lr -> binding wrapper (identity-stable)
+        self._step_calls = 0    # numbers the transformer.step spans
 
     # --- sharding helpers -------------------------------------------------
     def _ns(self, *spec):
@@ -125,24 +127,28 @@ class TransformerParallel:
         c = self.cfg
         B, T = tokens.shape
         d = c["d_model"]
-        x = params["embed"][tokens]  # (B, T, d)
+        with device_scope("embed"):
+            x = params["embed"][tokens]  # (B, T, d)
         for li in range(c["n_layers"]):
             p = "l%d_" % li
             # --- attention, heads split on tp, sequence ring on sp ------
-            q, k, v = self._qkv(params, p, _rms_norm(x))
-            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-            if "sp" in self.axes and self.mesh.shape.get("sp", 1) > 1:
-                att = ring_attention(
-                    q, k, v, self.mesh, axis="sp", causal=True,
-                    head_axis="tp" if "tp" in self.axes else None,
-                    batch_axis="dp" if "dp" in self.axes else None)
-            else:
-                att = _local_attention(q, k, v, self.mesh)
-            att = att.transpose(0, 2, 1, 3).reshape(B, T, d)
-            x = x + att @ params[p + "wo"]
+            with device_scope("l%d/attn" % li):
+                q, k, v = self._qkv(params, p, _rms_norm(x))
+                q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+                if "sp" in self.axes and self.mesh.shape.get("sp", 1) > 1:
+                    att = ring_attention(
+                        q, k, v, self.mesh, axis="sp", causal=True,
+                        head_axis="tp" if "tp" in self.axes else None,
+                        batch_axis="dp" if "dp" in self.axes else None)
+                else:
+                    att = _local_attention(q, k, v, self.mesh)
+                att = att.transpose(0, 2, 1, 3).reshape(B, T, d)
+                x = x + att @ params[p + "wo"]
             # --- MoE FFN: soft top-2-ish gate over ep-sharded experts ---
-            x = x + self._moe_ffn(params, p, x)
-        logits = _rms_norm(x) @ params["out_w"]
+            with device_scope("l%d/ffn" % li):
+                x = x + self._moe_ffn(params, p, x)
+        with device_scope("head_loss"):
+            logits = _rms_norm(x) @ params["out_w"]
         return logits
 
     # --- incremental decode (generation subsystem) ------------------------
@@ -255,10 +261,11 @@ class TransformerParallel:
         import jax.numpy as jnp
 
         logits = self._forward(params, tokens)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1)[..., 0]
-        return jnp.mean(nll)
+        with device_scope("head_loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+            return jnp.mean(nll)
 
     # --- compiled train step ----------------------------------------------
     def step_fn(self, lr=0.1):
@@ -279,8 +286,9 @@ class TransformerParallel:
             def step(params, tokens, targets, lr):
                 loss, grads = jax.value_and_grad(self.loss_fn)(
                     params, tokens, targets)
-                new_params = {k: (params[k] - lr * grads[k]).astype(
-                    params[k].dtype) for k in params}
+                with device_scope("update"):
+                    new_params = {k: (params[k] - lr * grads[k]).astype(
+                        params[k].dtype) for k in params}
                 return new_params, loss
 
             self._step_jit = jax.jit(
@@ -290,7 +298,11 @@ class TransformerParallel:
             step_jit = self._step_jit
 
             def bound(params, tokens, targets, _lr=lr):
-                return step_jit(params, tokens, targets, _lr)
+                with trace_span("transformer.step", "parallel",
+                                step=self._step_calls):
+                    self._step_calls += 1
+                    with trace_span("transformer.enqueue", "parallel"):
+                        return step_jit(params, tokens, targets, _lr)
 
             self._step_cache[lr] = bound
         return self._step_cache[lr]

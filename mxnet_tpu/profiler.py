@@ -13,13 +13,20 @@ Two complementary layers here:
    profiling, eager ops run synchronously (block_until_ready) so
    durations mean compute, not dispatch — the reference's profiler
    measures inside the engine worker the same way. Framework *phase
-   spans* (observability.trace_span: fit-loop forward/backward/update,
-   trainer step, kvstore push/pull) record in ANY mode while the session
-   runs — phases are not ops, so the mode split does not gate them.
+   spans* (observability.trace_span: trainer step and enqueue, fit-loop
+   forward/backward/update, kvstore push/pull) record in ANY mode while
+   the session runs, and also whenever telemetry is enabled — phases
+   are not ops, so the mode split does not gate them. These events
+   carry ``perf_counter`` timestamps: the chrome JSON is the host's
+   timeline alone.
 2. **XLA device trace** — set_state('run') also starts the JAX/XLA
-   profiler (XPlane → TensorBoard/Perfetto) in ``<filename>_trace/``
-   for kernel-level device timing; ``tools/trace_report.py`` reads the
-   ``*.trace.json.gz`` it contains.
+   profiler in ``<filename>_trace/``. Its ``.xplane.pb`` holds the
+   device's operations AND every ``trace_span`` (as a
+   ``TraceAnnotation`` in the host plane) on one clock, with the
+   ``device_scope`` labels in each operation's ``op_name``:
+   ``tools/trace_report.py <dir>`` reads it for device time by phase
+   and scope and for the host span over each idle gap (and still reads
+   the ``*.trace.json.gz`` beside it for the top-K table).
 
 The initial mode can be set from the environment (``MXNET_PROFILER_MODE``)
 so unmodified scripts can be traced. All state transitions take the
@@ -81,8 +88,8 @@ def symbolic_active():
 
 
 def spans_active():
-    """Phase spans (observability.trace_span) record in any mode while
-    the session runs."""
+    """Whether a session runs: phase spans (observability.trace_span)
+    record in any mode while it does (and while telemetry is enabled)."""
     return _state["running"] and not _state["paused"]
 
 
@@ -150,6 +157,17 @@ def _append(ev):
         _note_dropped_metric(1)
 
 
+def _note_pid():
+    global _pid
+    _pid = os.getpid()
+
+
+# getpid is a system call (5 us on a sandboxed host): asked once, and
+# again in a forked child
+_note_pid()
+os.register_at_fork(after_in_child=_note_pid)
+
+
 def record(name, cat, ts_us, dur_us, args=None, tid=None):
     """Append one complete ('ph':'X') event. ``args`` rides into the
     chrome JSON verbatim (request tracing stores trace ids there);
@@ -157,7 +175,7 @@ def record(name, cat, ts_us, dur_us, args=None, tid=None):
     completion replays spans onto the threads where they happened)."""
     ev = {"name": name, "cat": cat, "ph": "X",
           "ts": ts_us, "dur": dur_us,
-          "pid": os.getpid(),
+          "pid": _pid,
           "tid": (threading.get_ident() % (1 << 20)
                   if tid is None else int(tid))}
     if args:

@@ -12,6 +12,19 @@ command:
 
     python tools/trace_report.py profile.json
     python tools/trace_report.py profile_trace/           # XPlane dir
+    python tools/trace_report.py profile_trace/ --device  # by phase/scope
+    python tools/trace_report.py --cell lm_train_t2048_b2 --steps 4
+
+``--device`` reads the ``.xplane.pb`` the JAX profiler writes (the one
+input that carries the program's scopes and the operations' stats):
+device time by phase (forward / backward / update / unscoped, from the
+``device_scope`` labels in each operation's ``op_name``) and by scope
+(graph node, or ``l*/attn`` folded over layers), the costliest
+operations with their share of the roofline where the trace carries
+FLOPs and bytes, and the longest device idle gaps, each named by the
+innermost ``trace_span`` that covers it. ``--cell`` builds a cell of
+``BENCHMARK.json`` through ``perfbench.drivers``, traces a few steps of
+it under ``mx.profiler.set_state('run')`` and prints that report.
     python tools/trace_report.py profile.json --cat operator -k 20
     python tools/trace_report.py --compare before.json after.json
 
@@ -38,6 +51,7 @@ import glob
 import gzip
 import json
 import os
+import re
 import sys
 
 
@@ -583,6 +597,384 @@ def format_input_pipeline(rows, path):
     return "\n".join(lines)
 
 
+# ------------------------------------------------- device trace (.xplane.pb)
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_ROOT = os.path.dirname(_HERE)
+# transforms JAX wraps around a scope's name in op_name:
+# jit(step)/transpose(jvp(forward))/bn0/dot_general
+_WRAPPED = re.compile(r"\b(jvp|transpose|checkpoint|remat|rematted_"
+                      r"computation|vmap|custom_jvp|custom_vjp|p?jit)"
+                      r"\(([^()]*)\)")
+PHASES = ("forward", "backward", "update", "unscoped")
+
+
+def split_op_name(op_name):
+    """(phase, scope) of an HLO ``op_name`` path. ``transpose(...)`` is
+    the backward pass (a recomputed forward operation under a
+    checkpoint sits inside it and counts there), ``jvp(...)`` the
+    forward, a leading ``update`` scope the optimizer; the scope is the
+    path less the jitted function's name, the transform wrappers, a
+    leading ``forward`` and the primitive's own name."""
+    path = (op_name or "").split(";")[0]
+    if "transpose(" in path:
+        phase = "backward"
+    elif "jvp(" in path:
+        phase = "forward"
+    else:
+        phase = None
+    jitted = re.match(r"p?jit\(", path) is not None
+    while True:
+        path, n = _WRAPPED.subn(r"\2", path)
+        if not n:
+            break
+    segs = [seg for seg in path.split("/") if seg][jitted:-1]
+    if segs[:1] == ["forward"]:
+        segs = segs[1:]
+    if phase is None:
+        phase = "update" if segs[:1] == ["update"] else "unscoped"
+    if phase == "unscoped":
+        return phase, ""
+    return phase, "/".join(segs) or "(no scope)"
+
+
+def program_scope(scope):
+    """The part of a scope path the program itself named: a graph node
+    (``bn0``), or ``l17/attn`` as ``l*/attn`` (one row for the same scope
+    of every layer); what jax.numpy adds below it (an einsum's spec,
+    ``log_softmax``, a kernel's name) is left to the per-operation rows."""
+    layer = re.match(r"l\d+/([^/]+)", scope)
+    return "l*/" + layer.group(1) if layer else scope.split("/")[0]
+
+
+def find_xplane(path):
+    """The newest ``.xplane.pb`` under a directory (or the file itself);
+    None where there is none."""
+    if os.path.isfile(path):
+        return path if path.endswith(".xplane.pb") else None
+    found = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def _trace_reduce():
+    """perfbench.trace_reduce (its interval arithmetic is the one copy)."""
+    if _ROOT not in sys.path:
+        sys.path.insert(0, _ROOT)
+    from perfbench import trace_reduce
+
+    return trace_reduce
+
+
+def _pb_varint(buf, i):
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if not byte & 0x80:
+            return value, i
+
+
+def _pb_fields(buf):
+    """(field number, value) pairs of one protobuf message: an int for a
+    varint, a memoryview for a length-delimited or fixed field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _pb_varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            value, i = _pb_varint(buf, i)
+        else:
+            if wire == 2:
+                size, i = _pb_varint(buf, i)
+            elif wire in (1, 5):
+                size = 8 if wire == 1 else 4
+            else:
+                raise ValueError("wire type %d in an .xplane.pb" % wire)
+            value = buf[i:i + size]
+            i += size
+        yield field, value
+
+
+# the stats the TPU profiler keeps per *kind* of event (XEventMetadata),
+# which jax.profiler.ProfileData does not hand out with the events
+METADATA_STATS = ("tf_op", "flops", "bytes_accessed", "hlo_category")
+
+
+def metadata_stats(path):
+    """{plane name: {event name: {stat: value}}} for METADATA_STATS, read
+    from the file's wire format (tsl/profiler/protobuf/xplane.proto:
+    XSpace.planes=1; XPlane.name=2, .event_metadata=4, .stat_metadata=5;
+    XEventMetadata.name=2, .stats=5; XStat.metadata_id=1, .uint64=3,
+    .int64=4, .str=5; XStatMetadata.id=1, .name=2)."""
+    import struct
+
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out = {}
+    for field, plane in _pb_fields(space):
+        if field != 1:
+            continue
+        name, stat_names, metas = "", {}, []
+        for field, value in _pb_fields(plane):
+            if field == 2:
+                name = bytes(value).decode()
+            elif field == 5:
+                entry = dict(_pb_fields(dict(_pb_fields(value))[2]))
+                if bytes(entry.get(2, b"")).decode() in METADATA_STATS:
+                    stat_names[entry.get(1, 0)] = bytes(entry[2]).decode()
+            elif field == 4:
+                metas.append(dict(_pb_fields(value))[2])
+        if not name.startswith("/device:"):
+            continue
+        events = out[name] = {}
+        for meta in metas:
+            ev_name, stats = "", {}
+            for field, value in _pb_fields(meta):
+                if field == 2:
+                    ev_name = bytes(value).decode(errors="replace")
+                elif field == 5:
+                    stat = dict(_pb_fields(value))
+                    key = stat_names.get(stat.get(1))
+                    if key is None:
+                        continue
+                    if 5 in stat:
+                        stats[key] = bytes(stat[5]).decode(errors="replace")
+                    elif 2 in stat:
+                        stats[key] = struct.unpack("<d", stat[2])[0]
+                    else:
+                        stats[key] = stat.get(4, stat.get(3, 0))
+            if stats:
+                events[ev_name] = stats
+    return out
+
+
+def load_xplane(path):
+    """``{"devices": {plane name: [event, ...]}, "host": [event, ...]}``
+    of an ``.xplane.pb``, read with ``jax.profiler.ProfileData``. A
+    device event is a chrome-style dict (``name``, ``ts``/``dur`` in us,
+    ``pid``, ``tid``) with the operation's stats under ``args``; a host
+    event likewise, for every annotation of the host plane (the Python
+    tracer's ``$file:line`` frames left out)."""
+    import jax
+
+    ops_line = _trace_reduce().OPS_LINE
+    path = find_xplane(path)
+    data = jax.profiler.ProfileData.from_file(path)
+    by_kind = metadata_stats(path)
+    devices, host = {}, []
+    for plane in data.planes:
+        is_device = plane.name.startswith("/device:")
+        if not is_device and not plane.name.startswith("/host:"):
+            continue
+        kinds = by_kind.get(plane.name, {})
+        for line in plane.lines:
+            if is_device and line.name != ops_line:
+                continue
+            out = devices.setdefault(plane.name, []) if is_device else host
+            for e in line.events:
+                if not is_device and e.name.startswith("$"):
+                    continue
+                out.append({"name": e.name, "ph": "X",
+                            "ts": e.start_ns / 1e3,
+                            "dur": e.duration_ns / 1e3,
+                            "pid": plane.name, "tid": line.name,
+                            "args": dict(kinds.get(e.name, ()),
+                                         **dict(e.stats))})
+    return {"devices": devices, "host": host}
+
+
+def event_op_name(ev):
+    """The ``op_name`` path of a device event: on this libtpu the
+    ``tf_op`` stat of the event's kind, written ``<op_name>:<op type>``."""
+    return (ev["args"].get("tf_op") or "").rsplit(":", 1)[0]
+
+
+def _innermost(host, lo, hi):
+    """Name of the innermost host event that covers [lo, hi] us."""
+    best = None
+    for ev in host:
+        if ev["ts"] <= lo and ev["ts"] + ev["dur"] >= hi and (
+                best is None or ev["dur"] < best["dur"]):
+            best = ev
+    return best["name"] if best else None
+
+
+def device_report(trace, peaks=None, k=10):
+    """The report of one device plane (the first): busy time, its split
+    by phase and by scope, the costliest operations, what is unscoped by
+    kind of operation, the copies by owner, and the longest idle gaps."""
+    reduce = _trace_reduce()
+    if not trace["devices"]:
+        raise SystemExit("trace_report: the trace holds no device plane "
+                         "with an %r line (a CPU trace has none)"
+                         % reduce.OPS_LINE)
+    plane = sorted(trace["devices"])[0]
+    events = sorted(trace["devices"][plane], key=lambda e: e["ts"])
+    lo = events[0]["ts"]
+    hi = max(e["ts"] + e["dur"] for e in events)
+    busy, gaps = reduce.busy_union([(e["name"], e["ts"], e["dur"])
+                                    for e in events], lo, hi)
+    # an operation that encloses others (a while loop and its body) keeps
+    # only its own time, so that the rows sum to the busy time
+    selfs = _self_times(events)
+    where = [split_op_name(event_op_name(ev)) for ev in events]
+    # a copy the compiler put in carries no scope: its owner is the next
+    # scoped operation on the device, the one it moves data for
+    owner, owners = ("unscoped", ""), []
+    for at in reversed(where):
+        owner = at if at[0] != "unscoped" else owner
+        owners.append(owner)
+    owners.reverse()
+    phases = dict.fromkeys(PHASES, 0.0)
+    scopes, ops, unscoped, copies = {}, {}, {}, {}
+    for ev, (phase, scope), owner in zip(events, where, owners):
+        own = max(selfs[id(ev)], 0.0)
+        phases[phase] += own
+        name = reduce.short_name(ev["name"])
+        kind = re.sub(r"[.\d]+$", "", name.split(" ")[0])
+        if phase == "unscoped":
+            unscoped[kind] = unscoped.get(kind, 0.0) + own
+        else:
+            key = (phase, program_scope(scope))
+            scopes[key] = scopes.get(key, 0.0) + own
+        if kind.startswith("copy"):
+            key = (owner[0], program_scope(owner[1]),
+                   "own scope" if phase != "unscoped" else "next scoped")
+            copies[key] = copies.get(key, 0.0) + own
+        row = ops.setdefault(name, {"us": 0.0, "count": 0, "flops": 0,
+                                    "bytes": 0, "phase": phase,
+                                    "scope": scope})
+        row["us"] += own
+        row["count"] += 1
+        row["flops"] += ev["args"].get("flops") or 0
+        row["bytes"] += ev["args"].get("bytes_accessed") or 0
+    top = sorted(ops.items(), key=lambda kv: -kv[1]["us"])[:k]
+    for _, row in top:
+        row["roofline_pct"] = row["binds"] = None
+        if peaks and (row["flops"] or row["bytes"]) and row["us"]:
+            t_flops = row["flops"] / peaks["bf16_flops_per_s"]
+            t_bytes = row["bytes"] / peaks["hbm_bytes_per_s"]
+            row["roofline_pct"] = 100.0 * max(t_flops, t_bytes) / (
+                row["us"] / 1e6)
+            row["binds"] = "flops" if t_flops >= t_bytes else "bytes"
+    spans = [e for e in trace["host"] if "cat" in e["args"]]
+    named = []
+    for a, b in sorted(gaps, key=lambda g: g[0] - g[1])[:5]:
+        inner = _innermost(spans, a, b)
+        named.append({"us": b - a, "at_us": a - lo,
+                      "span": inner or "no program span (%s)" % (
+                          _innermost(trace["host"], a, b)
+                          or "no annotation")})
+
+    def ranked(table):
+        return sorted(table.items(), key=lambda kv: -kv[1])[:k]
+
+    return {"plane": plane, "window_us": hi - lo, "busy_us": busy,
+            "phases_us": phases, "scopes_us": ranked(scopes), "ops": top,
+            "unscoped_us": ranked(unscoped), "copies_us": ranked(copies),
+            "gaps": named,
+            "program_spans": sorted({e["name"] for e in spans})}
+
+
+def format_device_report(rep, path):
+    busy = rep["busy_us"] or 1.0
+    lines = ["# device trace - %s (%s)" % (path, rep["plane"]),
+             "window %.3f ms, busy %.3f ms (idle %.4f%%)" % (
+                 rep["window_us"] / 1e3, busy / 1e3,
+                 100.0 * (1 - busy / rep["window_us"])),
+             "", "# device time by phase",
+             "%-10s %12s %8s" % ("phase", "ms", "% busy")]
+    for phase in PHASES:
+        us = rep["phases_us"][phase]
+        lines.append("%-10s %12.3f %8.2f" % (phase, us / 1e3,
+                                             100.0 * us / busy))
+    total = sum(rep["phases_us"].values())
+    lines.append("%-10s %12.3f %8.2f" % ("sum", total / 1e3,
+                                         100.0 * total / busy))
+    lines += ["", "# costliest scopes", "%-10s %-36s %12s %8s" % (
+        "phase", "scope", "ms", "% busy")]
+    for (phase, scope), us in rep["scopes_us"]:
+        lines.append("%-10s %-36s %12.3f %8.2f" % (
+            phase, scope[:36], us / 1e3, 100.0 * us / busy))
+    lines += ["", "# unscoped operations by kind",
+              "%-36s %12s %8s" % ("kind", "ms", "% busy")]
+    for kind, us in rep["unscoped_us"]:
+        lines.append("%-36s %12.3f %8.2f" % (kind[:36], us / 1e3,
+                                             100.0 * us / busy))
+    lines += ["", "# copies by owner (their own scope, or the next scoped "
+              "operation's)", "%-10s %-36s %-12s %10s %8s" % (
+                  "phase", "scope", "from", "ms", "% busy")]
+    for (phase, scope, how), us in rep["copies_us"]:
+        lines.append("%-10s %-36s %-12s %10.3f %8.2f" % (
+            phase, scope[:36], how, us / 1e3, 100.0 * us / busy))
+    lines += ["", "# costliest operations (roofline against "
+              "perfbench/peaks.json; flops and bytes are XLA's estimate "
+              "of the", "# operation, operands reused on chip counted "
+              "again: a share over 100% says so)",
+              "%-44s %6s %10s %-9s %-22s %12s %12s %9s %6s" % (
+                  "operation", "count", "ms", "phase", "scope", "flops",
+                  "bytes", "roofline%", "binds")]
+    for name, row in rep["ops"]:
+        has = row["flops"] or row["bytes"]
+        lines.append("%-44s %6d %10.3f %-9s %-22s %12s %12s %9s %6s" % (
+            name[:44], row["count"], row["us"] / 1e3, row["phase"],
+            row["scope"][:22],
+            "%.3e" % row["flops"] if has else "not in trace",
+            "%.3e" % row["bytes"] if has else "not in trace",
+            "-" if row["roofline_pct"] is None
+            else "%.1f" % row["roofline_pct"], row["binds"] or "-"))
+    lines += ["", "# longest device idle gaps, by the innermost program "
+              "span over each", "%12s %14s  %s" % ("us", "at ms", "span")]
+    for gap in rep["gaps"]:
+        lines.append("%12.1f %14.3f  %s" % (gap["us"], gap["at_us"] / 1e3,
+                                            gap["span"]))
+    lines += ["", "program spans in the host plane: %s" % (
+        ", ".join(rep["program_spans"]) or "none")]
+    return "\n".join(lines)
+
+
+def load_peaks(device_kind=None):
+    """The row of perfbench/peaks.json for a device kind (the only row
+    where none is given: a trace file does not name its chip)."""
+    with open(os.path.join(_ROOT, "perfbench", "peaks.json")) as f:
+        table = json.load(f)
+    if device_kind is None and len(table) == 1:
+        return next(iter(table.values()))
+    return table.get(device_kind)
+
+
+def trace_cell(name, steps, out_dir):
+    """Build a cell of BENCHMARK.json through its perfbench driver, run
+    3 warm steps and ``steps`` traced ones of the harness's own loop
+    under ``mx.profiler.set_state('run')``; returns (trace directory,
+    the chip's peaks)."""
+    import importlib
+
+    _trace_reduce()  # the checkout's root on sys.path
+    import jax
+
+    import mxnet_tpu as mx
+    from perfbench import run as harness
+
+    _, entry, workload, config = harness.load_cell(name, False)
+    mx.config.enable_compile_cache()
+    mx.observability.set_enabled(True)
+    driver = importlib.import_module("perfbench.drivers." + config["driver"])
+    cell = driver.build(config, workload["sizes"], 0,
+                        jax.devices()[:entry["chips"]])
+    harness.drive(cell, 0, steps=3)
+    mx.profiler.set_config(filename=os.path.join(out_dir, "profile.json"))
+    mx.profiler.set_state("run")
+    try:
+        harness.drive(cell, 3, steps=steps, annotate=True)
+    finally:
+        mx.profiler.set_state("stop")
+    return (os.path.join(out_dir, "profile_trace"),
+            load_peaks(jax.devices()[0].device_kind))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="top-K op/phase time report from a chrome/XPlane trace")
@@ -631,9 +1023,32 @@ def main(argv=None):
                          "flight-recorder dump (per-stage wait/occupancy "
                          "of the streaming input pipeline + the "
                          "input-bound vs compute-bound verdict)")
+    ap.add_argument("--device", action="store_true",
+                    help="read the .xplane.pb under the trace path: device "
+                         "time by phase and scope, costliest operations "
+                         "against the roofline, idle gaps by program span")
+    ap.add_argument("--cell", metavar="NAME",
+                    help="build this cell of BENCHMARK.json through "
+                         "perfbench.drivers, trace --steps steps of it "
+                         "under mx.profiler and print the --device report")
+    ap.add_argument("--steps", type=int, default=4,
+                    help="with --cell: steps to trace (after 3 warm ones)")
+    ap.add_argument("--out", default="trace_report_out",
+                    help="with --cell: where the trace is written")
     ap.add_argument("--json", action="store_true",
                     help="emit rows as JSON instead of a table")
     args = ap.parse_args(argv)
+
+    if args.cell or args.device:
+        peaks = load_peaks()
+        if args.cell:
+            args.trace, peaks = trace_cell(args.cell, args.steps, args.out)
+        elif not args.trace or not find_xplane(args.trace):
+            ap.error("--device needs a trace path with an .xplane.pb")
+        rep = device_report(load_xplane(args.trace), peaks, k=args.top_k)
+        print(json.dumps(rep, indent=1) if args.json
+              else format_device_report(rep, args.trace))
+        return 0
 
     if args.roofline or args.waterfall:
         try:
