@@ -73,9 +73,11 @@ def _dtype_bytes(ctx):
     return int(ctx.get("dtype_bytes", 2))  # bf16 default
 
 
-def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False):
-    """Live VMEM of one grid step (input tiles double-buffered by the
-    pipeline, fp32 accumulators single-buffered)."""
+def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None):
+    """Live VMEM of one grid step (input and output tiles double-buffered
+    by the pipeline, fp32 accumulators single-buffered). With ``T``, the
+    fused backward: the whole head's fp32 dq scratch (T, D) and its
+    resident (1, T, D) output block on top of the dk/dv pass's tiles."""
     db = dtype_bytes
     if not backward:
         tiles = (bq * D * db          # q
@@ -83,18 +85,38 @@ def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False):
                  + bq * D * db)       # out
         scratch = bq * D * 4 + 2 * bq * 4      # acc, m, l (fp32)
     else:
-        # worst of the two passes: dkv holds q/k/v/do tiles + two fp32
-        # accumulators; dq holds the same tiles + one accumulator
+        # the dk/dv pass (the dq pass holds one accumulator fewer)
         tiles = (2 * bq * D * db      # q, do
-                 + 2 * bk * D * db    # k, v
+                 + 4 * bk * D * db    # k, v, dk, dv
                  + 2 * bq * 4)        # lse, delta rows
         scratch = 2 * bk * D * 4      # dk_acc, dv_acc
-    # block score/probability tile s/p: (bq, bk) fp32 intermediates
-    inter = bq * bk * 4 * (2 if backward else 1)
+        if T is not None:
+            tiles += T * D * db       # dq out
+            scratch += T * D * 4      # dq_acc
+    # score/probability intermediates, fp32: the backward's (bq, bk) s^T
+    # and dp^T; the forward works its tile through 256 rows at a time
+    inter = (bq if backward else min(bq, 256)) * bk * 4 * 2
     return 2 * tiles + scratch + inter
 
 
+def _live_tiles(n_q, n_k, bq, bk, causal, grain=None):
+    """Score tiles a pass computes: all, or those a causal diagonal
+    leaves live (last query row of the tile >= its first key). Square
+    tiles ON the diagonal are worked through in ``grain``-wide sub-chunks
+    that stop at it: each counts as the stepped share it computes."""
+    if not causal:
+        return n_q * n_k
+    live = sum(min(n_k, ((i + 1) * bq - 1) // bk + 1) for i in range(n_q))
+    if grain and bq == bk:
+        n = max(1, bq // grain)
+        live -= n_q * (1 - (n + 1) / (2.0 * n))
+    return live
+
+
 def _flash_cost(ctx, bq, bk, backward):
+    from ..parallel.flash_attention import (_BWD_SUB_KEYS, _FWD_SUB_ROWS,
+                                            _VMEM_LIMIT, _bwd_is_fused)
+
     T = int(ctx["T"])
     D = int(ctx.get("D", 64))
     BH = int(ctx.get("B", 1)) * int(ctx.get("H", 1))
@@ -102,15 +124,26 @@ def _flash_cost(ctx, bq, bk, backward):
     db = _dtype_bytes(ctx)
     bq = min(bq, T)
     bk = min(bk, T)
-    if flash_vmem_bytes(bq, bk, D, db, backward=backward) > _VMEM_BUDGET:
+    # the kernels raise Mosaic's scoped-VMEM limit; keep its headroom
+    fused = backward and _bwd_is_fused(T, D, bq, bk, db)
+    if not fused and flash_vmem_bytes(
+            bq, bk, D, db, backward=backward) > 0.75 * _VMEM_LIMIT:
         return math.inf
     n_q, n_k = -(-T // bq), -(-T // bk)
-    live = 0.5 if causal else 1.0  # dead-block skip halves the grid work
-    steps = BH * n_q * n_k
-    # fwd: qk^T + pv = 4*bq*bk*D flops/block; bwd recompute ~2.5x (s, dp,
-    # ds, dq/dk/dv accumulation across two passes)
-    flops = 4 * bq * bk * D * steps * live * (2.5 if backward else 1.0)
-    traffic = steps * (bq * D + 2 * bk * D) * db * (2.0 if backward else 1.0)
+    passes = 2 if backward and not fused else 1
+    steps = passes * BH * n_q * n_k
+    # 2*bq*bk*D flops a matmul a live tile — forward: s, pv; fused
+    # backward: s, dp, dv, dk, dq; the two passes compute s and dp twice
+    matmuls = (5 if fused else 7) if backward else 2
+    flops = (2 * bq * bk * D * matmuls * BH
+             * _live_tiles(n_q, n_k, bq, bk, causal,
+                           _BWD_SUB_KEYS if backward else _FWD_SUB_ROWS))
+    # a live step moves the inner axis's two tiles (a dead one names its
+    # neighbour's block: no DMA); the outer axis's tiles and the outputs
+    # move once: 4 T x D tensors forward (q, k, v, o), 8 backward
+    traffic = BH * D * db * (
+        passes * _live_tiles(n_q, n_k, bq, bk, causal) * 2 * max(bq, bk)
+        + (8 if backward else 4) * T)
     return roofline_seconds(flops, traffic) + steps * _GRID_STEP_S
 
 
@@ -122,7 +155,8 @@ def flash_fwd_cost(candidate, ctx):
 
 
 def flash_bwd_cost(candidate, ctx):
-    """Estimated seconds of the two tiled backward passes."""
+    """Estimated seconds of the backward at this block pair: the fused
+    pass where the shape selects it, else the two tiled passes."""
     return _flash_cost(ctx, int(candidate["block_q"]),
                        int(candidate["block_k"]), backward=True)
 

@@ -43,23 +43,27 @@ Flags currently honored:
 ``MXNET_FLASH_ATTENTION_BWD`` (default 1)
     Run the flash-attention backward as the tiled recompute Pallas
     kernels (parallel/flash_attention.py): the forward saves only
-    (q, k, v, o, lse) and the backward recomputes block scores, so
-    training is O(T) in attention memory. 0 restores the pre-kernel
-    behavior — XLA autodiff of the dense formula, which materializes
-    the T x T score matrix in the backward.
+    (q, k, v, o, lse) and the backward recomputes block scores — in one
+    fused pass where the head's dq fits VMEM, else in two — so training
+    is O(T) in attention memory. 0 restores the pre-kernel behavior —
+    XLA autodiff of the dense formula, which materializes the T x T
+    score matrix in the backward.
 
-``MXNET_FLASH_BLOCK_Q`` / ``MXNET_FLASH_BLOCK_K`` (default 1024)
+``MXNET_FLASH_BLOCK_Q`` / ``MXNET_FLASH_BLOCK_K`` (default 2048)
     Upper bounds for the forward kernel's q/k block sizes. The tile
     that runs is the whole sequence when it fits under the bound, else
     the largest divisor of T that is a multiple of 128 (any divisor in
     the Pallas interpreter); a T with no such tile lowers the dense
-    formula (a static decline, docs/flash_attention.md). Defaults from
-    the round-5 on-chip sweep at T=4096 on v5e.
+    formula (a static decline, docs/flash_attention.md). The kernel
+    works a tile through in 256-row sub-chunks that stop at the causal
+    diagonal, so a large tile wastes no more than a small one and pays
+    fewer grid steps. Defaults from the v5e sweep of PR 27 at T = 1024
+    to 8192, D = 128 (PERF.md section 6).
 
-``MXNET_FLASH_BWD_BLOCK_Q`` / ``MXNET_FLASH_BWD_BLOCK_K`` (default 512)
-    Same bounds for the backward kernels. The backward holds more live
-    tiles per grid step (q, k, v, do and two fp32 accumulators), so the
-    default is one notch below the forward's to stay inside VMEM.
+``MXNET_FLASH_BWD_BLOCK_Q`` / ``MXNET_FLASH_BWD_BLOCK_K`` (default 1024)
+    Same bounds for the backward kernels (the same sweep: 1024/1024
+    wins at every T from 1024 to 8192; tiles on the diagonal are worked
+    through in 128-key sub-chunks).
 
 ``MXNET_RING_ATTENTION_FLASH`` (default 1)
     Per-ring-step local attention in ring_attention: 1 = use the Pallas
@@ -569,10 +573,10 @@ _DEFAULTS = {
     "MXNET_POOLING_MASK_BWD": 0,
     "MXNET_DEBUG_NANS": 0,
     "MXNET_FLASH_ATTENTION_BWD": 1,
-    "MXNET_FLASH_BLOCK_Q": 1024,
-    "MXNET_FLASH_BLOCK_K": 1024,
-    "MXNET_FLASH_BWD_BLOCK_Q": 512,
-    "MXNET_FLASH_BWD_BLOCK_K": 512,
+    "MXNET_FLASH_BLOCK_Q": 2048,
+    "MXNET_FLASH_BLOCK_K": 2048,
+    "MXNET_FLASH_BWD_BLOCK_Q": 1024,
+    "MXNET_FLASH_BWD_BLOCK_K": 1024,
     "MXNET_RING_ATTENTION_FLASH": 1,
     "MXNET_TELEMETRY": 0,
     "MXNET_TELEMETRY_MEMSTATS": 1,

@@ -9,15 +9,26 @@ kernel blocks it WITHIN a chip.
 
 Training is O(T) in memory end to end: the forward saves only
 (q, k, v, o, lse) — lse is the per-row logsumexp of the scaled scores —
-and the backward recomputes block scores on the fly in two tiled passes:
+and the backward recomputes block scores on the fly, each tile once:
 
-- a dq pass gridded over q blocks (k blocks as the innermost,
-  sequential axis), and
-- a dk/dv pass gridded over k blocks (q blocks innermost),
+- ONE fused pass gridded over k blocks (q blocks innermost) computes s, p,
+  dp and ds of a tile and accumulates dk, dv (per k block) and dq (the
+  whole head's (T, D), in fp32 VMEM scratch) from them — 5 matmuls and
+  one exp pass a tile; a static rule on the shape (``_bwd_is_fused``:
+  the dq scratch beside the tiles against a VMEM budget) selects it;
+- beyond that budget, two passes — the same dk/dv pass without dq, and a
+  dq pass gridded over q blocks (k blocks innermost) — 7 matmuls a tile.
 
-each accumulating in fp32 VMEM scratch and honoring the same causal
-dead-block skipping as the forward. No pass ever materializes a T x T
-tensor in HBM.
+Every pass accumulates in fp32 VMEM scratch and follows the causal
+diagonal the same way: tiles above it are skipped (their index maps name
+the neighbouring live block, so they cost no DMA), tiles it crosses are
+masked — and a square tile ON it is worked through in sub-chunks that
+stop at the diagonal, so its dead part is not computed — and tiles below
+it run mask-free. Dots take their operands in the
+input's dtype (bf16 in, fp32 accumulation; fp32 inputs keep fp32 dots)
+with p and ds cast down for the second dot of each pair; scores, softmax
+statistics, lse, delta and all accumulators stay fp32. No pass ever
+materializes a T x T tensor in HBM.
 
 Standard flash-attention recurrence (Dao et al. 2022, public algorithm);
 the kernel implementation is original. Falls back to the XLA reference
@@ -32,6 +43,7 @@ the TPU lowering accepts where (1, bq) of a 2-d (B*H, T) array is not.
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 
@@ -63,7 +75,7 @@ _declare_tunable(
                          "block_k": get_flag("MXNET_FLASH_BLOCK_K")},
     cost=_tune_cost.flash_fwd_cost,
     doc="Forward kernel q/k block upper bounds (config defaults from "
-        "the round-5 on-chip sweep at T=4096).")
+        "the v5e sweep of PR 27, PERF.md section 6).")
 _declare_tunable(
     "flash_attention.bwd",
     space=lambda ctx: {"block_q": _block_space(ctx),
@@ -71,15 +83,34 @@ _declare_tunable(
     default=lambda ctx: {"block_q": get_flag("MXNET_FLASH_BWD_BLOCK_Q"),
                          "block_k": get_flag("MXNET_FLASH_BWD_BLOCK_K")},
     cost=_tune_cost.flash_bwd_cost,
-    doc="Backward (dq + dk/dv recompute passes) block upper bounds — "
-        "more live tiles per grid step than the forward.")
+    doc="Backward block upper bounds: of the fused dq/dk/dv pass where "
+        "the shape selects it, else of the dk/dv and dq passes.")
 
 
-def _compiler_params():
+#: VMEM the fused backward may plan for (``flash_vmem_bytes``: the whole
+#: head's fp32 dq (T, D), its resident (1, T, D) output block and the
+#: step's tiles). A shape over it runs the two-pass kernels — the static
+#: rule that picks the backward, decided from shapes at trace time.
+_FUSED_BWD_VMEM_BUDGET = 40 * 2 ** 20
+#: scoped VMEM handed to Mosaic for every kernel here (its default is
+#: 16 MiB of the v5e's 128 MiB; a 2048-wide forward tile needs 18)
+_VMEM_LIMIT = 64 * 2 ** 20
+#: rows of q a forward tile is worked through at a time, and keys a
+#: backward tile ON the diagonal is: the grain at which a tile follows
+#: the diagonal (chip sweep, PERF.md §6 PR 27)
+_FWD_SUB_ROWS = 256
+_BWD_SUB_KEYS = 128
+
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_NN = (((1,), (0,)), ((), ()))      # a . b
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
+
+
+def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _tuned_block(value):
@@ -101,28 +132,97 @@ def _pick_block(T, bound, interpret):
     return aligned_block(T, bound, 1 if interpret else LANES)
 
 
-def _visible(q_idx, kv_idx, bq, bk, transposed=False):
-    """Causal visibility (query position >= key position) of one score
-    tile: (bq, bk), or (bk, bq) when ``transposed``."""
+def _bwd_is_fused(T, D, bq, bk, itemsize):
+    """The static rule that picks the backward: one fused pass while the
+    whole head's dq fits the VMEM budget beside the tiles, else two."""
+    return _tune_cost.flash_vmem_bytes(
+        bq, bk, D, itemsize, backward=True, T=T) <= _FUSED_BWD_VMEM_BUDGET
+
+
+def _last_live_k(q_idx, bq, bk):
+    """Last k block a causal q block sees (its last row's own key)."""
+    return ((q_idx + 1) * bq - 1) // bk
+
+
+def _first_live_q(kv_idx, bq, bk):
+    """First q block that sees a causal k block (its first key's row)."""
+    return (kv_idx * bk) // bq
+
+
+def _visible(q0, k0, nq, nk, transposed=False):
+    """Causal visibility (query position >= key position) of the score
+    tile of ``nq`` queries from position ``q0`` and ``nk`` keys from
+    ``k0``: (nq, nk), or (nk, nq) when ``transposed``."""
     import jax
     import jax.numpy as jnp
 
-    shape, q_dim = ((bk, bq), 1) if transposed else ((bq, bk), 0)
-    q_pos = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
-    k_pos = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, shape,
-                                                   1 - q_dim)
+    shape, q_dim = ((nk, nq), 1) if transposed else ((nq, nk), 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     return q_pos >= k_pos
 
 
+def _has_interior(T, bq, bk):
+    """Does a causal T x T score matrix in (bq, bk) tiles hold a tile
+    wholly below the diagonal (one that runs mask-free)? Static: where
+    none does — one tile a head, as at T <= the bounds — the kernels
+    carry no mask-free body at all."""
+    return (T // bq - 1) * bq >= bk - 1
+
+
+def _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, tile):
+    """Run ``tile(masked)`` on this grid step's score tile if it is live.
+    A causal tile is dead above the diagonal (skipped: its index maps
+    are clamped, so it costs no DMA either), ``masked`` where the
+    diagonal crosses it, and mask-free below — no iota, compare or
+    select on interior tiles (``interior``: whether the grid has any,
+    :func:`_has_interior`)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        tile(False)
+        return
+    first_q, first_k = q_idx * bq, kv_idx * bk
+    crosses = first_k + bk - 1 > first_q
+    if interior:
+        pl.when(jnp.logical_not(crosses))(lambda: tile(False))
+    pl.when(crosses & (first_k <= first_q + bq - 1))(lambda: tile(True))
+
+
+def _sub_block(b, want, interpret):
+    """Sub-chunk of a ``b``-long tile side: the largest divisor of ``b``
+    at or under ``want`` that the lowering takes as a slice (a multiple
+    of 128), else the whole side; a quarter of the side in the
+    interpreter, whose tiles are small."""
+    if interpret:
+        return aligned_block(b, max(1, b // 4), 1) or b
+    return aligned_block(b, want, LANES) or b
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-            scale, causal, block_k, seq_len):
-    """One (batch*head, q_block, k_block) forward grid step."""
+            scale, causal, interior, sub):
+    """One (batch*head, q_block, k_block) forward grid step.
+
+    Dots take q, k, v as loaded (bf16 operands, fp32 accumulation; fp32
+    inputs keep fp32 dots) and p cast to v's dtype; scores, softmax
+    statistics and accumulators are fp32. The statistics are lane-dense,
+    (bq, W) with W the 128 lanes (``_forward_call``): the running max m
+    replicated across them, the running sum l as W partial sums reduced
+    once, at the last k block — so a row costs one cross-lane reduction a
+    tile (its max), and no statistic lives in a one-lane column. No row
+    is ever fully masked within one call — causal or not, every query
+    sees a key in its first live tile — so m is finite from that tile on
+    and the -inf mask needs no guard."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
+    W = m_ref.shape[1]
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -130,54 +230,52 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal: blocks entirely above the diagonal contribute nothing —
-    # skip their MXU work (half the grid for long sequences)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    # a block is live unless it lies entirely above the causal diagonal:
-    # last query position >= first key position
-    live = ((q_idx + 1) * bq - 1 >= kv_idx * bk) if causal else (kv_idx >= 0)
+    def _tile(masked):
+        # the tile is worked through ``sub`` rows of q at a time. On a
+        # tile that sits ON the diagonal (bq == bk) a chunk's keys end
+        # with its own last row: dead sub-blocks are not computed
+        on_diagonal = masked and bq == bk
+        for first in range(0, bq, sub):
+            rows = slice(first, first + sub)
+            nk = first + sub if on_diagonal else bk
+            q, k, v = q_ref[0, rows, :], k_ref[0, :nk, :], v_ref[0, :nk, :]
+            s = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = jnp.where(_visible(q_idx * bq + first, kv_idx * bk,
+                                       sub, nk), s, -jnp.inf)
+            cols = [s[:, j:j + W] for j in range(0, nk, W)]
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(
+                functools.reduce(jnp.maximum, cols), axis=1, keepdims=True))
+            ps = [jnp.exp(col - m_new) for col in cols]
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[rows, :] = (l_ref[rows, :] * corr
+                              + functools.reduce(jnp.add, ps))
+            p = ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=1)
+            acc_ref[rows, :] = (
+                acc_ref[rows, :] * (corr if acc_ref.shape[1] == W
+                                    else corr[:, :1])
+                + jax.lax.dot_general(p.astype(v.dtype), v, _NN,
+                                      preferred_element_type=jnp.float32))
+            m_ref[rows, :] = m_new
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale        # (bq, d)
-        k = k_ref[0].astype(jnp.float32)                # (bk, d)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = jnp.where(_visible(q_idx, kv_idx, bq, bk), s, -jnp.inf)
-        m_prev = m_ref[...]                       # (bq, 1)
-        block_max = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, block_max)
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(jnp.isneginf(s), 0.0, p)
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0,
-                         jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = (acc_ref[...] * corr
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
 
-    @pl.when(kv_idx == (seq_len // block_k) - 1)
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-        # the O(T) softmax residual: lse = m + log(l). -inf rows (fully
-        # masked — only reachable through ring blocks above the causal
-        # diagonal) stay -inf: -inf + log(eps) = -inf. The (bq, 1) column
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
+        o_ref[0] = (acc_ref[...] * (1.0 / l)).astype(o_ref.dtype)
+        # the O(T) softmax residual: lse = m + log(l). The (bq, 1) column
         # leaves as a lane-dense (1, bq) row of a (B*H, 1, T) array
-        lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
-        lse_ref[0, 0] = lse[:, 0]
+        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l))[:, 0]
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, scale, causal, block_k, seq_len):
-    """dq pass: grid (batch*head, q_block, k_block); k is the sequential
-    axis, dq accumulates in fp32 scratch across it.
+                   dq_ref, acc_ref, *, scale, causal, interior):
+    """dq pass of the two-pass backward: grid (batch*head, q_block,
+    k_block); k is the sequential axis, dq accumulates in fp32 scratch
+    across it.
 
     Recomputes the (bq, bk) tile of p and ds from the residuals: p =
     exp(s - lse) is the EXACT softmax (no renormalization needed — lse
@@ -191,114 +289,263 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
 
     @pl.when(kv_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    live = ((q_idx + 1) * bq - 1 >= kv_idx * bk) if causal else (kv_idx >= 0)
-
-    @pl.when(live)
-    def _compute():
-        qs, k, v, do = _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale)
-        lse = jnp.expand_dims(lse_ref[0, 0], -1)            # (bq, 1)
-        delta = jnp.expand_dims(delta_ref[0, 0], -1)
-        s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = jnp.where(_visible(q_idx, kv_idx, bq, bk), s, -jnp.inf)
-        ds = _softmax_grad_tile(s, lse, delta, do, v, transposed=False)[1]
-        # ds/dq_i = scale * sum_j ds_ij k_j
+    def _tile(masked):
+        k = k_ref[0]
+        s = jax.lax.dot_general(q_ref[0], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(_visible(q_idx * bq, kv_idx * bk, bq, bk), s,
+                          -jnp.inf)
+        p = jnp.exp(s - jnp.expand_dims(lse_ref[0, 0], -1))
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - jnp.expand_dims(delta_ref[0, 0], -1))
         acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(kv_idx == (seq_len // block_k) - 1)
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale):
-    """fp32 (scale * q, k, v, do) tiles of one backward grid step."""
-    import jax.numpy as jnp
-
-    return (q_ref[0].astype(jnp.float32) * scale,           # (bq, d)
-            k_ref[0].astype(jnp.float32),                   # (bk, d)
-            v_ref[0].astype(jnp.float32),
-            do_ref[0].astype(jnp.float32))
-
-
-def _softmax_grad_tile(s, lse, delta, do, v, transposed):
-    """(p, ds) of one masked score tile ``s`` — (bq, bk) with lse/delta
-    as (bq, 1) columns, or (bk, bq) with (1, bq) rows when ``transposed``
-    — shared by both backward passes."""
-    import jax
-    import jax.numpy as jnp
-
-    # fully-masked rows have lse = -inf; exp(s - 0) would explode, so
-    # zero them explicitly (s is -inf there too, but -inf - -inf is nan)
-    lse_safe = jnp.where(jnp.isneginf(lse), 0.0, lse)
-    p = jnp.exp(s - lse_safe)
-    p = jnp.where(jnp.isneginf(s) | jnp.isneginf(lse), 0.0, p)
-    lhs, rhs = (v, do) if transposed else (do, v)
-    dp = jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    return p, p * (dp - delta)
+        # ds/dq_i = scale * sum_j ds_ij k_j
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, block_q, seq_len):
-    """dk/dv pass: grid (batch*head, k_block, q_block); q is the
-    sequential axis, dk and dv accumulate in fp32 scratch across it.
+                    dk_ref, dv_ref, *rest, scale, causal, interior, fused,
+                    sub):
+    """dk/dv pass — and, ``fused``, the whole backward: grid (batch*head,
+    k_block, q_block); q is the sequential axis, dk and dv accumulate in
+    fp32 scratch across it.
 
     Works on the TRANSPOSED (bk, bq) score tile s^T = k.q^T, so dv =
-    p^T.do and dk = ds^T.q are plain row-major matmuls (no transposed-LHS
-    contraction for Mosaic to transpose) and lse/delta broadcast along
-    sublanes straight from their (1, bq) rows."""
+    p^T.do and dk = ds^T.q are plain row-major matmuls and lse/delta
+    broadcast along sublanes straight from their (1, bq) rows. Fused, the
+    same p and ds also feed dq += ds.k (one transposed-LHS contraction),
+    accumulated over ALL k blocks in an fp32 scratch holding the whole
+    head's dq (T, D) — zeroed at the head's first grid step, cast into
+    the resident (1, T, D) output block at its last — so s, p, dp and ds
+    are computed once: 5 matmuls and one exp pass a tile where the two
+    passes spend 7 and two."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if fused:
+        dq_ref, dk_acc, dv_acc, dq_acc = rest
+    else:
+        dk_acc, dv_acc = rest
     q_idx = pl.program_id(2)
     kv_idx = pl.program_id(1)
+    last_q = pl.num_programs(2) - 1
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
 
     @pl.when(q_idx == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    live = ((q_idx + 1) * bq - 1 >= kv_idx * bk) if causal else (q_idx >= 0)
+    if fused:
+        @pl.when((q_idx == 0) & (kv_idx == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(live)
-    def _compute():
-        qs, k, v, do = _bwd_operands(q_ref, k_ref, v_ref, do_ref, scale)
-        lse = lse_ref[0]                                    # (1, bq)
-        delta = delta_ref[0]
-        s_t = jax.lax.dot_general(k, qs, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        if causal:
-            s_t = jnp.where(_visible(q_idx, kv_idx, bq, bk, transposed=True),
-                            s_t, -jnp.inf)
-        p_t, ds_t = _softmax_grad_tile(s_t, lse, delta, do, v,
-                                       transposed=True)
-        # dv_j = sum_i p_ij do_i ; dk_j = sum_i ds_ij (scale q_i) — qs is
-        # already scaled, so no extra factor here
-        dv_acc[...] += jax.lax.dot_general(
-            p_t, do, (((1,), (0,)), ((), ())),
+    def _chunk(masked, keys, first):
+        """Keys ``keys`` (a slice of the tile's rows) against the tile's
+        queries from ``first`` on."""
+        q, do = q_ref[0, first:, :], do_ref[0, first:, :]
+        k = k_ref[0, keys, :]
+        s_t = jax.lax.dot_general(k, q, _NT,
+                                  preferred_element_type=jnp.float32) * scale
+        if masked:
+            s_t = jnp.where(
+                _visible(q_idx * bq + first, kv_idx * bk + keys.start,
+                         bq - first, keys.stop - keys.start,
+                         transposed=True), s_t, -jnp.inf)
+        p_t = jnp.exp(s_t - lse_ref[0, :, first:])            # (1, .) row
+        dp_t = jax.lax.dot_general(v_ref[0, keys, :], do, _NT,
+                                   preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta_ref[0, :, first:])).astype(q.dtype)
+        # dv_j = sum_i p_ij do_i ; dk_j = scale * sum_i ds_ij q_i
+        dv_acc[keys, :] += jax.lax.dot_general(
+            p_t.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds_t, qs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_acc[keys, :] += jax.lax.dot_general(
+            ds_t, q, _NN, preferred_element_type=jnp.float32)
+        if fused:
+            rows = pl.ds(pl.multiple_of(q_idx * bq, bq) + first, bq - first)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds_t, k, _TN, preferred_element_type=jnp.float32)
 
-    @pl.when(q_idx == (seq_len // block_q) - 1)
+    def _tile(masked):
+        # on a tile that sits ON the diagonal (bq == bk) a key sub-chunk
+        # is seen only from its own first row on: the dead sub-blocks
+        # are not computed
+        if masked and bq == bk:
+            for first in range(0, bk, sub):
+                _chunk(True, slice(first, first + sub), first)
+        else:
+            _chunk(masked, slice(0, bk), 0)
+
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
+
+    @pl.when(q_idx == last_q)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when((q_idx == last_q) & (kv_idx == pl.num_programs(1) - 1))
+        def _finish_dq():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _forward_call(causal, scale, block_q, block_k, interpret):
+    """The jitted forward ``pallas_call`` of one static configuration, on
+    (B*H, T, D) operands. ONE function object a configuration: every
+    layer of a model that calls it on the same shapes shares one trace
+    and one lowering of the kernel body, where a step of 24 layers would
+    lower 24 identical Mosaic bodies — set-up time, warm cache or cold."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def forward(qf, kf, vf):
+        BH, T, D = qf.shape
+        # a dead causal step names the block of the last live one: no DMA
+        # is issued for a block index that did not change
+        def kv_map(b, i, j):
+            if causal:
+                j = jnp.minimum(j, _last_live_k(i, block_q, block_k))
+            return (b, j, 0)
+
+        sub = _sub_block(block_q, _FWD_SUB_ROWS, interpret)
+        # width of the lane-dense statistics: the 128 lanes, which divide
+        # every aligned tile and sub-chunk; an unaligned tile is the whole
+        # sequence and one slice wide; the interpreter's small tiles take
+        # what divides them
+        lanes = (math.gcd(LANES, block_k, sub)
+                 if interpret or block_k % LANES == 0 else block_k)
+        return pallas_call(
+            functools.partial(_kernel, scale=scale, causal=causal, sub=sub,
+                              interior=_has_interior(T, block_q, block_k)),
+            grid=(BH, T // block_q, T // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, D), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
+                jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, lanes), jnp.float32),
+                pltpu.VMEM((block_q, lanes), jnp.float32),
+            ],
+            interpret=interpret,
+            compiler_params=_compiler_params(),
+            name="flash_attention_fwd",
+        )(qf, kf, vf)
+
+    return jax.jit(forward)
+
+
+@functools.lru_cache(maxsize=64)
+def _backward_call(causal, scale, bq, bk, fused, interpret):
+    """The jitted backward of one static configuration — the fused pass,
+    or the dk/dv and dq passes — on (B*H, T, D) operands and (B*H, 1, T)
+    lse/delta rows; returns (dq, dk, dv). One function object a
+    configuration, as :func:`_forward_call`."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def backward(*operands):
+        qf, kf, vf = operands[:3]
+        BH, T, D = qf.shape
+        # grid (b, k block, q block): k/v and their gradients follow dim
+        # 1, q/do/rows dim 2 — clamped on dead causal steps, which come
+        # first in the scan, to the first live q block
+        def q_block(j, i):
+            return jnp.maximum(i, _first_live_q(j, bq, bk)) if causal else i
+
+        q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, q_block(j, i), 0))
+        k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+        row_spec = pl.BlockSpec((1, 1, bq),
+                                lambda b, j, i: (b, 0, q_block(j, i)))
+        out_specs = [k_spec, k_spec]
+        out_shape = [jax.ShapeDtypeStruct((BH, T, D), kf.dtype),
+                     jax.ShapeDtypeStruct((BH, T, D), vf.dtype)]
+        scratch = [pltpu.VMEM((bk, D), jnp.float32),
+                   pltpu.VMEM((bk, D), jnp.float32)]
+        if fused:
+            # dq is one (1, T, D) block per head, resident across both
+            # inner axes — which makes the k axis sequential too
+            out_specs.append(pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)))
+            out_shape.append(jax.ShapeDtypeStruct((BH, T, D), qf.dtype))
+            scratch.append(pltpu.VMEM((T, D), jnp.float32))
+        outs = pallas_call(
+            functools.partial(
+                _bwd_dkv_kernel, scale=scale, causal=causal, fused=fused,
+                interior=_has_interior(T, bq, bk),
+                sub=_sub_block(bk, _BWD_SUB_KEYS, interpret)),
+            grid=(BH, T // bk, T // bq),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            compiler_params=_compiler_params(
+                ("parallel", "arbitrary", "arbitrary") if fused
+                else ("parallel", "parallel", "arbitrary")),
+            name="flash_attention_bwd_dqkv" if fused
+            else "flash_attention_bwd_dkv",
+        )(*operands)
+        if fused:
+            dk, dv, dq = outs
+            return dq, dk, dv
+        dk, dv = outs
+        # dq pass grid is (b, q block, k block): k/v follow dim 2,
+        # clamped on dead steps to the last live k block
+        def k_block(i, j):
+            return jnp.minimum(j, _last_live_k(i, bq, bk)) if causal else j
+
+        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
+        k_spec = pl.BlockSpec((1, bk, D),
+                              lambda b, i, j: (b, k_block(i, j), 0))
+        row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        dq = pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                              interior=_has_interior(T, bq, bk)),
+            grid=(BH, T // bq, T // bk),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interpret,
+            compiler_params=_compiler_params(),
+            name="flash_attention_bwd_dq",
+        )(*operands)
+        return dq, dk, dv
+
+    return jax.jit(backward)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
@@ -310,17 +557,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     below them are used (``_pick_block``: the whole sequence or a
     multiple of 128 dividing T when compiled, any divisor interpreted; a
     T with no such tile lowers the dense XLA formula). Unset bounds
-    resolve through the autotuner
-    first — a persistent per-device tuning-cache entry for this
-    (shape-bucket, dtype) wins (docs/autotune.md; a miss with
-    MXNET_TUNE=1 outside a trace runs the measured sweep on the spot) —
-    then fall back to config.py (MXNET_FLASH_BLOCK_Q/K for the forward,
-    MXNET_FLASH_BWD_BLOCK_Q/K for the backward; forward defaults from an
-    on-chip sweep at T=4096, v5e, round 5: 1024/1024 measures 2.49 ms vs
-    2.67 ms for 512/512 and 35.5 ms for the dense XLA formula).
-    Differentiable: the vjp runs the
-    tiled recompute backward kernels above (dense XLA autodiff of the
-    reference formula when MXNET_FLASH_ATTENTION_BWD=0).
+    resolve through the autotuner first — a persistent per-device
+    tuning-cache entry for this (shape-bucket, dtype) wins
+    (docs/autotune.md; a miss with MXNET_TUNE=1 outside a trace runs the
+    measured sweep on the spot) — then fall back to config.py
+    (MXNET_FLASH_BLOCK_Q/K for the forward, MXNET_FLASH_BWD_BLOCK_Q/K
+    for the backward; both from the v5e sweep of PR 27, PERF.md
+    section 6). Differentiable: the vjp runs the tiled recompute
+    backward above — fused, or in two passes, by ``_bwd_is_fused`` (dense
+    XLA autodiff of the reference formula when
+    MXNET_FLASH_ATTENTION_BWD=0).
 
     With ``return_lse`` the per-row logsumexp of the scaled scores is
     returned alongside the output, shape (batch, heads, T) fp32 — the
@@ -329,8 +575,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
@@ -377,92 +621,32 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         return (out, lse) if return_lse else out
 
     def _flash_fwd_impl(q, k, v):
-        qf = q.reshape(B * H, T, D)
-        kf = k.reshape(B * H, T, D)
-        vf = v.reshape(B * H, T, D)
-        grid = (B * H, T // block_q, T // block_k)
-        kernel = functools.partial(_kernel, scale=scale, causal=causal,
-                                   block_k=block_k, seq_len=T)
-        out, lse = pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-                jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, D), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-            ],
-            interpret=interpret,
-            compiler_params=_compiler_params(),
-            name="flash_attention_fwd",
-        )(qf, kf, vf)
+        out, lse = _forward_call(causal, scale, block_q, block_k, interpret)(
+            *(a.reshape(B * H, T, D) for a in (q, k, v)))
         return out.reshape(B, H, T, D), lse.reshape(B, H, T)
 
     def _flash_bwd_impl(q, k, v, o, lse, do, dlse):
-        bq, bk = block_q_bwd, block_k_bwd
-        qf, kf, vf, dof = (a.reshape(B * H, T, D) for a in (q, k, v, do))
+        from ..observability import counter
+
         # delta_i = rowsum(do_i * o_i); an lse cotangent adds
         # glse_i * p_ij to ds_ij, which folds in as delta - glse
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
+        fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd,
+                              q.dtype.itemsize)
+        counter("flash_attention.bwd_fused" if fused
+                else "flash_attention.bwd_two_pass").inc()
         # per-row residuals ride as lane-dense rows of (B*H, 1, T)
         # arrays — a (1, bq) block of a 2-d (B*H, T) array is not a legal
         # TPU block shape. The dq pass turns its row into a column
-        # in-kernel; the dk/dv pass uses it as a row
-        lse_row = lse.reshape(B * H, 1, T)
-        delta_row = delta.reshape(B * H, 1, T)
-        # dq pass grid is (b, q_idx, kv_idx): q/do/rows follow dim 1,
-        # k/v follow dim 2
-        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-        k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
-        row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
-        dq = pallas_call(
-            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                              block_k=bk, seq_len=T),
-            grid=(B * H, T // bq, T // bk),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            interpret=interpret,
-            compiler_params=_compiler_params(),
-            name="flash_attention_bwd_dq",
-        )(qf, kf, vf, dof, lse_row, delta_row)
-        # dk/dv pass: grid dim 1 walks k blocks, dim 2 scans q blocks
-        q_spec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
-        k_spec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
-        row_spec2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
-        dk, dv = pallas_call(
-            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                              block_q=bq, seq_len=T),
-            grid=(B * H, T // bk, T // bq),
-            in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2,
-                      row_spec2],
-            out_specs=[k_spec2, k_spec2],
-            out_shape=[jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-                       jax.ShapeDtypeStruct((B * H, T, D), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-            interpret=interpret,
-            compiler_params=_compiler_params(),
-            name="flash_attention_bwd_dkv",
-        )(qf, kf, vf, dof, lse_row, delta_row)
-        return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
-                dv.reshape(B, H, T, D))
+        # in-kernel; the dk/dv and fused passes use it as a row
+        grads = _backward_call(causal, scale, block_q_bwd, block_k_bwd,
+                               fused, interpret)(
+            *(a.reshape(B * H, T, D) for a in (q, k, v, do)),
+            lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T))
+        return tuple(g.reshape(B, H, T, D) for g in grads)
 
     @jax.custom_vjp
     def _flash(q, k, v):

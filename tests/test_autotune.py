@@ -271,6 +271,37 @@ def test_flash_cost_penalizes_tiny_blocks():
     assert tiny > sane  # grid-step overhead dominates 512x512 grids
 
 
+def test_flash_cost_counts_live_tiles_and_the_fused_backward(monkeypatch):
+    import importlib
+
+    # tiles a causal diagonal leaves live, exactly: 10 of 16, 3 of 4, and
+    # 512-row q blocks against 1024-wide k blocks
+    assert cost_model._live_tiles(4, 4, 512, 512, True) == 10
+    assert cost_model._live_tiles(2, 2, 1024, 1024, True) == 3
+    assert cost_model._live_tiles(4, 2, 512, 1024, True) == 6
+    assert cost_model._live_tiles(4, 2, 512, 1024, False) == 8
+    # a square tile ON the diagonal, worked through in 256-wide
+    # sub-chunks that stop at it, computes 10 of its 16 sub-blocks
+    assert cost_model._live_tiles(2, 2, 1024, 1024, True,
+                                  grain=256) == 1 + 2 * 10 / 16
+    # the fused backward holds the whole head's fp32 dq and its resident
+    # output block (double-buffered like every tile) on top of the tiles
+    assert (cost_model.flash_vmem_bytes(512, 512, 128, 2, backward=True,
+                                        T=2048)
+            - cost_model.flash_vmem_bytes(512, 512, 128, 2, backward=True)
+            == 2048 * 128 * 4 + 2 * 2048 * 128 * 2)
+    # 5 matmuls a live tile in one pass against 7 in two
+    ctx = {"T": 2048, "D": 128, "B": 2, "H": 16, "causal": True,
+           "dtype_bytes": 2}
+    blocks = {"block_q": 512, "block_k": 512}
+    fused = cost_model.flash_bwd_cost(blocks, ctx)
+    monkeypatch.setattr(
+        importlib.import_module("mxnet_tpu.parallel.flash_attention"),
+        "_FUSED_BWD_VMEM_BUDGET", 0)
+    two_pass = cost_model.flash_bwd_cost(blocks, ctx)
+    assert 1.4 <= two_pass / fused < 1.7   # 7/5 and twice the grid steps
+
+
 def test_expected_padding_math():
     # ladder (1,2,4): sizes 1->1, 2->2, 3->4, 4->4 : alloc 11 / real 10
     assert cost_model.expected_padding((1, 2, 4), [1, 2, 3, 4]) == \

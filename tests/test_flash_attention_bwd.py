@@ -1,14 +1,32 @@
 """Flash-attention backward kernel tests (parallel/flash_attention.py).
 
 The training-side contract of the long-context path: the vjp runs tiled
-recompute Pallas kernels (dq pass + dk/dv pass) from O(T) residuals —
-gradient parity vs the dense reference across causal/non-causal,
-fp32/bf16, block-fallback shapes; plus the memory regression guard that
-no T x T tensor survives the forward."""
+recompute Pallas kernels from O(T) residuals — ONE fused pass (dk, dv and
+the whole head's dq) where the shape fits the VMEM budget, else a dk/dv
+pass and a dq pass. Gradient parity vs the dense reference on both paths
+across causal/non-causal, fp32/bf16, block-fallback shapes — T = 64 in
+16-wide tiles, so mask-free interior tiles, tiles the diagonal crosses
+and dead tiles all occur; plus the memory regression guard that no T x T
+tensor survives the forward."""
+import functools
+import importlib
+
 import numpy as np
 import pytest
 
 from mxnet_tpu import config
+
+# the package re-exports the function under the module's own name
+flash_module = importlib.import_module("mxnet_tpu.parallel.flash_attention")
+
+
+@pytest.fixture(params=["fused", "two_pass"])
+def bwd_path(request, monkeypatch):
+    """Both backwards: the static rule picks the fused pass at these
+    sizes; a budget of nothing sends the same call down the two passes."""
+    if request.param == "two_pass":
+        monkeypatch.setattr(flash_module, "_FUSED_BWD_VMEM_BUDGET", 0)
+    return request.param
 
 
 def _qkv(B=2, H=2, T=64, D=16, dtype=np.float32, seed=0):
@@ -29,20 +47,24 @@ def _grads(fn, q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_matches_dense_fp32(causal):
-    import jax
-    import functools
+def _flash(causal, bq_bwd=16, bk_bwd=16, fwd=16):
+    from mxnet_tpu.parallel import flash_attention
 
-    from mxnet_tpu.parallel import attention_reference, flash_attention
+    return functools.partial(flash_attention, causal=causal, block_q=fwd,
+                             block_k=fwd, block_q_bwd=bq_bwd,
+                             block_k_bwd=bk_bwd, interpret=True)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bwd_matches_dense_fp32(causal, bwd_path):
+    import jax
+
+    from mxnet_tpu.parallel import attention_reference
 
     q, k, v = _qkv()
-    flash = functools.partial(flash_attention, causal=causal, block_q=16,
-                              block_k=16, block_q_bwd=16, block_k_bwd=16,
-                              interpret=True)
     ref = functools.partial(attention_reference, causal=causal)
     with jax.default_matmul_precision("highest"):
-        gf = _grads(flash, q, k, v)
+        gf = _grads(_flash(causal), q, k, v)
         gr = _grads(ref, q, k, v)
     for name, a, b in zip("qkv", gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -51,22 +73,19 @@ def test_flash_bwd_matches_dense_fp32(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_matches_dense_bf16(causal):
+def test_flash_bwd_matches_dense_bf16(causal, bwd_path):
     import jax
     import jax.numpy as jnp
-    import functools
 
-    from mxnet_tpu.parallel import attention_reference, flash_attention
+    from mxnet_tpu.parallel import attention_reference
 
     q, k, v = _qkv(dtype=jnp.bfloat16, seed=1)
-    flash = functools.partial(flash_attention, causal=causal, block_q=16,
-                              block_k=16, block_q_bwd=16, block_k_bwd=16,
-                              interpret=True)
     ref = functools.partial(attention_reference, causal=causal)
     with jax.default_matmul_precision("highest"):
-        gf = _grads(flash, q, k, v)
+        gf = _grads(_flash(causal), q, k, v)
         gr = _grads(ref, q, k, v)
     for name, a, b in zip("qkv", gf, gr):
+        assert a.dtype == jnp.bfloat16
         a = np.asarray(a, np.float32)
         b = np.asarray(b, np.float32)
         # bf16 inputs: compare against the dense grads at bf16 resolution
@@ -75,19 +94,17 @@ def test_flash_bwd_matches_dense_bf16(causal):
             "d%s causal=%s: %s" % (name, causal, float(np.abs(a - b).max()))
 
 
-def test_flash_bwd_uneven_blocks():
-    # bwd block bounds pick divisors independently of the fwd's
+@pytest.mark.parametrize("bq_bwd,bk_bwd", [(24, 16), (16, 24), (12, 48)])
+def test_flash_bwd_uneven_blocks(bq_bwd, bk_bwd, bwd_path):
+    # bwd block bounds pick divisors independently of the fwd's, and of
+    # each other: the diagonal crosses tiles off their corners
     import jax
-    import functools
 
-    from mxnet_tpu.parallel import attention_reference, flash_attention
+    from mxnet_tpu.parallel import attention_reference
 
     q, k, v = _qkv(B=1, T=48, seed=2)
-    flash = functools.partial(flash_attention, causal=True, block_q=32,
-                              block_k=32, block_q_bwd=24, block_k_bwd=16,
-                              interpret=True)
     with jax.default_matmul_precision("highest"):
-        gf = _grads(flash, q, k, v)
+        gf = _grads(_flash(True, bq_bwd, bk_bwd, fwd=32), q, k, v)
         gr = _grads(functools.partial(attention_reference, causal=True),
                     q, k, v)
     for a, b in zip(gf, gr):
@@ -95,11 +112,113 @@ def test_flash_bwd_uneven_blocks():
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype,causal", [("float32", True),
+                                          ("float32", False),
+                                          ("bfloat16", True)])
+def test_fused_and_two_pass_backward_agree(dtype, causal, monkeypatch):
+    # one algorithm on both paths: the same tiles in the same order, so
+    # the gradients agree to float32 rounding (bf16: to one rounding of
+    # the result)
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = _qkv(dtype=jnp.dtype(dtype), seed=8)
+    flash = _flash(causal, 16, 32)
+    with jax.default_matmul_precision("highest"):
+        fused = _grads(flash, q, k, v)
+        monkeypatch.setattr(flash_module, "_FUSED_BWD_VMEM_BUDGET", 0)
+        two_pass = _grads(flash, q, k, v)
+    tol = 1e-6 if dtype == "float32" else 1e-2
+    for name, a, b in zip("qkv", fused, two_pass):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_matches_dense_at_lane_width_tiles(causal):
+    # 512-wide tiles as the chip runs them: the forward's statistics 128
+    # lanes wide, its 128-row sub-chunks and the backward's 128-key ones
+    # stopping at the diagonal inside a tile that sits on it
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import flash_attention
+    from mxnet_tpu.parallel.flash_attention import _dense_with_lse
+
+    q, k, v = _qkv(B=1, H=1, T=1024, D=8, seed=10)
+
+    def loss(attend):
+        def fn(q, k, v):
+            out, lse = attend(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+        return fn
+
+    flash = functools.partial(flash_attention, causal=causal, block_q=512,
+                              block_k=512, block_q_bwd=512, block_k_bwd=512,
+                              interpret=True, return_lse=True)
+    dense = functools.partial(_dense_with_lse, causal=causal)
+    with jax.default_matmul_precision("highest"):
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5, err_msg="d" + name)
+
+
+def test_flash_bwd_selection_is_static_and_counted(monkeypatch):
+    # the path is chosen from shapes while tracing: one trace serves
+    # every call, and each traced backward counts once on its own counter
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import observability as obs
+
+    traces = []
+
+    def loss(q, k, v):
+        traces.append(1)
+        return jnp.sum(_flash(True)(q, k, v) ** 2)
+
+    q, k, v = _qkv(seed=9)
+    obs.set_enabled(True)
+    obs.reset_metrics()
+    try:
+        step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        first = step(q, k, v)
+        second = step(q, k, v)
+        assert len(traces) == 1
+        assert obs.metrics.get_value("flash_attention.bwd_fused") == 1
+        assert obs.metrics.get_value("flash_attention.bwd_two_pass",
+                                     0) == 0
+        monkeypatch.setattr(flash_module, "_FUSED_BWD_VMEM_BUDGET", 0)
+        third = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        assert obs.metrics.get_value("flash_attention.bwd_fused") == 1
+        assert obs.metrics.get_value("flash_attention.bwd_two_pass") == 1
+    finally:
+        obs.reset_metrics()
+        obs.set_enabled(False)
+    for a, b, c in zip(first, second, third):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_fused_backward_rule_follows_the_vmem_budget():
+    # (T, D) against the budget, nothing else: the cell's shape and the
+    # longest local length the repo's queue names fuse, a head whose
+    # fp32 dq alone outgrows the budget does not
+    fused = flash_module._bwd_is_fused
+    assert fused(2048, 128, 512, 512, 2)
+    assert fused(8192, 128, 512, 512, 2)
+    assert fused(8192, 128, 1024, 1024, 4)
+    assert not fused(131072, 128, 512, 512, 2)
+
+
 def test_flash_bwd_prime_seq_fallback_grads():
     # prime-ish T routes the whole op through the dense fallback; grads
     # must still match the reference there
     import jax
-    import functools
 
     from mxnet_tpu.parallel import attention_reference, flash_attention
 
@@ -141,7 +260,7 @@ def test_flash_fwd_residuals_are_linear_in_T():
     assert n_elem <= 4 * B * H * T * D + B * H * T + T, n_elem
 
 
-def test_flash_bwd_lse_cotangent():
+def test_flash_bwd_lse_cotangent(bwd_path):
     # return_lse output is differentiable too (the ring merge needs it)
     import jax
     import jax.numpy as jnp
@@ -174,7 +293,6 @@ def test_flash_bwd_config_escape_hatch():
     # MXNET_FLASH_ATTENTION_BWD=0 restores the dense-autodiff vjp and
     # still produces correct gradients
     import jax
-    import functools
 
     from mxnet_tpu.parallel import attention_reference, flash_attention
 
