@@ -73,23 +73,26 @@ def _dtype_bytes(ctx):
     return int(ctx.get("dtype_bytes", 2))  # bf16 default
 
 
-def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None):
+def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
+                     Dv=None):
     """Live VMEM of one grid step (input and output tiles double-buffered
     by the pipeline, fp32 accumulators single-buffered). With ``T``, the
     fused backward: the whole head's fp32 dq scratch (T, D) and its
-    resident (1, T, D) output block on top of the dk/dv pass's tiles."""
+    resident (1, T, D) output block on top of the dk/dv pass's tiles.
+    ``D`` is the q/k width; ``Dv`` the v/o width where it differs."""
     db = dtype_bytes
+    Dv = D if Dv is None else Dv
     if not backward:
-        tiles = (bq * D * db          # q
-                 + 2 * bk * D * db    # k, v
-                 + bq * D * db)       # out
-        scratch = bq * D * 4 + 2 * bq * 4      # acc, m, l (fp32)
+        tiles = (bq * D * db            # q
+                 + bk * (D + Dv) * db   # k, v
+                 + bq * Dv * db)        # out
+        scratch = bq * Dv * 4 + 2 * bq * 4     # acc, m, l (fp32)
     else:
         # the dk/dv pass (the dq pass holds one accumulator fewer)
-        tiles = (2 * bq * D * db      # q, do
-                 + 4 * bk * D * db    # k, v, dk, dv
-                 + 2 * bq * 4)        # lse, delta rows
-        scratch = 2 * bk * D * 4      # dk_acc, dv_acc
+        tiles = (bq * (D + Dv) * db         # q, do
+                 + 2 * bk * (D + Dv) * db   # k, v, dk, dv
+                 + 2 * bq * 4)              # lse, delta rows
+        scratch = bk * (D + Dv) * 4         # dk_acc, dv_acc
         if T is not None:
             tiles += T * D * db       # dq out
             scratch += T * D * 4      # dq_acc

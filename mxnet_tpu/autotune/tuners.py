@@ -39,10 +39,14 @@ __all__ = ["flash_shape_key", "tune_flash_attention", "tune_fused_matmul",
 from .cost_model import pow2_at_least as _pow2_at_least
 
 
-def flash_shape_key(T, D, causal):
+def flash_shape_key(T, D, causal, Dv=None):
     """Shape-bucket key for flash-attention entries: T rounds up to a
-    power of two (one tuning per T-bucket, not per exact length)."""
-    return ("T%d" % _pow2_at_least(int(T)), "D%d" % int(D),
+    power of two (one tuning per T-bucket, not per exact length). ``Dv``
+    is the v/o width where it differs from the q/k width ``D``."""
+    width = "D%d" % int(D)
+    if Dv is not None and int(Dv) != int(D):
+        width += "v%d" % int(Dv)
+    return ("T%d" % _pow2_at_least(int(T)), width,
             "causal" if causal else "full")
 
 

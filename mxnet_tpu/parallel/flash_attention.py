@@ -132,11 +132,13 @@ def _pick_block(T, bound, interpret):
     return aligned_block(T, bound, 1 if interpret else LANES)
 
 
-def _bwd_is_fused(T, D, bq, bk, itemsize):
+def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None):
     """The static rule that picks the backward: one fused pass while the
-    whole head's dq fits the VMEM budget beside the tiles, else two."""
+    whole head's dq fits the VMEM budget beside the tiles, else two.
+    ``D`` is the q/k width, ``Dv`` the v/o width where it differs."""
     return _tune_cost.flash_vmem_bytes(
-        bq, bk, D, itemsize, backward=True, T=T) <= _FUSED_BWD_VMEM_BUDGET
+        bq, bk, D, itemsize, backward=True, T=T,
+        Dv=Dv) <= _FUSED_BWD_VMEM_BUDGET
 
 
 def _last_live_k(q_idx, bq, bk):
@@ -422,6 +424,7 @@ def _forward_call(causal, scale, block_q, block_k, interpret):
 
     def forward(qf, kf, vf):
         BH, T, D = qf.shape
+        Dv = vf.shape[-1]       # v and o may be narrower than q and k
         # a dead causal step names the block of the last live one: no DMA
         # is issued for a block index that did not change
         def kv_map(b, i, j):
@@ -443,18 +446,18 @@ def _forward_call(causal, scale, block_q, block_k, interpret):
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, Dv), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
+                jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype),
                 jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
                 pltpu.VMEM((block_q, lanes), jnp.float32),
                 pltpu.VMEM((block_q, lanes), jnp.float32),
             ],
@@ -480,21 +483,27 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
     def backward(*operands):
         qf, kf, vf = operands[:3]
         BH, T, D = qf.shape
+        Dv = vf.shape[-1]       # v, o, do and dv may be narrower
         # grid (b, k block, q block): k/v and their gradients follow dim
         # 1, q/do/rows dim 2 — clamped on dead causal steps, which come
         # first in the scan, to the first live q block
         def q_block(j, i):
             return jnp.maximum(i, _first_live_q(j, bq, bk)) if causal else i
 
-        q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, q_block(j, i), 0))
-        k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+        def q_spec(width):
+            return pl.BlockSpec((1, bq, width),
+                                lambda b, j, i: (b, q_block(j, i), 0))
+
+        def k_spec(width):
+            return pl.BlockSpec((1, bk, width), lambda b, j, i: (b, j, 0))
+
         row_spec = pl.BlockSpec((1, 1, bq),
                                 lambda b, j, i: (b, 0, q_block(j, i)))
-        out_specs = [k_spec, k_spec]
+        out_specs = [k_spec(D), k_spec(Dv)]
         out_shape = [jax.ShapeDtypeStruct((BH, T, D), kf.dtype),
-                     jax.ShapeDtypeStruct((BH, T, D), vf.dtype)]
+                     jax.ShapeDtypeStruct((BH, T, Dv), vf.dtype)]
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
-                   pltpu.VMEM((bk, D), jnp.float32)]
+                   pltpu.VMEM((bk, Dv), jnp.float32)]
         if fused:
             # dq is one (1, T, D) block per head, resident across both
             # inner axes — which makes the k axis sequential too
@@ -507,7 +516,8 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
                 interior=_has_interior(T, bq, bk),
                 sub=_sub_block(bk, _BWD_SUB_KEYS, interpret)),
             grid=(BH, T // bk, T // bq),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
+                      row_spec, row_spec],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
@@ -527,16 +537,21 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
         def k_block(i, j):
             return jnp.minimum(j, _last_live_k(i, bq, bk)) if causal else j
 
-        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-        k_spec = pl.BlockSpec((1, bk, D),
-                              lambda b, i, j: (b, k_block(i, j), 0))
+        def q_spec(width):
+            return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
+
+        def k_spec(width):
+            return pl.BlockSpec((1, bk, width),
+                                lambda b, i, j: (b, k_block(i, j), 0))
+
         row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
         dq = pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                               interior=_has_interior(T, bq, bk)),
             grid=(BH, T // bq, T // bk),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
+            in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
+                      row_spec, row_spec],
+            out_specs=q_spec(D),
             out_shape=jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interpret,
@@ -551,7 +566,9 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, block_q_bwd=None, block_k_bwd=None,
                     interpret=False, return_lse=False):
-    """Blocked attention; q/k/v: (batch, heads, T, d).
+    """Blocked attention; q/k: (batch, heads, T, d), v: (batch, heads, T,
+    dv) — ``dv`` may differ from ``d`` (latent attention's 192/128), and
+    the output then has v's width; nothing is padded in HBM.
 
     Block arguments are upper bounds; the largest TPU-legal tiles at or
     below them are used (``_pick_block``: the whole sequence or a
@@ -575,8 +592,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     """
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..observability import counter
 
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
+    if k.shape[-1] != D:
+        raise ValueError("flash_attention: q and k widths differ (%d, %d)"
+                         % (D, k.shape[-1]))
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
     # block resolution: explicit per-call override > tuning-cache entry
     # for this (device, shape-bucket, dtype) > config.py flag. The cache
@@ -586,8 +610,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from .. import autotune
 
-        key = autotune.flash_shape_key(T, D, causal)
-        ctx = {"T": T, "D": D, "B": B, "H": H, "causal": causal,
+        key = autotune.flash_shape_key(T, D, causal, Dv=Dv)
+        ctx = {"T": T, "D": D, "Dv": Dv, "B": B, "H": H, "causal": causal,
                "dtype": str(q.dtype), "dtype_bytes": q.dtype.itemsize,
                "interpret": interpret or None}
         if block_q is None or block_k is None:
@@ -621,13 +645,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         return (out, lse) if return_lse else out
 
     def _flash_fwd_impl(q, k, v):
+        if Dv != D:
+            counter("flash_attention.dqk_ne_dv").inc()
         out, lse = _forward_call(causal, scale, block_q, block_k, interpret)(
-            *(a.reshape(B * H, T, D) for a in (q, k, v)))
-        return out.reshape(B, H, T, D), lse.reshape(B, H, T)
+            *(a.reshape(B * H, T, a.shape[-1]) for a in (q, k, v)))
+        # named for a recomputing caller's policy: kept, the backward
+        # pass does not run this kernel again (an identity otherwise)
+        return (checkpoint_name(out.reshape(B, H, T, Dv), "flash_out"),
+                checkpoint_name(lse.reshape(B, H, T), "flash_lse"))
 
     def _flash_bwd_impl(q, k, v, o, lse, do, dlse):
-        from ..observability import counter
-
         # delta_i = rowsum(do_i * o_i); an lse cotangent adds
         # glse_i * p_ij to ds_ij, which folds in as delta - glse
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -635,7 +662,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
         fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd,
-                              q.dtype.itemsize)
+                              q.dtype.itemsize, Dv=Dv)
         counter("flash_attention.bwd_fused" if fused
                 else "flash_attention.bwd_two_pass").inc()
         # per-row residuals ride as lane-dense rows of (B*H, 1, T)
@@ -644,9 +671,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         # in-kernel; the dk/dv and fused passes use it as a row
         grads = _backward_call(causal, scale, block_q_bwd, block_k_bwd,
                                fused, interpret)(
-            *(a.reshape(B * H, T, D) for a in (q, k, v, do)),
+            *(a.reshape(B * H, T, a.shape[-1]) for a in (q, k, v, do)),
             lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T))
-        return tuple(g.reshape(B, H, T, D) for g in grads)
+        return tuple(g.reshape(B, H, T, g.shape[-1]) for g in grads)
 
     @jax.custom_vjp
     def _flash(q, k, v):

@@ -1,0 +1,227 @@
+"""Layer kinds a :class:`~.transformer.TransformerParallel` can be built
+from beside its first block: latent attention (``"mla"``), a SwiGLU FFN
+(``"swiglu"``) and a routed expert layer that is told which experts it
+holds (``"moe"``). Each kind is three functions of the architecture's
+widths: its leaves (name -> (shape, init)), and its forward on (B, T, d).
+
+Every norm here is ``x * rsqrt(mean(x^2) + eps) * w`` with a learned
+``w``, computed in float32. Positions are rotary (``rope_tables``), in the
+``deepseek_yarn`` scaling where the architecture states one; the pairing
+is half-split (``rotate_half``). docs/lm_layers.md has the equations.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from ..observability import counter, device_scope
+from . import moe as _moe
+
+__all__ = ["ATTENTION_KINDS", "FFN_KINDS", "layer_table", "rope_tables",
+           "yarn_inv_freq", "yarn_mscale", "mla_scale", "rms_norm",
+           "apply_rope"]
+
+ATTENTION_KINDS = ("mha", "mla")
+FFN_KINDS = ("soft_moe", "swiglu", "moe")
+
+_NORMAL = ("normal", 0.02)
+
+
+# --- leaves ------------------------------------------------------------
+def layer_table(li, kinds, cfg, arch):
+    """name -> (shape, init) of layer ``li``'s leaves, in a fixed order."""
+    attn, ffn = kinds
+    p = "l%d_" % li
+    d = cfg["d_model"]
+    H = cfg["n_heads"]
+    t = {}
+    if attn == "mla":
+        dq = arch["qk_nope_head_dim"] + arch["qk_rope_head_dim"]
+        r = arch["kv_lora_rank"]
+        t[p + "attn_norm"] = ((d,), 1.0)
+        t[p + "wq"] = ((d, H * dq), _NORMAL)
+        t[p + "wkva"] = ((d, r + arch["qk_rope_head_dim"]), _NORMAL)
+        t[p + "kv_norm"] = ((r,), 1.0)
+        t[p + "wkvb"] = ((r, H * (arch["qk_nope_head_dim"]
+                                  + arch["v_head_dim"])), _NORMAL)
+        t[p + "wo"] = ((H * arch["v_head_dim"], d), _NORMAL)
+    if ffn == "swiglu":
+        f = cfg["d_ff"]
+        t[p + "ffn_norm"] = ((d,), 1.0)
+        t[p + "wg"] = ((d, f), _NORMAL)
+        t[p + "wu"] = ((d, f), _NORMAL)
+        t[p + "wd"] = ((f, d), _NORMAL)
+    if ffn == "moe":
+        m = arch["moe"]
+        f = m["d_expert"]
+        lo, hi = m["experts_held"]
+        t[p + "ffn_norm"] = ((d,), 1.0)
+        t[p + "router"] = ((d, m["n_experts"]), _NORMAL)
+        t[p + "router_bias"] = ((m["n_experts"],), _NORMAL)
+        t[p + "shared_wg"] = ((d, f * m["n_shared"]), _NORMAL)
+        t[p + "shared_wu"] = ((d, f * m["n_shared"]), _NORMAL)
+        t[p + "shared_wd"] = ((f * m["n_shared"], d), _NORMAL)
+        t[p + "moe_wg"] = ((hi - lo, d, f), _NORMAL)
+        t[p + "moe_wu"] = ((hi - lo, d, f), _NORMAL)
+        t[p + "moe_wd"] = ((hi - lo, f, d), _NORMAL)
+    return t
+
+
+# --- norms and positions -----------------------------------------------
+def rms_norm(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim, rope):
+    """Inverse frequencies of the ``dim`` rotary channels (dim/2 of them):
+    plain ``theta^(-2i/dim)`` without ``factor``; under ``deepseek_yarn``
+    the interpolated ones (``/ factor``) below the correction range, the
+    plain ones above it and a linear ramp between."""
+    theta = float(rope["theta"])
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    factor = float(rope.get("factor", 1.0))
+    if factor <= 1:
+        return plain
+
+    def correction_dim(rotations):
+        return (dim * math.log(rope["original_max_position_embeddings"]
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(rope["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(rope["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    return plain / factor * ramp + plain * (1 - ramp)
+
+
+def rope_tables(T, dim, rope):
+    """(cos, sin), each (T, dim/2) float32, scaled by the yarn ratio
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    angle = np.arange(T, dtype=np.float64)[:, None] * yarn_inv_freq(
+        dim, rope)[None, :]
+    factor = float(rope.get("factor", 1.0))
+    ratio = (yarn_mscale(factor, rope.get("mscale", 1.0))
+             / yarn_mscale(factor, rope.get("mscale_all_dim", 0.0) or 0.0)
+             if factor > 1 else 1.0)
+    return ((np.cos(angle) * ratio).astype(np.float32),
+            (np.sin(angle) * ratio).astype(np.float32))
+
+
+def apply_rope(x, cos, sin):
+    """Half-split rotation of the last axis: ``x`` (..., T, dim) with
+    tables (T, dim/2) broadcast over the leading axes."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def mla_scale(arch):
+    """Softmax scale: ``q_head_dim^-1/2 * m^2`` with ``m`` the yarn mscale
+    of ``mscale_all_dim`` (1 without yarn)."""
+    dq = arch["qk_nope_head_dim"] + arch["qk_rope_head_dim"]
+    rope = arch["rope"]
+    m = (yarn_mscale(float(rope.get("factor", 1.0)),
+                     rope.get("mscale_all_dim", 0.0))
+         if rope.get("mscale_all_dim") else 1.0)
+    return dq ** -0.5 * m * m
+
+
+# --- forwards ----------------------------------------------------------
+def mla_attention(params, li, x, cfg, arch, attend):
+    """Latent attention's residual branch on (B, T, d); ``attend(q, k, v,
+    scale)`` takes (B, H, T, .) operands."""
+    import jax.numpy as jnp
+
+    p = "l%d_" % li
+    B, T, _ = x.shape
+    H = cfg["n_heads"]
+    dn, dr, dv = (arch["qk_nope_head_dim"], arch["qk_rope_head_dim"],
+                  arch["v_head_dim"])
+    r = arch["kv_lora_rank"]
+    eps = arch["rms_norm_eps"]
+    with device_scope("l%d/attn/proj" % li):
+        h = rms_norm(x, params[p + "attn_norm"], eps)
+        q = jnp.einsum("btd,dhe->bhte", h,
+                       params[p + "wq"].reshape(-1, H, dn + dr))
+        kva = h @ params[p + "wkva"]
+        c_kv = rms_norm(kva[..., :r], params[p + "kv_norm"], eps)
+        kvb = jnp.einsum("btr,rhe->bhte", c_kv,
+                         params[p + "wkvb"].reshape(r, H, dn + dv))
+    with device_scope("l%d/attn/rope" % li):
+        cos, sin = rope_tables(T, dr, arch["rope"])
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
+        k_rope = apply_rope(kva[..., r:], cos, sin)        # (B, T, dr)
+        k = jnp.concatenate(
+            [kvb[..., :dn],
+             jnp.broadcast_to(k_rope[:, None], (B, H, T, dr))], axis=-1)
+        v = kvb[..., dn:]
+    with device_scope("l%d/attn/flash" % li):
+        att = attend(q, k, v, mla_scale(arch))             # (B, H, T, dv)
+    with device_scope("l%d/attn/out" % li):
+        return jnp.einsum("bhte,hed->btd", att,
+                          params[p + "wo"].reshape(H, dv, -1))
+
+
+def _swiglu(u, wg, wu, wd):
+    import jax
+
+    return (jax.nn.silu(u @ wg) * (u @ wu)) @ wd
+
+
+def swiglu_ffn(params, li, x, arch):
+    p = "l%d_" % li
+    with device_scope("l%d/ffn" % li):
+        u = rms_norm(x, params[p + "ffn_norm"], arch["rms_norm_eps"])
+        return _swiglu(u, params[p + "wg"], params[p + "wu"],
+                       params[p + "wd"])
+
+
+def moe_ffn(params, li, x, arch):
+    """Shared expert plus this rank's part of the routed sum on (B, T, d),
+    and each held expert's pairs (held,) int32."""
+    import jax
+    import jax.numpy as jnp
+
+    p = "l%d_" % li
+    m = arch["moe"]
+    held = tuple(m["experts_held"])
+    B, T, d = x.shape
+    u = rms_norm(x, params[p + "ffn_norm"], arch["rms_norm_eps"])
+    rows_in = u.reshape(B * T, d)
+    with device_scope("l%d/moe/router" % li):
+        logits = jnp.dot(rows_in.astype(jnp.float32),
+                         params[p + "router"].astype(jnp.float32),
+                         precision="highest")
+        idx, weight = _moe.route(logits, params[p + "router_bias"],
+                                 m["top_k"], m["scale"])
+    with device_scope("l%d/moe/dispatch" % li):
+        plan = _moe.plan_dispatch(idx, held, _moe.GMM_BLOCK_ROWS)
+        counter("moe.experts_held").inc(held[1] - held[0])
+        counter("moe.row_budget").inc(plan["pair_of_row"].shape[0])
+        rows = _moe.dispatch(rows_in, plan)
+    with device_scope("l%d/moe/experts" % li):
+        act = (jax.nn.silu(_moe.gmm(rows, params[p + "moe_wg"], plan))
+               * _moe.gmm(rows, params[p + "moe_wu"], plan))
+        out_rows = _moe.gmm(act, params[p + "moe_wd"], plan)
+    with device_scope("l%d/moe/shared" % li):
+        shared = _swiglu(u, params[p + "shared_wg"], params[p + "shared_wu"],
+                         params[p + "shared_wd"])
+    with device_scope("l%d/moe/combine" % li):
+        routed = _moe.combine(out_rows, weight, plan).reshape(B, T, d)
+        return shared + routed, plan["counts"]

@@ -1,0 +1,379 @@
+"""Sparse mixture-of-experts layer parts: the router, the dispatch plan of
+an expert-parallel rank that is told which experts it holds, and the
+grouped matmul over those experts as Pallas TPU kernels.
+
+- :func:`route` scores every token against ALL experts (sigmoid, float32),
+  selects the top-k by score plus a selection-only bias, and weights the
+  selected experts by their normalised scores times a scaling factor.
+- :func:`plan_dispatch` lays the (token, expert) pairs whose expert lies in
+  the held range out as rows sorted by expert, each expert's group padded
+  to a whole number of row tiles (at least one, so every held expert's
+  weight gradient is written). Shapes are static: the row budget is the
+  worst case the routing can produce (every token's top-k inside the held
+  range) and the kernels skip the tiles past the live ones, so no pair is
+  ever dropped and the budget costs memory and grid steps, not matmuls.
+- :func:`dispatch` / :func:`combine` move token rows into and out of that
+  layout; each is the other's transpose, so both directions are gathers.
+- :func:`gmm` is the grouped matmul ``rows[i] @ w[expert_of_tile(i)]``;
+  its device events are named ``moe_gmm_fwd`` (also the input's gradient,
+  with the weight read transposed) and ``moe_gmm_dw`` (the weight's
+  gradient, accumulated over an expert's row tiles in fp32 VMEM scratch).
+
+What the absent ranks' experts would add is left out: the layer's output
+is this rank's partial sum (plus what every rank computes alike), and no
+code stands in for the exchange.
+"""
+from __future__ import annotations
+
+import functools
+
+from .pallas_common import pallas_call
+
+__all__ = ["route", "plan_dispatch", "dispatch", "combine", "gmm",
+           "row_budget", "GMM_BLOCK_ROWS"]
+
+#: rows of a grouped-matmul tile, and what each expert's group is padded
+#: to; interpreted (tests) any multiple of 8 works
+GMM_BLOCK_ROWS = 256
+#: column bound of a weight tile of the forward / input-gradient kernel and
+#: (k, n) bounds of the weight-gradient kernel's accumulator
+_GMM_BLOCK_COLS = 1024
+_GMM_DW_BLOCK = (1024, 2048)
+_VMEM_LIMIT = 96 * 2 ** 20
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def route(logits, bias, top_k, scale):
+    """``logits``: (N, E) float32 router outputs; ``bias``: (E,) the expert
+    bias, used for the selection only (no gradient reaches it). Returns
+    (idx (N, k) int32, weight (N, k) float32): the k experts with the
+    largest ``sigmoid(z) + b`` and ``scale * sigmoid(z_e) / sum_topk``."""
+    import jax
+    import jax.numpy as jnp
+
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(score)
+                           + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(score, idx, axis=-1)
+    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight
+
+
+def row_budget(n_tokens, top_k, n_held, block_rows):
+    """Rows of the sorted layout: the worst case the routing can produce
+    (every token's pairs inside the held range) plus each group's padding
+    to whole tiles."""
+    return n_tokens * min(top_k, n_held) + n_held * block_rows
+
+
+def plan_dispatch(idx, held, block_rows):
+    """The layout of this rank's share. ``idx``: (N, k) global expert ids;
+    ``held``: (lo, hi) the range of experts held. Returns a dict of int32
+    arrays: ``row_of_pair`` (N, k) (R where the pair's expert is not held),
+    ``pair_of_row`` (R,) (N*k on padding and dead rows), per row tile
+    ``tile_expert`` / ``tile_first`` / ``tile_last``, ``n_live`` (1,) the
+    live tiles, and ``counts`` (held,) each held expert's pairs."""
+    import jax.numpy as jnp
+
+    lo, hi = held
+    n_held = hi - lo
+    N, k = idx.shape
+    P = N * k
+    R = row_budget(N, k, n_held, block_rows)
+    n_tiles = R // block_rows
+    local = idx.reshape(P) - lo
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts_all = jnp.zeros(n_held + 1, jnp.int32).at[key].add(1)
+    counts = counts_all[:n_held]
+    group_tiles = jnp.maximum(1, -(-counts // block_rows))
+    tile_end = jnp.cumsum(group_tiles)
+    tile_start = tile_end - group_tiles
+    first_sorted = jnp.cumsum(counts_all) - counts_all   # start in `order`
+    key_sorted = key[order]
+    rank = jnp.arange(P, dtype=jnp.int32) - first_sorted[key_sorted]
+    row_start = jnp.concatenate(
+        [tile_start * block_rows, jnp.full((1,), R, jnp.int32)])
+    rows_sorted = jnp.where(key_sorted < n_held,
+                            row_start[key_sorted] + rank, R)
+    row_of_pair = jnp.zeros(P, jnp.int32).at[order].set(
+        rows_sorted.astype(jnp.int32), unique_indices=True)
+    pair_of_row = jnp.full(R, P, jnp.int32).at[rows_sorted].set(
+        order, mode="drop", unique_indices=True)
+    tiles = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.sum(tiles[:, None] >= tile_end[None, :], axis=1),
+        n_held - 1).astype(jnp.int32)
+    return {"row_of_pair": row_of_pair.reshape(N, k),
+            "pair_of_row": pair_of_row,
+            "tile_expert": tile_expert,
+            "tile_first": (tiles == tile_start[tile_expert]).astype(
+                jnp.int32),
+            "tile_last": (tiles == tile_end[tile_expert] - 1).astype(
+                jnp.int32),
+            "n_live": tile_end[-1:].astype(jnp.int32),
+            "counts": counts}
+
+
+def _take_rows(x, index):
+    """x[index] with zeros where ``index`` is out of range."""
+    import jax.numpy as jnp
+
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _gather_pairs(rows, weight, row_of_pair):
+    """out[t] = sum_j weight[t, j] * rows[row_of_pair[t, j]] (absent pairs
+    add nothing), accumulated in float32."""
+    import jax.numpy as jnp
+
+    N, k = row_of_pair.shape
+    picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
+        N, k, rows.shape[-1])
+    return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
+                      weight.astype(jnp.float32)).astype(rows.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _moves():
+    """(dispatch, combine): each other's transpose, built once."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def dispatch(x, row_of_pair, pair_of_row):
+        return _take_rows(x, pair_of_row // row_of_pair.shape[1])
+
+    def dispatch_fwd(x, row_of_pair, pair_of_row):
+        return dispatch(x, row_of_pair, pair_of_row), row_of_pair
+
+    def dispatch_bwd(row_of_pair, d_rows):
+        ones = jnp.ones(row_of_pair.shape, jnp.float32)
+        return _gather_pairs(d_rows, ones, row_of_pair), None, None
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(rows, weight, row_of_pair, pair_of_row):
+        return _gather_pairs(rows, weight, row_of_pair)
+
+    def combine_fwd(rows, weight, row_of_pair, pair_of_row):
+        return (_gather_pairs(rows, weight, row_of_pair),
+                (rows, weight, row_of_pair, pair_of_row))
+
+    def combine_bwd(res, d_out):
+        rows, weight, row_of_pair, pair_of_row = res
+        N, k = row_of_pair.shape
+        w_row = _take_rows(weight.reshape(N * k), pair_of_row)
+        d_rows = (_take_rows(d_out, pair_of_row // k).astype(jnp.float32)
+                  * w_row[:, None].astype(jnp.float32)).astype(rows.dtype)
+        picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
+            N, k, rows.shape[-1])
+        d_weight = jnp.einsum("nkd,nd->nk", picked.astype(jnp.float32),
+                              d_out.astype(jnp.float32)).astype(weight.dtype)
+        return d_rows, d_weight, None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+def dispatch(x, plan):
+    """Token rows (N, d) into the sorted layout (R, d): row r holds the
+    token of its pair, padding and dead rows hold zeros."""
+    return _moves()[0](x, plan["row_of_pair"], plan["pair_of_row"])
+
+
+def combine(rows, weight, plan):
+    """(R, d) expert outputs back to tokens: ``out[t] = sum_j weight[t, j]
+    * rows[row_of_pair[t, j]]`` over the pairs held here."""
+    return _moves()[1](rows, weight, plan["row_of_pair"],
+                       plan["pair_of_row"])
+
+
+# --- the grouped matmul ----------------------------------------------------
+def _fwd_kernel(te_ref, live_ref, x_ref, w_ref, o_ref, *, transpose_rhs):
+    """One (column tile, row tile) step: the row tile times its expert's
+    weight tile. Tiles past the live ones do nothing (their index maps
+    name the last live tile: no DMA, and no write-back of their own)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], _NT if transpose_rhs else _NN,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _dw_kernel(te_ref, first_ref, last_ref, live_ref, x_ref, dy_ref, dw_ref,
+               acc_ref):
+    """One (k tile, n tile, row tile) step of the weight gradient: x^T dy
+    of the row tile, accumulated in fp32 over the expert's row tiles."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(2)
+    live = i < live_ref[0]
+
+    @pl.when(live & (first_ref[i] == 1))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)  # graftlint: disable=G003 — a Pallas kernel writes its refs
+
+    @pl.when(live)
+    def _():
+        acc_ref[...] += jax.lax.dot_general(  # graftlint: disable=G003 — a Pallas kernel writes its refs
+            x_ref[...], dy_ref[...], _TN, preferred_element_type=jnp.float32)
+
+    @pl.when(live & (last_ref[i] == 1))
+    def _():
+        dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)  # graftlint: disable=G003 — a Pallas kernel writes its refs
+
+
+def _col_block(n, bound, interpret):
+    from .pallas_common import LANES, aligned_block
+
+    block = aligned_block(n, bound, 1 if interpret else LANES)
+    if block is None:
+        raise ValueError("moe.gmm: no legal tile of a %d-wide axis under %d"
+                         % (n, bound))
+    return block
+
+
+@functools.lru_cache(maxsize=64)
+def _fwd_call(transpose_rhs, block_rows, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(x, w, tile_expert, n_live):
+        R, K = x.shape
+        n_out = w.shape[1] if transpose_rhs else w.shape[2]
+        tn = _col_block(n_out, _GMM_BLOCK_COLS, interpret)
+
+        def row(i, live):
+            return jnp.minimum(i, live[0] - 1)
+
+        w_spec = (pl.BlockSpec((1, tn, K),
+                               lambda j, i, te, live: (te[i], j, 0))
+                  if transpose_rhs else
+                  pl.BlockSpec((1, K, tn),
+                               lambda j, i, te, live: (te[i], 0, j)))
+        return pallas_call(
+            functools.partial(_fwd_kernel, transpose_rhs=transpose_rhs),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(n_out // tn, R // block_rows),
+                in_specs=[
+                    pl.BlockSpec((block_rows, K),
+                                 lambda j, i, te, live: (row(i, live), 0)),
+                    w_spec],
+                out_specs=pl.BlockSpec(
+                    (block_rows, tn),
+                    lambda j, i, te, live: (row(i, live), j))),
+            out_shape=jax.ShapeDtypeStruct((R, n_out), x.dtype),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            name="moe_gmm_fwd",
+        )(tile_expert, n_live, x, w)
+
+    return jax.jit(call)
+
+
+@functools.lru_cache(maxsize=64)
+def _dw_call(n_experts, block_rows, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(x, dy, tile_expert, tile_first, tile_last, n_live):
+        R, K = x.shape
+        n_out = dy.shape[1]
+        tk = _col_block(K, _GMM_DW_BLOCK[0], interpret)
+        tn = _col_block(n_out, _GMM_DW_BLOCK[1], interpret)
+
+        def row(i, live):
+            return jnp.minimum(i, live[0] - 1)
+
+        return pallas_call(
+            _dw_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(K // tk, n_out // tn, R // block_rows),
+                in_specs=[
+                    pl.BlockSpec((block_rows, tk),
+                                 lambda a, b, i, te, fi, la, live:
+                                 (row(i, live), a)),
+                    pl.BlockSpec((block_rows, tn),
+                                 lambda a, b, i, te, fi, la, live:
+                                 (row(i, live), b))],
+                out_specs=pl.BlockSpec(
+                    (1, tk, tn),
+                    lambda a, b, i, te, fi, la, live: (te[i], a, b)),
+                scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((n_experts, K, n_out), x.dtype),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            name="moe_gmm_dw",
+        )(tile_expert, tile_first, tile_last, n_live, x, dy)
+
+    return jax.jit(call)
+
+
+@functools.lru_cache(maxsize=None)
+def _gmm_of(block_rows, interpret):
+    """The differentiable grouped matmul of one tile height: the compiled
+    kernels, or the same kernels in the Pallas interpreter."""
+    import jax
+
+    def forward(x, w, plan, transpose_rhs=False):
+        return _fwd_call(transpose_rhs, block_rows, interpret)(
+            x, w, plan["tile_expert"], plan["n_live"])
+
+    def weight_grad(x, dy, plan, n_experts):
+        return _dw_call(n_experts, block_rows, interpret)(
+            x, dy, plan["tile_expert"], plan["tile_first"],
+            plan["tile_last"], plan["n_live"])
+
+    @jax.custom_vjp
+    def gmm(x, w, plan):
+        return forward(x, w, plan)
+
+    def gmm_fwd(x, w, plan):
+        return forward(x, w, plan), (x, w, plan)
+
+    def gmm_bwd(res, dy):
+        x, w, plan = res
+        dx = forward(dy, w, plan, transpose_rhs=True)
+        dw = weight_grad(x, dy, plan, w.shape[0])
+        return dx, dw.astype(w.dtype), None
+
+    gmm.defvjp(gmm_fwd, gmm_bwd)
+    return gmm
+
+
+def gmm(x, w, plan, block_rows=GMM_BLOCK_ROWS, interpret=None):
+    """Grouped matmul: ``x`` (R, K) rows in the layout of ``plan``, ``w``
+    (E, K, N) the held experts' weights; (R, N), row tile i times
+    ``w[plan["tile_expert"][i]]``. Rows of tiles past ``plan["n_live"]``
+    are not computed (their contents are undefined; :func:`combine` never
+    reads them). Differentiable in ``x`` and ``w`` by the same kernels.
+    ``interpret``: run the kernels in the Pallas interpreter; None does so
+    wherever the backend is not a TPU (the one path off the chip)."""
+    import jax
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _gmm_of(int(block_rows), bool(interpret))(
+        x, w, {k: plan[k] for k in ("tile_expert", "tile_first",
+                                    "tile_last", "n_live")})

@@ -21,6 +21,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..observability import device_scope, trace_span
+from . import lm_layers
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerParallel"]
@@ -31,18 +32,81 @@ class TransformerParallel:
 
     Parameters are a flat dict of jax arrays placed with NamedShardings;
     ``step`` runs fwd+bwd+SGD as one compiled program over the mesh.
+
+    ``layers`` describes the model layer by layer, each an (attention
+    kind, FFN kind) pair (docs/lm_layers.md). Without it the model is
+    ``n_layers`` of the first block, ``("mha", "soft_moe")``: multi-head
+    attention without positions, a weightless RMSNorm and a GELU FFN whose
+    experts all run under a softmax gate. The other kinds — ``"mla"``
+    (latent attention with rotary positions), ``"swiglu"`` and ``"moe"``
+    (sigmoid top-k routing over ``arch["moe"]["n_experts"]`` experts of
+    which this rank holds the range ``experts_held``, plus shared experts)
+    — take their widths from ``arch``, have learned norm weights and a
+    learned final norm, and train on dp meshes. ``remat`` recomputes each
+    layer in the backward pass (the flash kernel's output and row
+    statistics are kept, so the kernel's forward is not run twice).
     """
 
     def __init__(self, mesh, vocab=64, d_model=32, n_heads=4, n_layers=2,
-                 d_ff=64, n_experts=2, dtype=np.float32):
+                 d_ff=64, n_experts=2, dtype=np.float32, layers=None,
+                 arch=None, remat=False):
         self.mesh = mesh
+        self.layers = (tuple(tuple(k) for k in layers) if layers is not None
+                       else (("mha", "soft_moe"),) * n_layers)
         self.cfg = dict(vocab=vocab, d_model=d_model, n_heads=n_heads,
-                        n_layers=n_layers, d_ff=d_ff, n_experts=n_experts)
+                        n_layers=len(self.layers), d_ff=d_ff,
+                        n_experts=n_experts)
+        self.arch = dict(arch or {})
+        self.remat = bool(remat)
         self.dtype = dtype
         self.axes = set(mesh.axis_names)
+        for attn, ffn in self.layers:
+            if (attn not in lm_layers.ATTENTION_KINDS
+                    or ffn not in lm_layers.FFN_KINDS):
+                raise ValueError("unknown layer kinds %r" % ((attn, ffn),))
+        #: the first block throughout: its flat table, no learned norm
+        self.classic = all(k == ("mha", "soft_moe") for k in self.layers)
+        if not self.classic and any(
+                mesh.shape[a] > 1 for a in self.axes - {"dp"}):
+            raise NotImplementedError(
+                "layer kinds beside ('mha', 'soft_moe') train on dp meshes; "
+                "got axes %s" % dict(mesh.shape))
         self._step_jit = None   # ONE compiled step; lr is a traced arg
         self._step_cache = {}   # lr -> binding wrapper (identity-stable)
         self._step_calls = 0    # numbers the transformer.step spans
+        self._stats_jit = None  # routing_stats' forward
+
+    @classmethod
+    def from_config(cls, mesh, cfg, dtype=np.float32, remat=False):
+        """A model of MLA layers from a published ``config.json``'s keys
+        (the DeepSeek-MLA family's names, ``model_type: sarvam_mla``
+        among them): the first ``first_k_dense_replace`` layers with a
+        SwiGLU FFN, the rest routed. ``num_experts`` counts the experts
+        HELD; ``cfg["published"]["num_experts"]`` (the router's width) and
+        ``cfg["deployment"]["experts_held"]`` (their range) say of which
+        share, and default to all of them."""
+        n_dense = cfg.get("first_k_dense_replace", 0)
+        layers = [("mla", "swiglu" if li < n_dense else "moe")
+                  for li in range(cfg["num_hidden_layers"])]
+        held = cfg.get("deployment", {}).get(
+            "experts_held", (0, cfg["num_experts"]))
+        moe = {"n_experts": cfg.get("published", {}).get(
+                   "num_experts", cfg["num_experts"]),
+               "top_k": cfg["num_experts_per_tok"],
+               "scale": cfg["routed_scaling_factor"],
+               "d_expert": cfg["moe_intermediate_size"],
+               "n_shared": cfg["num_shared_experts"],
+               "experts_held": (int(held[0]), int(held[1]))}
+        arch = {k: cfg[k] for k in (
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+            "kv_lora_rank", "rms_norm_eps")}
+        arch["rope"] = dict(cfg.get("rope_scaling") or {},
+                            theta=cfg["rope_theta"])
+        arch["moe"] = moe
+        return cls(mesh, vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
+                   n_heads=cfg["num_attention_heads"],
+                   d_ff=cfg["intermediate_size"], dtype=dtype, layers=layers,
+                   arch=arch, remat=remat)
 
     # --- sharding helpers -------------------------------------------------
     def _ns(self, *spec):
@@ -51,47 +115,69 @@ class TransformerParallel:
         spec = tuple(s if s in self.axes else None for s in spec)
         return NamedSharding(self.mesh, PartitionSpec(*spec))
 
-    def param_shardings(self):
+    def param_table(self):
+        """name -> (shape, init) of every leaf, in the order keys are
+        folded in: ``("normal", std)`` or a constant."""
         c = self.cfg
-        sh = {"embed": self._ns(None, None),
-              "out_w": self._ns(None, None)}
-        for li in range(c["n_layers"]):
+        d, f, e = c["d_model"], c["d_ff"], c["n_experts"]
+        std = ("normal", 0.02)
+        table = {"embed": ((c["vocab"], d), std),
+                 "out_w": ((d, c["vocab"]), std)}
+        if not self.classic:
+            table["final_norm"] = ((d,), 1.0)
+        for li, kinds in enumerate(self.layers):
             p = "l%d_" % li
-            # column-parallel QKV (heads on tp), row-parallel proj
-            sh[p + "wq"] = self._ns(None, "tp")
-            sh[p + "wk"] = self._ns(None, "tp")
-            sh[p + "wv"] = self._ns(None, "tp")
-            sh[p + "wo"] = self._ns("tp", None)
-            # experts on ep; hidden dim on tp (Megatron FFN split)
-            sh[p + "w1"] = self._ns("ep", None, "tp")
-            sh[p + "w2"] = self._ns("ep", "tp", None)
-            sh[p + "gate"] = self._ns(None, "ep")
+            if kinds[0] == "mha":
+                for name in ("wq", "wk", "wv", "wo"):
+                    table[p + name] = ((d, d), std)
+            if kinds[1] == "soft_moe":
+                table[p + "w1"] = ((e, d, f), std)
+                table[p + "w2"] = ((e, f, d), std)
+                table[p + "gate"] = ((d, e), std)
+            table.update(lm_layers.layer_table(li, kinds, c, self.arch))
+        return table
+
+    def param_shardings(self):
+        # the first block: column-parallel QKV (heads on tp), row-parallel
+        # proj; experts on ep, hidden dim on tp (Megatron FFN split). The
+        # other kinds' leaves are replicated (dp meshes only)
+        sh = {name: self._ns(*(None,) * len(shape))
+              for name, (shape, _) in self.param_table().items()}
+        for li, (attn, ffn) in enumerate(self.layers):
+            p = "l%d_" % li
+            if attn == "mha":
+                sh[p + "wq"] = self._ns(None, "tp")
+                sh[p + "wk"] = self._ns(None, "tp")
+                sh[p + "wv"] = self._ns(None, "tp")
+                sh[p + "wo"] = self._ns("tp", None)
+            if ffn == "soft_moe":
+                sh[p + "w1"] = self._ns("ep", None, "tp")
+                sh[p + "w2"] = self._ns("ep", "tp", None)
+                sh[p + "gate"] = self._ns(None, "ep")
         return sh
 
     def init(self, seed=0):
+        """Every leaf made on the device from a key (one jitted program;
+        nothing is drawn on the host), placed by ``param_shardings``."""
         import jax
+        import jax.numpy as jnp
 
-        c = self.cfg
-        r = np.random.RandomState(seed)
+        table = self.param_table()
+        dtype = jnp.dtype(self.dtype)
 
-        def mk(shape, scale):
-            return (r.randn(*shape) * scale).astype(self.dtype)
+        def make(key):
+            out = {}
+            for i, (name, (shape, init)) in enumerate(table.items()):
+                if isinstance(init, tuple):
+                    leaf = jnp.float32(init[1]) * jax.random.normal(
+                        jax.random.fold_in(key, i), shape, jnp.float32)
+                else:
+                    leaf = jnp.full(shape, init, jnp.float32)
+                out[name] = leaf.astype(dtype)
+            return out
 
-        d, h, f, e = c["d_model"], c["n_heads"], c["d_ff"], c["n_experts"]
-        params = {"embed": mk((c["vocab"], d), 0.02),
-                  "out_w": mk((d, c["vocab"]), 0.02)}
-        for li in range(c["n_layers"]):
-            p = "l%d_" % li
-            params[p + "wq"] = mk((d, d), 0.02)
-            params[p + "wk"] = mk((d, d), 0.02)
-            params[p + "wv"] = mk((d, d), 0.02)
-            params[p + "wo"] = mk((d, d), 0.02)
-            params[p + "w1"] = mk((e, d, f), 0.02)
-            params[p + "w2"] = mk((e, f, d), 0.02)
-            params[p + "gate"] = mk((d, e), 0.02)
-        shardings = self.param_shardings()
-        return {k: jax.device_put(v, shardings[k])
-                for k, v in params.items()}
+        return jax.jit(make, out_shardings=self.param_shardings())(
+            jax.random.PRNGKey(seed))
 
     # --- the model --------------------------------------------------------
     def _qkv(self, params, p, ln):
@@ -123,14 +209,16 @@ class TransformerParallel:
                                 params[p + "w2"])
         return jnp.einsum("bted,bte->btd", expert_out, gate)
 
-    def _forward(self, params, tokens):
+    def _attend(self, q, k, v, scale):
+        return _local_attention(q, k, v, self.mesh, scale=scale)
+
+    def _layer(self, li, params, x, collect=None):
+        """Layer ``li`` on (B, T, d)."""
         c = self.cfg
-        B, T = tokens.shape
-        d = c["d_model"]
-        with device_scope("embed"):
-            x = params["embed"][tokens]  # (B, T, d)
-        for li in range(c["n_layers"]):
-            p = "l%d_" % li
+        attn, ffn = self.layers[li]
+        p = "l%d_" % li
+        if attn == "mha":
+            B, T, d = x.shape
             # --- attention, heads split on tp, sequence ring on sp ------
             with device_scope("l%d/attn" % li):
                 q, k, v = self._qkv(params, p, _rms_norm(x))
@@ -144,12 +232,98 @@ class TransformerParallel:
                     att = _local_attention(q, k, v, self.mesh)
                 att = att.transpose(0, 2, 1, 3).reshape(B, T, d)
                 x = x + att @ params[p + "wo"]
+        else:
+            x = x + lm_layers.mla_attention(params, li, x, c, self.arch,
+                                            self._attend)
+        if ffn == "soft_moe":
             # --- MoE FFN: soft top-2-ish gate over ep-sharded experts ---
             with device_scope("l%d/ffn" % li):
-                x = x + self._moe_ffn(params, p, x)
+                return x + self._moe_ffn(params, p, x)
+        if ffn == "swiglu":
+            return x + lm_layers.swiglu_ffn(params, li, x, self.arch)
+        out, counts = self._routed_ffn(params, li, x)
+        if collect is not None:
+            collect.append((li, counts))
+        return x + out
+
+    def _routed_ffn(self, params, li, x):
+        """Layer ``li``'s routed FFN on (B, T, d) and each held expert's
+        pairs. ``pallas_call`` has no GSPMD partitioning rule, so on a dp
+        mesh every device routes its own rows and runs the grouped matmul
+        on them under ``shard_map``, the weights replicated (as
+        :func:`_local_attention` does for the flash kernels)."""
+        import jax
+
+        sub = {n: params[n] for n in lm_layers.layer_table(
+            li, (None, "moe"), self.cfg, self.arch)}
+
+        def local(sub, x):
+            return lm_layers.moe_ffn(sub, li, x, self.arch)
+
+        if dict(self.mesh.shape).get("dp", 1) == 1:
+            return local(sub, x)
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        def one_device(sub, x):
+            out, counts = local(sub, x)
+            return out, jax.lax.psum(counts, "dp")
+
+        return shard_map(one_device, mesh=self.mesh, in_specs=(P(), P("dp")),
+                         out_specs=(P("dp"), P()), check_vma=False)(sub, x)
+
+    def _forward(self, params, tokens, collect=None):
+        import jax
+
+        with device_scope("embed"):
+            x = params["embed"][tokens]  # (B, T, d)
+        for li in range(len(self.layers)):
+            if self.remat and collect is None:
+                names = [n for n in params if n.startswith("l%d_" % li)]
+                x = jax.checkpoint(
+                    lambda sub, x, li=li: self._layer(li, sub, x),
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        "flash_out", "flash_lse"))(
+                    {n: params[n] for n in names}, x)
+            else:
+                x = self._layer(li, params, x, collect)
         with device_scope("head_loss"):
-            logits = _rms_norm(x) @ params["out_w"]
+            ln = (_rms_norm(x) if self.classic else lm_layers.rms_norm(
+                x, params["final_norm"], self.arch["rms_norm_eps"]))
+            logits = ln @ params["out_w"]
         return logits
+
+    def routing_stats(self, params, tokens):
+        """Per expert layer, what the router sends to the experts held
+        here at these tokens: ``{"layer", "pairs_held", "load" (each held
+        expert's pairs, over all devices), "row_budget" (of one device's
+        layout)}``. A jitted forward of its own, outside the step."""
+        import jax
+
+        if self._stats_jit is None:
+            def stats(params, tokens):
+                collect = []
+                self._forward(params, tokens, collect)
+                return {li: counts for li, counts in collect}
+
+            self._stats_jit = jax.jit(stats)
+        m = self.arch["moe"]
+        lo, hi = m["experts_held"]
+        budget = lm_layers._moe.row_budget(
+            tokens.size // dict(self.mesh.shape).get("dp", 1), m["top_k"],
+            hi - lo, lm_layers._moe.GMM_BLOCK_ROWS)
+        loads = jax.device_get(self._stats_jit(params, tokens))
+        return [{"layer": li, "pairs_held": int(load.sum()),
+                 "load": [int(n) for n in load], "row_budget": budget}
+                for li, load in sorted(loads.items())]
+
+    def _serving_only_classic(self):
+        if not self.classic:
+            raise NotImplementedError(
+                "the serving forwards (prefill/decode/verify) run the "
+                "('mha', 'soft_moe') block only: a latent (MLA) layer needs "
+                "a latent KV cache and a routed layer a serving dispatch, "
+                "which this model does not have yet (ROADMAP Reach A5)")
 
     # --- incremental decode (generation subsystem) ------------------------
     def prefill_forward(self, params, tokens, attend=None):
@@ -178,6 +352,7 @@ class TransformerParallel:
         shared implementation, so training checkpoints serve unchanged
         on every path.
         """
+        self._serving_only_classic()
         import jax.numpy as jnp
 
         c = self.cfg
@@ -211,6 +386,7 @@ class TransformerParallel:
         ``_forward``/``prefill_forward``, so any checkpoint that trains
         here decodes here. Returns fp32 logits (S, V).
         """
+        self._serving_only_classic()
         import jax.numpy as jnp
 
         c = self.cfg
@@ -242,6 +418,7 @@ class TransformerParallel:
         encoding, so candidate positions need no offset). Returns fp32
         logits (S, Q, V).
         """
+        self._serving_only_classic()
         import jax.numpy as jnp
 
         c = self.cfg
@@ -386,7 +563,7 @@ def _prefill_attention(q, k, v):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _local_attention(q, k, v, mesh=None):
+def _local_attention(q, k, v, mesh=None, scale=None):
     """Non-sequence-sharded attention: the Pallas flash kernel on TPU
     (forward AND backward tiled — no T x T HBM materialization in
     training either), XLA reference elsewhere.
@@ -404,7 +581,7 @@ def _local_attention(q, k, v, mesh=None):
         from .flash_attention import flash_attention
 
         if mesh is None or mesh.devices.size == 1:
-            return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True, scale=scale)
         axes = dict(mesh.shape)
         ndp, ntp = axes.get("dp", 1), axes.get("tp", 1)
         sharded = {a for a, s in axes.items() if s > 1}
@@ -415,13 +592,14 @@ def _local_attention(q, k, v, mesh=None):
             spec = P("dp" if ndp > 1 else None,
                      "tp" if ntp > 1 else None, None, None)
             fn = shard_map(
-                lambda q, k, v: flash_attention(q, k, v, causal=True),
+                lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                scale=scale),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
             return fn(q, k, v)
     from .ring_attention import attention_reference
 
-    return attention_reference(q, k, v, causal=True)
+    return attention_reference(q, k, v, causal=True, scale=scale)
 
 
 def _rms_norm(x):

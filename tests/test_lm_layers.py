@@ -1,0 +1,478 @@
+"""A model described by its layers: latent attention (MLA), SwiGLU and
+routed-expert layers of ``TransformerParallel`` against the plain reference
+``perfbench/reference/mla_moe.py`` (float32, seeded, small widths), the
+shares of an expert-parallel deployment adding up to the uncut layer, the
+flash kernels at unequal q/k and v/o widths, the grouped matmul against a
+per-expert loop, rotary/yarn closed forms, and the first block unchanged
+to the bit."""
+import importlib
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from mxnet_tpu import observability as obs  # noqa: E402
+from mxnet_tpu.parallel import lm_layers, make_mesh, moe  # noqa: E402
+from mxnet_tpu.parallel.transformer import (TransformerParallel,  # noqa: E402
+                                            _local_attention, _rms_norm)
+from perfbench.reference import mla_moe as ref  # noqa: E402
+
+fa = importlib.import_module("mxnet_tpu.parallel.flash_attention")
+F32 = jnp.float32
+ROPE = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 16,
+        "type": "deepseek_yarn"}
+
+
+def small_cfg(layers=3, dense=1, experts=8, held=(2, 6), top_k=2):
+    return dict(
+        hidden_size=32, num_attention_heads=4, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, kv_lora_rank=16,
+        intermediate_size=64, moe_intermediate_size=24,
+        num_experts=held[1] - held[0], num_experts_per_tok=top_k,
+        num_shared_experts=1, routed_scaling_factor=2.5,
+        first_k_dense_replace=dense, num_hidden_layers=layers, vocab_size=64,
+        rope_theta=10000, rms_norm_eps=1e-6, rope_scaling=dict(ROPE),
+        published={"num_experts": experts},
+        deployment={"experts_held": list(held)},
+        optimizer={"learning_rate": 0.5})
+
+
+def one_chip():
+    return make_mesh({"dp": 1}, devices=jax.devices()[:1])
+
+
+def seeded_params(model, seed, spread=1.0):
+    """``model.init`` with the norm weights moved off 1 and the embedding
+    spread out, so that every leaf's gradient and the routing say
+    something."""
+    rs = np.random.RandomState(seed)
+    out = {}
+    for name, leaf in model.init(seed).items():
+        if leaf.ndim == 1 and not name.endswith("router_bias"):
+            leaf = leaf + 0.1 * jnp.asarray(rs.randn(*leaf.shape), F32)
+        if name in ("embed",) or name.endswith("router"):
+            leaf = leaf * (50.0 * spread)
+        out[name] = leaf
+    return out
+
+
+def batch(seed, B=2, T=32, vocab=64):
+    rs = np.random.RandomState(seed)
+    return (rs.randint(0, vocab, (B, T)).astype(np.int32),
+            rs.randint(0, vocab, (B, T)).astype(np.int32))
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max() / (np.abs(b).max() + 1e-30))
+
+
+# --- (a) the program against the plain reference -----------------------------
+@pytest.mark.parametrize("layers,dense,remat", [
+    (1, 1, False), (1, 0, False), (3, 1, False), (3, 1, True)],
+    ids=["dense_layer", "expert_layer", "model_1_plus_2",
+         "model_1_plus_2_recomputed"])
+def test_program_matches_the_reference(layers, dense, remat):
+    cfg = small_cfg(layers, dense)
+    model = TransformerParallel.from_config(one_chip(), cfg, remat=remat)
+    assert ({n: tuple(s) for n, (s, _) in model.param_table().items()}
+            == {n: tuple(s) for n, (s, _) in ref.param_table(cfg).items()})
+    params = seeded_params(model, 3)
+    start = {k: np.asarray(v) for k, v in params.items()}
+    tok, tgt = batch(0)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
+            params, tok, tgt)
+        want_loss, want = ref.loss_and_grads(
+            cfg, {k: jnp.asarray(v) for k, v in start.items()}, tok, tgt)
+        assert abs(float(loss) - float(want_loss)) < 2e-6 * float(want_loss)
+        for name in want:
+            assert rel(grads[name], want[name]) < 2e-4, name
+        held = [n for n in want if n.endswith("router_bias")]
+        assert all(float(jnp.abs(grads[n]).max()) == 0.0 for n in held)
+        # three SGD steps through the compiled step
+        batches = [batch(i) for i in range(3)]
+        step = model.step_fn(lr=cfg["optimizer"]["learning_rate"])
+        losses = []
+        for tok, tgt in batches:
+            params, loss = step(params, *model.shard_batch(tok, tgt))
+            losses.append(float(loss))
+        followed = ref.three_steps(cfg, lambda n: jnp.asarray(start[n]),
+                                   batches)
+    np.testing.assert_allclose(losses, followed["loss"], rtol=5e-6)
+    for name, norm in followed["change"].items():
+        got = float(np.linalg.norm(np.asarray(params[name], np.float64)
+                                   - start[name]))
+        assert abs(got - norm) <= 2e-4 * max(norm, 1e-6), name
+
+
+# --- (b) the shares add up ---------------------------------------------------
+@pytest.mark.parametrize("ranks", [2, 4, 8])
+def test_the_ranks_shares_add_up_to_the_uncut_layer(ranks):
+    """Routed parts of every rank, plus attention and the shared expert
+    counted once, equal the uncut reference's layer output."""
+    E, per = 8, 8 // ranks
+    whole = small_cfg(1, 0, experts=E, held=(0, E), top_k=3)
+    model = TransformerParallel.from_config(one_chip(), whole)
+    params = seeded_params(model, 11)
+    for n in ("moe_wg", "moe_wu", "moe_wd"):   # a routed part of size
+        params["l0_" + n] = 10.0 * params["l0_" + n]
+    x = 3.0 * jax.random.normal(jax.random.PRNGKey(5), (2, 16, 32), F32)
+    w = {n: params["l0_" + n] for n in ref.layer_leaves(whole, "expert")}
+    with jax.default_matmul_precision("highest"):
+        uncut = ref.layer(w, x, whole, "expert")
+        y = ref.mla(w, x, whole, False)
+        common = ref.ffn(w, y, whole, "expert", False, held=(0, 0))
+        total = common
+        for r in range(ranks):
+            held = (r * per, (r + 1) * per)
+            cfg = small_cfg(1, 0, experts=E, held=held, top_k=3)
+            rank = TransformerParallel.from_config(one_chip(), cfg)
+            mine = dict(params)
+            for n in ("moe_wg", "moe_wu", "moe_wd"):
+                mine["l0_" + n] = params["l0_" + n][held[0]:held[1]]
+            out = jax.jit(lambda p, x, rank=rank: rank._layer(0, p, x))(
+                mine, x)
+            total = total + (out - common)
+    assert rel(total, uncut) < 1e-5
+    assert rel(common, uncut) > 0.05      # the routed part is not nothing
+
+
+# --- (c) flash attention, q/k wider than v/o ---------------------------------
+def _dense_attention(q, k, v, scale):
+    return fa._dense_with_lse(q, k, v, causal=True, scale=scale)
+
+
+@pytest.mark.parametrize("widths", [(192, 128), (24, 16)])
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_pass"])
+def test_flash_with_unequal_widths_against_the_dense_formula(
+        widths, fused, monkeypatch):
+    D, Dv = widths
+    if not fused:
+        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_BUDGET", 0)
+    obs.set_enabled(True)
+    name = ("flash_attention.bwd_fused" if fused
+            else "flash_attention.bwd_two_pass")
+    before = (obs.metrics.get_value(name, 0),
+              obs.metrics.get_value("flash_attention.dqk_ne_dv", 0))
+    B, H, T = 1, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(D), 5)
+    q, k = (jax.random.normal(ks[i], (B, H, T, D), F32) for i in (0, 1))
+    v, do = (jax.random.normal(ks[i], (B, H, T, Dv), F32) for i in (2, 3))
+    dlse = jax.random.normal(ks[4], (B, H, T), F32)
+    scale = 1.37 ** 2 / math.sqrt(D)
+
+    def run(fn):
+        def total(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * do) + jnp.sum(lse * dlse), out
+        return jax.value_and_grad(total, (0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, out), grads = run(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, scale=scale, block_q=16, block_k=16,
+        block_q_bwd=16, block_k_bwd=16, interpret=True, return_lse=True))
+    (_, want_out), want = run(lambda q, k, v: _dense_attention(q, k, v,
+                                                               scale))
+    assert out.shape == (B, H, T, Dv)
+    assert rel(out, want_out) < 2e-5
+    for got, ref_grad, what in zip(grads, want, ("dq", "dk", "dv")):
+        assert got.shape == ref_grad.shape
+        assert rel(got, ref_grad) < 5e-5, what
+    assert obs.metrics.get_value(name, 0) > before[0]
+    assert obs.metrics.get_value("flash_attention.dqk_ne_dv", 0) > before[1]
+
+
+def test_flash_refuses_q_and_k_of_different_widths():
+    q = jnp.zeros((1, 1, 16, 8), F32)
+    with pytest.raises(ValueError, match="q and k widths differ"):
+        fa.flash_attention(q, jnp.zeros((1, 1, 16, 4), F32), q, interpret=True)
+
+
+# --- (d, e) the grouped matmul and the dispatch ------------------------------
+def _routing(case, N, k, E):
+    if case == "uneven":       # counts that are no multiple of the tile
+        logits = jax.random.normal(jax.random.PRNGKey(1), (N, E), F32)
+        return moe.route(logits, jnp.zeros(E), k, 2.5)[0]
+    if case == "empty_expert":  # expert 2 of the held range sees no row
+        idx = moe.route(jax.random.normal(jax.random.PRNGKey(2), (N, E), F32)
+                        .at[:, 4].set(-1e9), jnp.zeros(E), k, 2.5)[0]
+        return idx
+    # one held expert takes every row; the other pair falls outside
+    return jnp.stack([jnp.full(N, 3), jnp.full(N, 7)], 1).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["uneven", "empty_expert", "one_takes_all"])
+@pytest.mark.parametrize("tm", [8, 16])
+def test_grouped_matmul_against_a_per_expert_loop(case, tm):
+    N, k, E, held, d, f = 40, 2, 8, (2, 6), 16, 24
+    idx = _routing(case, N, k, E)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (N, d), F32)
+    w = 0.1 * jax.random.normal(ks[1], (held[1] - held[0], d, f), F32)
+    weight = jax.random.uniform(ks[2], (N, k), F32)
+    g = jax.random.normal(ks[3], (N, f), F32)
+    plan = moe.plan_dispatch(idx, held, tm)
+    counts = np.asarray(plan["counts"])
+    assert counts.sum() == int(np.sum((np.asarray(idx) >= held[0])
+                                      & (np.asarray(idx) < held[1])))
+    if case == "uneven":
+        assert any(c % tm for c in counts)
+    if case == "empty_expert":
+        assert counts[2] == 0
+    if case == "one_takes_all":
+        assert counts.tolist() == [0, N, 0, 0]
+
+    def program(x, w, weight):
+        rows = moe.dispatch(x, plan)
+        return moe.combine(moe.gmm(rows, w, plan, block_rows=tm), weight,
+                           plan)
+
+    def loop(x, w, weight):
+        out = jnp.zeros((N, f), F32)
+        for e in range(*held):
+            w_e = jnp.sum(jnp.where(idx == e, weight, 0.0), axis=1)
+            out = out + w_e[:, None] * (x @ w[e - held[0]])
+        return out
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(
+            lambda *a: jnp.sum(program(*a) * g), (0, 1, 2))(x, w, weight)
+        want = jax.value_and_grad(
+            lambda *a: jnp.sum(loop(*a) * g), (0, 1, 2))(x, w, weight)
+    assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
+    for a, b, what in zip(got[1], want[1], ("dx", "dw", "dweight")):
+        assert rel(a, b) < 1e-5, what
+
+
+def test_no_pair_is_dropped_when_every_choice_is_held():
+    """The worst case the routing can produce: every token's top-k inside
+    the held range. The row budget covers it; nothing is dropped."""
+    N, k, E, tm = 24, 4, 4, 8
+    idx = jnp.stack([jnp.roll(jnp.arange(E), i)[:k] for i in range(N)]
+                    ).astype(jnp.int32)
+    plan = moe.plan_dispatch(idx, (0, E), tm)
+    R = moe.row_budget(N, k, E, tm)
+    assert plan["pair_of_row"].shape == (R,) and R == N * k + E * tm
+    assert int(plan["counts"].sum()) == N * k
+    rows = np.asarray(plan["row_of_pair"]).reshape(-1)
+    assert len(set(rows.tolist())) == N * k and rows.max() < R
+    assert int(plan["n_live"][0]) * tm <= R
+    back = np.asarray(plan["pair_of_row"])[rows]
+    assert back.tolist() == list(range(N * k))
+    # and the layer's output is the whole routed sum
+    x = jax.random.normal(jax.random.PRNGKey(0), (N, 16), F32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (E, 16, 8), F32)
+    weight = jnp.ones((N, k), F32)
+    out = moe.combine(moe.gmm(moe.dispatch(x, plan), w, plan, block_rows=tm),
+                      weight, plan)
+    want = sum(jnp.where((idx == e).any(1)[:, None], x @ w[e], 0.0)
+               for e in range(E))
+    assert rel(out, want) < 1e-5
+
+
+def test_routing_stats_and_the_trace_time_counters():
+    cfg = small_cfg(3, 1)
+    model = TransformerParallel.from_config(one_chip(), cfg)
+    params = seeded_params(model, 5)
+    tok, _ = batch(1)
+    obs.set_enabled(True)
+    before = {n: obs.metrics.get_value(n, 0)
+              for n in ("moe.experts_held", "moe.row_budget")}
+    stats = model.routing_stats(params, tok)
+    assert [s["layer"] for s in stats] == [1, 2]
+    budget = moe.row_budget(tok.size, 2, 4, moe.GMM_BLOCK_ROWS)
+    for s in stats:
+        assert s["row_budget"] == budget and len(s["load"]) == 4
+        assert s["pairs_held"] == sum(s["load"]) <= tok.size * 2
+        assert max(s["load"]) <= budget
+    assert sum(s["pairs_held"] for s in stats) > 0
+    assert obs.metrics.get_value("moe.experts_held", 0) - before[
+        "moe.experts_held"] == 2 * 4
+    assert obs.metrics.get_value("moe.row_budget", 0) - before[
+        "moe.row_budget"] == 2 * budget
+
+
+# --- (f) closed forms --------------------------------------------------------
+SARVAM_ROPE = {"theta": 10000, "beta_fast": 32, "beta_slow": 1, "factor": 40,
+               "mscale": 1, "mscale_all_dim": 1,
+               "original_max_position_embeddings": 4096}
+
+
+def test_yarn_frequencies_and_the_softmax_scale():
+    inv = lm_layers.yarn_inv_freq(64, SARVAM_ROPE)
+    plain = 10000.0 ** (-np.arange(0, 64, 2) / 64.0)
+    # correction range: 64 ln(4096 / (2 pi n)) / (2 ln 10000) at n = 32, 1
+    low = math.floor(64 * math.log(4096 / (32 * 2 * math.pi))
+                     / (2 * math.log(10000)))
+    high = math.ceil(64 * math.log(4096 / (2 * math.pi))
+                     / (2 * math.log(10000)))
+    assert (low, high) == (10, 23)
+    np.testing.assert_allclose(inv[:low + 1], plain[:low + 1], rtol=1e-12)
+    np.testing.assert_allclose(inv[high:], plain[high:] / 40, rtol=1e-12)
+    mid = 16
+    ramp = (mid - low) / (high - low)
+    np.testing.assert_allclose(
+        inv[mid], plain[mid] / 40 * ramp + plain[mid] * (1 - ramp),
+        rtol=1e-12)
+    np.testing.assert_allclose(inv, ref.inv_freq(dict(
+        qk_rope_head_dim=64, rope_theta=10000, rope_scaling=SARVAM_ROPE)),
+        rtol=1e-12)
+    m = 0.1 * math.log(40) + 1
+    assert abs(m - 1.3689) < 5e-5
+    arch = {"qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "rope": SARVAM_ROPE}
+    assert lm_layers.mla_scale(arch) == pytest.approx(192 ** -0.5 * m * m,
+                                                      rel=1e-12)
+    plain_arch = dict(arch, rope={"theta": 10000})
+    assert lm_layers.mla_scale(plain_arch) == pytest.approx(192 ** -0.5)
+    np.testing.assert_allclose(
+        lm_layers.yarn_inv_freq(64, {"theta": 10000}), plain)
+
+
+@pytest.mark.parametrize("position", [0, 1, 4095, 8191])
+def test_rope_rotates_each_pair_by_its_angle(position):
+    T, dim = 8192, 64
+    cos, sin = lm_layers.rope_tables(T, dim, SARVAM_ROPE)
+    x = jax.random.normal(jax.random.PRNGKey(position), (T, dim), F32)
+    got = np.asarray(lm_layers.apply_rope(x, cos, sin))[position]
+    inv = lm_layers.yarn_inv_freq(dim, SARVAM_ROPE)
+    row = np.asarray(x[position], np.float64)
+    z = (row[:32] + 1j * row[32:]) * np.exp(1j * position * inv)
+    np.testing.assert_allclose(got, np.concatenate([z.real, z.imag]),
+                               atol=2e-5)
+    if position == 0:
+        np.testing.assert_array_equal(got, np.asarray(x[0]))
+    # a rotation: the norm of every pair stands
+    np.testing.assert_allclose(got[:32] ** 2 + got[32:] ** 2,
+                               row[:32] ** 2 + row[32:] ** 2, rtol=1e-4)
+
+
+def test_the_expert_bias_changes_the_selection_and_not_the_weights():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (64, 16), F32)
+    idx0, w0 = moe.route(logits, jnp.zeros(16), 8, 2.5)
+    bias = jnp.zeros(16).at[3].set(10.0).at[5].set(-10.0)
+    idx1, w1 = moe.route(logits, bias, 8, 2.5)
+    assert bool((idx1 == 3).any(1).all()) and not bool((idx1 == 5).any())
+    assert not bool((idx0 == 3).any(1).all())
+    score = jax.nn.sigmoid(logits)
+    picked = jnp.take_along_axis(score, idx1, 1)
+    np.testing.assert_allclose(
+        w1, 2.5 * picked / picked.sum(1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(w1.sum(1), 2.5, rtol=1e-6)
+    np.testing.assert_allclose(w0.sum(1), 2.5, rtol=1e-6)
+    assert float(jnp.abs(jax.grad(lambda b: moe.route(logits, b, 8, 2.5)[1]
+                                  .sum())(bias)).max()) == 0.0
+
+
+# --- (g) the first block, unchanged to the bit -------------------------------
+def _first_block_loss(model, params, tokens, targets):
+    """The block as it stood before layers had kinds, copied."""
+    c = model.cfg
+    B, T = tokens.shape
+    d, H = c["d_model"], c["n_heads"]
+    x = params["embed"][tokens]
+    for li in range(c["n_layers"]):
+        p = "l%d_" % li
+        ln = _rms_norm(x)
+        q, k, v = ((ln @ params[p + n]).reshape(B, T, H, d // H)
+                   .transpose(0, 2, 1, 3) for n in ("wq", "wk", "wv"))
+        att = _local_attention(q, k, v, model.mesh)
+        x = x + att.transpose(0, 2, 1, 3).reshape(B, T, d) @ params[p + "wo"]
+        ln = _rms_norm(x)
+        gate = jax.nn.softmax(ln @ params[p + "gate"], axis=-1)
+        hidden = jax.nn.gelu(jnp.einsum("btd,edf->btef", ln,
+                                        params[p + "w1"]))
+        out = jnp.einsum("btef,efd->bted", hidden, params[p + "w2"])
+        x = x + jnp.einsum("bted,bte->btd", out, gate)
+    logits = _rms_norm(x) @ params["out_w"]
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    return jnp.mean(-jnp.take_along_axis(logp, targets[..., None],
+                                         axis=-1)[..., 0])
+
+
+@pytest.mark.parametrize("sizes", [
+    dict(vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, n_experts=2),
+    dict(vocab=32, d_model=16, n_heads=2, n_layers=1, d_ff=32, n_experts=2),
+    dict(vocab=256, d_model=64, n_heads=4, n_layers=2, d_ff=128,
+         n_experts=1)], ids=["default", "tiny", "rehearsal"])
+def test_the_first_block_is_unchanged_to_the_bit(sizes):
+    model = TransformerParallel(one_chip(), **sizes)
+    assert model.classic and "final_norm" not in model.param_table()
+    assert list(model.param_table())[:3] == ["embed", "out_w", "l0_wq"]
+    params = model.init(4)
+    tok, tgt = batch(2, T=16, vocab=sizes["vocab"])
+    got = jax.jit(jax.value_and_grad(model.loss_fn))(params, tok, tgt)
+    want = jax.jit(jax.value_and_grad(
+        lambda p, a, b: _first_block_loss(model, p, a, b)))(params, tok, tgt)
+    assert float(got[0]) == float(want[0])
+    for name in want[1]:
+        np.testing.assert_array_equal(np.asarray(got[1][name]),
+                                      np.asarray(want[1][name]))
+
+
+def test_init_makes_every_leaf_on_the_device_from_the_key():
+    model = TransformerParallel.from_config(one_chip(), small_cfg())
+    a, b, c = model.init(1), model.init(1), model.init(2)
+    table = model.param_table()
+    assert set(a) == set(table) == set(model.param_shardings())
+    for name, (shape, init) in table.items():
+        assert a[name].shape == tuple(shape) and a[name].dtype == np.float32
+        np.testing.assert_array_equal(np.asarray(a[name]), np.asarray(b[name]))
+        if isinstance(init, tuple):
+            assert float(jnp.abs(a[name] - c[name]).max()) > 0
+            assert 0.01 < float(jnp.std(a[name])) < 0.04
+        else:
+            assert float(jnp.abs(a[name] - init).max()) == 0.0
+
+
+# --- (h) what this model does not serve yet ----------------------------------
+@pytest.mark.parametrize("forward", ["prefill_forward", "decode_forward",
+                                     "verify_forward"])
+def test_serving_forwards_refuse_a_latent_layer(forward):
+    model = TransformerParallel.from_config(one_chip(), small_cfg())
+    tokens = jnp.zeros((1, 4), jnp.int32)
+    args = (None, tokens) if forward == "prefill_forward" else (
+        None, tokens, lambda *a: None)
+    with pytest.raises(NotImplementedError, match="latent"):
+        getattr(model, forward)(*args)
+
+
+def test_new_kinds_refuse_a_mesh_that_is_not_data_parallel():
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="dp meshes"):
+        TransformerParallel.from_config(mesh, small_cfg())
+    with pytest.raises(ValueError, match="unknown layer kinds"):
+        TransformerParallel(one_chip(), layers=[("mla", "dense")])
+
+
+def test_a_model_of_new_kinds_trains_data_parallel_on_two_devices():
+    """On a dp mesh every device routes its own rows under ``shard_map``:
+    the loss, every leaf after the step (the replicated weights' gradients
+    are summed over the devices) and the routing counts are the one-device
+    model's."""
+    cfg = small_cfg(2, 1)
+    two = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    tok, tgt = batch(7)
+    runs = []
+    for mesh in (one_chip(), two):
+        model = TransformerParallel.from_config(mesh, cfg)
+        params = seeded_params(model, 9)
+        stats = model.routing_stats(params, model.shard_batch(tok, tgt)[0])
+        step = model.step_fn(lr=0.5)
+        with jax.default_matmul_precision("highest"):
+            params, loss = step(params, *model.shard_batch(tok, tgt))
+        runs.append((float(loss), jax.device_get(params), stats))
+    (loss1, leaves1, stats1), (loss2, leaves2, stats2) = runs
+    assert loss1 == pytest.approx(loss2, rel=1e-5)
+    for name in leaves1:
+        assert rel(leaves2[name], leaves1[name]) < 1e-5, name
+    assert [s["load"] for s in stats1] == [s["load"] for s in stats2]
+    assert stats2[0]["row_budget"] == moe.row_budget(
+        tok.size // 2, 2, 4, moe.GMM_BLOCK_ROWS)

@@ -88,6 +88,46 @@ def test_flash_two_pass_backward_lowers_for_tpu(monkeypatch):
         "flash_attention_bwd_dq"]
 
 
+@pytest.mark.parametrize("shape", [
+    (1, 64, 8192, 192, 128),   # the cell sarvam_train_t8192_b1
+    (2, 4, 1024, 192, 128),
+])
+def test_flash_with_a_narrower_v_lowers_for_tpu(shape):
+    B, H, T, D, Dv = shape
+    q, v = _aval((B, H, T, D), "bfloat16"), _aval((B, H, T, Dv), "bfloat16")
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=0.135)
+                       .astype(jnp.float32))
+
+    names = _tpu_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
+    assert names == ["flash_attention_fwd", "flash_attention_bwd_dqkv"]
+
+
+def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16"):
+    from mxnet_tpu.parallel import moe
+
+    def loss(x, w, idx, weight):
+        plan = moe.plan_dispatch(idx, (0, held), moe.GMM_BLOCK_ROWS)
+        rows = moe.gmm(moe.dispatch(x, plan), w, plan, interpret=False)
+        return jnp.sum(moe.combine(rows, weight, plan).astype(jnp.float32))
+
+    return (jax.grad(loss, argnums=(0, 1, 3)),
+            (_aval((tokens, d), dtype), _aval((held, d, f), dtype),
+             _aval((tokens, top_k), "int32"),
+             _aval((tokens, top_k), "float32")))
+
+
+def test_grouped_matmul_kernels_lower_for_tpu():
+    # the cell's expert layer: 8,192 tokens, top 8, 16 experts held,
+    # 4096 x 2048. Forward, the input's gradient (the same kernel, the
+    # weight read transposed) and the weight's gradient; the readers
+    # match the ``moe_gmm`` prefix
+    fn, avals = _gmm_step(8192, 8, 16, 4096, 2048)
+    names = _tpu_kernels(fn, *avals)
+    assert sorted(names) == ["moe_gmm_dw", "moe_gmm_fwd", "moe_gmm_fwd"]
+
+
 def test_flash_declines_a_length_with_no_legal_block():
     # 1100 > the backward bound 1024 and no multiple of 128 divides it: a
     # static decline to the dense formula, not a lowering error
@@ -195,6 +235,12 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
         q = _aval(shape, "bfloat16")
         assert compiled_calls(jax.grad(loss, argnums=(0, 1, 2)),
                               q, q, q) == 2, shape
+    # latent attention's 192/128 heads at the cell's length (8 of its 64
+    # heads), and the grouped matmul over 16 held experts at its widths
+    q, v = (_aval((1, 8, 8192, w), "bfloat16") for w in (192, 128))
+    assert compiled_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 2
+    fn, avals = _gmm_step(8192, 8, 16, 4096, 2048)
+    assert compiled_calls(fn, *avals) == 3
     epilogue = (("bias",), ("act", "relu"))
     assert compiled_calls(
         lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue),

@@ -613,8 +613,9 @@ def split_op_name(op_name):
     the backward pass (a recomputed forward operation under a
     checkpoint sits inside it and counts there), ``jvp(...)`` the
     forward, a leading ``update`` scope the optimizer; the scope is the
-    path less the jitted function's name, the transform wrappers, a
-    leading ``forward`` and the primitive's own name."""
+    path less the jitted function's name, the transform wrappers,
+    ``checkpoint`` / ``rematted_computation`` segments, a leading
+    ``forward`` and the primitive's own name."""
     path = (op_name or "").split(";")[0]
     if "transpose(" in path:
         phase = "backward"
@@ -628,6 +629,9 @@ def split_op_name(op_name):
         if not n:
             break
     segs = [seg for seg in path.split("/") if seg][jitted:-1]
+    # a recomputed layer's operations sit under jax.checkpoint's own scope
+    segs = [seg for seg in segs
+            if seg not in ("checkpoint", "rematted_computation")]
     if segs[:1] == ["forward"]:
         segs = segs[1:]
     if phase is None:
@@ -640,9 +644,12 @@ def split_op_name(op_name):
 def program_scope(scope):
     """The part of a scope path the program itself named: a graph node
     (``bn0``), or ``l17/attn`` as ``l*/attn`` (one row for the same scope
-    of every layer); what jax.numpy adds below it (an einsum's spec,
-    ``log_softmax``, a kernel's name) is left to the per-operation rows."""
-    layer = re.match(r"l\d+/([^/]+)", scope)
+    of every layer) and ``l3/moe/experts`` as ``l*/moe/experts`` (the
+    parts a latent-attention or routed layer names); what jax.numpy adds
+    below it (an einsum's spec, ``log_softmax``, a kernel's name) is left
+    to the per-operation rows."""
+    layer = re.match(r"l\d+/(attn/(?:proj|rope|flash|out)(?=/|$)|moe/[^/]+"
+                     r"|[^/]+)", scope)
     return "l*/" + layer.group(1) if layer else scope.split("/")[0]
 
 
