@@ -194,13 +194,15 @@ def swiglu_ffn(params, li, x, arch):
 
 def moe_ffn(params, li, x, arch):
     """Shared expert plus this rank's part of the routed sum on (B, T, d),
-    and each held expert's pairs (held,) int32."""
+    and what the router sent here: each held expert's pairs ``counts``
+    (held,) and the tiles its layout needs ``live_tiles`` (1,), int32."""
     import jax
     import jax.numpy as jnp
 
     p = "l%d_" % li
     m = arch["moe"]
     held = tuple(m["experts_held"])
+    tile = _moe.GMM_BLOCK_ROWS
     B, T, d = x.shape
     u = rms_norm(x, params[p + "ffn_norm"], arch["rms_norm_eps"])
     rows_in = u.reshape(B * T, d)
@@ -211,17 +213,29 @@ def moe_ffn(params, li, x, arch):
         idx, weight = _moe.route(logits, params[p + "router_bias"],
                                  m["top_k"], m["scale"])
     with device_scope("l%d/moe/dispatch" % li):
-        plan = _moe.plan_dispatch(idx, held, _moe.GMM_BLOCK_ROWS)
+        plan = _moe.plan_dispatch(idx, held, tile)
+        compact = _moe.plan_dispatch(idx, held, tile, _moe.compact_row_budget(
+            B * T, m["top_k"], held[1] - held[0], m["n_experts"], tile))
         counter("moe.experts_held").inc(held[1] - held[0])
         counter("moe.row_budget").inc(plan["pair_of_row"].shape[0])
-        rows = _moe.dispatch(rows_in, plan)
-    with device_scope("l%d/moe/experts" % li):
-        act = (jax.nn.silu(_moe.gmm(rows, params[p + "moe_wg"], plan))
-               * _moe.gmm(rows, params[p + "moe_wu"], plan))
-        out_rows = _moe.gmm(act, params[p + "moe_wd"], plan)
+        counter("moe.compact_row_budget").inc(compact["pair_of_row"].shape[0])
+
+    def routed(plan, rows_in, weight, wg, wu, wd):
+        with device_scope("l%d/moe/dispatch" % li):
+            rows = _moe.dispatch(rows_in, plan)
+        with device_scope("l%d/moe/experts" % li):
+            act = (jax.nn.silu(_moe.gmm(rows, wg, plan))
+                   * _moe.gmm(rows, wu, plan))
+            out_rows = _moe.gmm(act, wd, plan)
+        with device_scope("l%d/moe/combine" % li):
+            return _moe.combine(out_rows, weight, plan)
+
+    out = _moe.in_the_layout_that_fits(
+        routed, compact, plan, rows_in, weight,
+        *(params[p + n] for n in ("moe_wg", "moe_wu", "moe_wd")))
     with device_scope("l%d/moe/shared" % li):
         shared = _swiglu(u, params[p + "shared_wg"], params[p + "shared_wu"],
                          params[p + "shared_wd"])
     with device_scope("l%d/moe/combine" % li):
-        routed = _moe.combine(out_rows, weight, plan).reshape(B, T, d)
-        return shared + routed, plan["counts"]
+        return (shared + out.reshape(B, T, d),
+                {"counts": plan["counts"], "live_tiles": plan["n_live"]})

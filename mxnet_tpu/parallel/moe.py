@@ -8,12 +8,21 @@ grouped matmul over those experts as Pallas TPU kernels.
 - :func:`plan_dispatch` lays the (token, expert) pairs whose expert lies in
   the held range out as rows sorted by expert, each expert's group padded
   to a whole number of row tiles (at least one, so every held expert's
-  weight gradient is written). Shapes are static: the row budget is the
-  worst case the routing can produce (every token's top-k inside the held
-  range) and the kernels skip the tiles past the live ones, so no pair is
-  ever dropped and the budget costs memory and grid steps, not matmuls.
-- :func:`dispatch` / :func:`combine` move token rows into and out of that
-  layout; each is the other's transpose, so both directions are gathers.
+  weight gradient is written). Shapes are static and no pair is ever
+  dropped, so there are two row budgets: :func:`row_budget`, the worst
+  case the routing can produce (every token's top-k inside the held
+  range), and :func:`compact_row_budget`, twice what an even router sends
+  the held range. The live rows are a prefix of either layout and the
+  kernels skip the tiles past them.
+- :func:`in_the_layout_that_fits` runs the routed block in the compact
+  layout where this step's routing fits it and in the worst-case layout
+  otherwise, chosen on the device from the plan's live-tile count: the
+  step pays for the rows the router sent, and a routing past the compact
+  budget costs time, never a pair.
+- :func:`dispatch` / :func:`combine` move token rows into and out of a
+  layout; each is the other's transpose. Into it is a gather by row; out
+  of it is a sum over the pairs (a gather) or over the rows (a sorted
+  segment sum), whichever are fewer.
 - :func:`gmm` is the grouped matmul ``rows[i] @ w[expert_of_tile(i)]``;
   its device events are named ``moe_gmm_fwd`` (also the input's gradient,
   with the weight read transposed) and ``moe_gmm_dw`` (the weight's
@@ -30,7 +39,8 @@ import functools
 from .pallas_common import pallas_call
 
 __all__ = ["route", "plan_dispatch", "dispatch", "combine", "gmm",
-           "row_budget", "GMM_BLOCK_ROWS"]
+           "in_the_layout_that_fits", "row_budget", "compact_row_budget",
+           "GMM_BLOCK_ROWS"]
 
 #: rows of a grouped-matmul tile, and what each expert's group is padded
 #: to; interpreted (tests) any multiple of 8 works
@@ -69,20 +79,44 @@ def row_budget(n_tokens, top_k, n_held, block_rows):
     return n_tokens * min(top_k, n_held) + n_held * block_rows
 
 
-def plan_dispatch(idx, held, block_rows):
-    """The layout of this rank's share. ``idx``: (N, k) global expert ids;
-    ``held``: (lo, hi) the range of experts held. Returns a dict of int32
-    arrays: ``row_of_pair`` (N, k) (R where the pair's expert is not held),
-    ``pair_of_row`` (R,) (N*k on padding and dead rows), per row tile
-    ``tile_expert`` / ``tile_first`` / ``tile_last``, ``n_live`` (1,) the
-    live tiles, and ``counts`` (held,) each held expert's pairs."""
+#: the compact layout's room over the pairs the router sends the held range
+#: in expectation. Twice: the widest held load seen at the seeded start of
+#: the one cell with a routed layer is +10.6% (PERF.md, PR 28), a trained
+#: router balances its load, and a routing past it costs the worst-case
+#: layout's time for that layer and step, never a pair
+_COMPACT_ROOM = 2
+
+
+def compact_row_budget(n_tokens, top_k, n_held, n_experts, block_rows):
+    """Rows of the layout the routing fits in practice: ``_COMPACT_ROOM``
+    times the pairs a router that spreads its choices evenly sends the
+    held experts, plus each group's padding to whole tiles; never more
+    than :func:`row_budget`, and equal to it where every expert is held."""
+    expected = -(-n_tokens * top_k * n_held // n_experts)
+    return min(row_budget(n_tokens, top_k, n_held, block_rows),
+               _COMPACT_ROOM * expected + n_held * block_rows)
+
+
+def plan_dispatch(idx, held, block_rows, n_rows=None):
+    """The layout of this rank's share in ``n_rows`` rows (None:
+    :func:`row_budget`, which every routing fits). ``idx``:
+    (N, k) global expert ids; ``held``: (lo, hi) the range of experts held.
+    Returns a dict of int32 arrays: ``row_of_pair`` (N, k) (R where the
+    pair's expert is not held), ``pair_of_row`` (R,) (N*k on padding and
+    dead rows), per row tile ``tile_expert`` / ``tile_first`` /
+    ``tile_last``, ``n_live`` (1,) the live tiles, and ``counts`` (held,)
+    each held expert's pairs. The live rows are a prefix of the layout, so
+    a layout of fewer rows is the worst-case one cut short: it holds every
+    pair where ``n_live * block_rows <= n_rows``, which is for the caller
+    to see to (:func:`in_the_layout_that_fits`); pairs past it are marked
+    as not held."""
     import jax.numpy as jnp
 
     lo, hi = held
     n_held = hi - lo
     N, k = idx.shape
     P = N * k
-    R = row_budget(N, k, n_held, block_rows)
+    R = row_budget(N, k, n_held, block_rows) if n_rows is None else n_rows
     n_tiles = R // block_rows
     local = idx.reshape(P) - lo
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
@@ -97,8 +131,9 @@ def plan_dispatch(idx, held, block_rows):
     rank = jnp.arange(P, dtype=jnp.int32) - first_sorted[key_sorted]
     row_start = jnp.concatenate(
         [tile_start * block_rows, jnp.full((1,), R, jnp.int32)])
-    rows_sorted = jnp.where(key_sorted < n_held,
-                            row_start[key_sorted] + rank, R)
+    rows_sorted = row_start[key_sorted] + rank
+    rows_sorted = jnp.where((key_sorted < n_held) & (rows_sorted < R),
+                            rows_sorted, R)
     row_of_pair = jnp.zeros(P, jnp.int32).at[order].set(
         rows_sorted.astype(jnp.int32), unique_indices=True)
     pair_of_row = jnp.full(R, P, jnp.int32).at[rows_sorted].set(
@@ -125,16 +160,38 @@ def _take_rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
-def _gather_pairs(rows, weight, row_of_pair):
+def _weight_of_pairs(weight, pair):
+    """weight (N, k) at the flat pair ids ``pair``; 0 at N*k (no pair)."""
+    k = weight.shape[1]
+    return weight.at[pair // k, pair % k].get(mode="fill", fill_value=0)
+
+
+def _sum_to_tokens(rows, weight, row_of_pair, pair_of_row):
     """out[t] = sum_j weight[t, j] * rows[row_of_pair[t, j]] (absent pairs
-    add nothing), accumulated in float32."""
+    add nothing), accumulated in float32. Summed over whichever is fewer:
+    the (token, slot) pairs, each gathering its row (absent pairs a row of
+    zeros), or the layout's rows, taken in token order and added to their
+    tokens (padding and dead rows, whose contents are undefined, to none).
+    On the v5e at 20,480 rows of 4096 against 65,536 pairs the sorted
+    segment sum takes 4.5 ms, an unsorted scatter-add 5.1-5.2, the pairs'
+    gather 6.0 (tools/moe_layout_bench.py; PERF.md, PR 29)."""
+    import jax
     import jax.numpy as jnp
 
     N, k = row_of_pair.shape
-    picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
-        N, k, rows.shape[-1])
-    return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
-                      weight.astype(jnp.float32)).astype(rows.dtype)
+    if N * k <= pair_of_row.shape[0]:
+        picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
+            N, k, rows.shape[-1])
+        return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
+                          weight.astype(jnp.float32)).astype(rows.dtype)
+    by_token = jnp.argsort(pair_of_row).astype(jnp.int32)
+    pair = pair_of_row[by_token]            # ascending; N*k past the held
+    w_row = _weight_of_pairs(weight, pair).astype(jnp.float32)
+    weighted = jnp.where((pair < N * k)[:, None], _take_rows(
+        rows, by_token).astype(jnp.float32) * w_row[:, None], 0.0)
+    return jax.ops.segment_sum(
+        weighted, pair // k, num_segments=N,
+        indices_are_sorted=True).astype(rows.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -148,32 +205,37 @@ def _moves():
         return _take_rows(x, pair_of_row // row_of_pair.shape[1])
 
     def dispatch_fwd(x, row_of_pair, pair_of_row):
-        return dispatch(x, row_of_pair, pair_of_row), row_of_pair
+        return (dispatch(x, row_of_pair, pair_of_row),
+                (row_of_pair, pair_of_row))
 
-    def dispatch_bwd(row_of_pair, d_rows):
+    def dispatch_bwd(res, d_rows):
+        row_of_pair, pair_of_row = res
         ones = jnp.ones(row_of_pair.shape, jnp.float32)
-        return _gather_pairs(d_rows, ones, row_of_pair), None, None
+        return (_sum_to_tokens(d_rows, ones, row_of_pair, pair_of_row),
+                None, None)
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
 
     @jax.custom_vjp
     def combine(rows, weight, row_of_pair, pair_of_row):
-        return _gather_pairs(rows, weight, row_of_pair)
+        return _sum_to_tokens(rows, weight, row_of_pair, pair_of_row)
 
     def combine_fwd(rows, weight, row_of_pair, pair_of_row):
-        return (_gather_pairs(rows, weight, row_of_pair),
+        return (_sum_to_tokens(rows, weight, row_of_pair, pair_of_row),
                 (rows, weight, row_of_pair, pair_of_row))
 
     def combine_bwd(res, d_out):
+        # both gradients from one gather of d_out by row: the weight's is
+        # <rows[r], d_out[token of r]> at the pair's row (rows no pair
+        # names, undefined past the live tiles, are never read)
         rows, weight, row_of_pair, pair_of_row = res
-        N, k = row_of_pair.shape
-        w_row = _take_rows(weight.reshape(N * k), pair_of_row)
-        d_rows = (_take_rows(d_out, pair_of_row // k).astype(jnp.float32)
-                  * w_row[:, None].astype(jnp.float32)).astype(rows.dtype)
-        picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
-            N, k, rows.shape[-1])
-        d_weight = jnp.einsum("nkd,nd->nk", picked.astype(jnp.float32),
-                              d_out.astype(jnp.float32)).astype(weight.dtype)
+        k = row_of_pair.shape[1]
+        w_row = _weight_of_pairs(weight, pair_of_row)
+        by_row = _take_rows(d_out, pair_of_row // k).astype(jnp.float32)
+        d_rows = (by_row * w_row[:, None].astype(jnp.float32)).astype(
+            rows.dtype)
+        dots = jnp.sum(rows.astype(jnp.float32) * by_row, axis=1)
+        d_weight = _take_rows(dots, row_of_pair).astype(weight.dtype)
         return d_rows, d_weight, None, None
 
     combine.defvjp(combine_fwd, combine_bwd)
@@ -191,6 +253,48 @@ def combine(rows, weight, plan):
     * rows[row_of_pair[t, j]]`` over the pairs held here."""
     return _moves()[1](rows, weight, plan["row_of_pair"],
                        plan["pair_of_row"])
+
+
+def in_the_layout_that_fits(block, compact, worst, *operands):
+    """``block(plan, *operands)`` in the compact layout where this routing
+    fits it (``n_live`` tiles within its rows, known from the counts before
+    a row moves), else in the worst-case one: a ``lax.cond``, so the step
+    runs one branch's buffers. Both are exact and neither drops a pair.
+    Where the two layouts have the same rows no conditional is traced.
+
+    ``compact`` / ``worst``: :func:`plan_dispatch` of one routing at the
+    two budgets. ``block`` is differentiable in ``operands``. The backward
+    is again a conditional on the same predicate, and each of its branches
+    recomputes its own forward from the operands: differentiating a
+    ``cond`` directly would have each branch emit zeros in the shapes of
+    the other's residuals, the worst-case buffers among them."""
+    import jax
+
+    if compact["pair_of_row"].shape == worst["pair_of_row"].shape:
+        return block(worst, *operands)
+
+    def either(on_plan, fits, compact, worst, *args):
+        return jax.lax.cond(fits, lambda c, w, *a: on_plan(c, *a),
+                            lambda c, w, *a: on_plan(w, *a),
+                            compact, worst, *args)
+
+    def pull_back(plan, operands, d_out):
+        return jax.vjp(lambda *ops: block(plan, *ops), *operands)[1](d_out)
+
+    @jax.custom_vjp
+    def run(fits, compact, worst, operands):
+        return either(lambda plan, ops: block(plan, *ops),
+                      fits, compact, worst, operands)
+
+    def run_fwd(*args):
+        return run(*args), args
+
+    def run_bwd(args, d_out):
+        return None, None, None, either(pull_back, *args, d_out)
+
+    run.defvjp(run_fwd, run_bwd)
+    fits = worst["n_live"][0] <= compact["tile_expert"].shape[0]
+    return run(fits, compact, worst, operands)
 
 
 # --- the grouped matmul ----------------------------------------------------

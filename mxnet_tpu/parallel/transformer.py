@@ -241,16 +241,18 @@ class TransformerParallel:
                 return x + self._moe_ffn(params, p, x)
         if ffn == "swiglu":
             return x + lm_layers.swiglu_ffn(params, li, x, self.arch)
-        out, counts = self._routed_ffn(params, li, x)
+        out, sent = self._routed_ffn(params, li, x)
         if collect is not None:
-            collect.append((li, counts))
+            collect.append((li, sent))
         return x + out
 
     def _routed_ffn(self, params, li, x):
-        """Layer ``li``'s routed FFN on (B, T, d) and each held expert's
-        pairs. ``pallas_call`` has no GSPMD partitioning rule, so on a dp
-        mesh every device routes its own rows and runs the grouped matmul
-        on them under ``shard_map``, the weights replicated (as
+        """Layer ``li``'s routed FFN on (B, T, d) and what the router sent
+        here (``lm_layers.moe_ffn``: the pairs summed over the devices, the
+        live tiles of the fullest device's layout). ``pallas_call`` has no
+        GSPMD partitioning rule, so on a dp mesh every device routes its
+        own rows and runs the grouped matmul on them, in the layout its
+        own routing fits, under ``shard_map``, the weights replicated (as
         :func:`_local_attention` does for the flash kernels)."""
         import jax
 
@@ -266,8 +268,9 @@ class TransformerParallel:
         from jax.sharding import PartitionSpec as P
 
         def one_device(sub, x):
-            out, counts = local(sub, x)
-            return out, jax.lax.psum(counts, "dp")
+            out, sent = local(sub, x)
+            return out, {"counts": jax.lax.psum(sent["counts"], "dp"),
+                         "live_tiles": jax.lax.pmax(sent["live_tiles"], "dp")}
 
         return shard_map(one_device, mesh=self.mesh, in_specs=(P(), P("dp")),
                          out_specs=(P("dp"), P()), check_vma=False)(sub, x)
@@ -296,26 +299,38 @@ class TransformerParallel:
     def routing_stats(self, params, tokens):
         """Per expert layer, what the router sends to the experts held
         here at these tokens: ``{"layer", "pairs_held", "load" (each held
-        expert's pairs, over all devices), "row_budget" (of one device's
-        layout)}``. A jitted forward of its own, outside the step."""
+        expert's pairs, over all devices), "row_budget" and
+        "compact_budget" (of one device's layout), "live_rows" (of the
+        fullest device's layout: its groups padded to whole tiles), "fits"
+        (whether every device's step runs the layer in the compact
+        layout)}``. A jitted forward of its own, outside the step: which
+        layout a step takes is decided on the device."""
         import jax
 
         if self._stats_jit is None:
             def stats(params, tokens):
                 collect = []
                 self._forward(params, tokens, collect)
-                return {li: counts for li, counts in collect}
+                return dict(collect)
 
             self._stats_jit = jax.jit(stats)
         m = self.arch["moe"]
         lo, hi = m["experts_held"]
-        budget = lm_layers._moe.row_budget(
-            tokens.size // dict(self.mesh.shape).get("dp", 1), m["top_k"],
-            hi - lo, lm_layers._moe.GMM_BLOCK_ROWS)
-        loads = jax.device_get(self._stats_jit(params, tokens))
-        return [{"layer": li, "pairs_held": int(load.sum()),
-                 "load": [int(n) for n in load], "row_budget": budget}
-                for li, load in sorted(loads.items())]
+        moe = lm_layers._moe
+        n_tokens = tokens.size // dict(self.mesh.shape).get("dp", 1)
+        budget = moe.row_budget(n_tokens, m["top_k"], hi - lo,
+                                moe.GMM_BLOCK_ROWS)
+        compact = moe.compact_row_budget(n_tokens, m["top_k"], hi - lo,
+                                         m["n_experts"], moe.GMM_BLOCK_ROWS)
+        out = []
+        for li, sent in sorted(jax.device_get(
+                self._stats_jit(params, tokens)).items()):
+            live = int(sent["live_tiles"][0]) * moe.GMM_BLOCK_ROWS
+            out.append({"layer": li, "pairs_held": int(sent["counts"].sum()),
+                        "load": [int(n) for n in sent["counts"]],
+                        "row_budget": budget, "compact_budget": compact,
+                        "live_rows": live, "fits": live <= compact})
+        return out
 
     def _serving_only_classic(self):
         if not self.classic:

@@ -75,12 +75,17 @@ def rel(a, b):
 
 
 # --- (a) the program against the plain reference -----------------------------
-@pytest.mark.parametrize("layers,dense,remat", [
-    (1, 1, False), (1, 0, False), (3, 1, False), (3, 1, True)],
+@pytest.mark.parametrize("layers,dense,remat,experts", [
+    (1, 1, False, 8), (1, 0, False, 8), (3, 1, False, 8), (3, 1, True, 8),
+    (3, 1, False, 32), (3, 1, True, 32)],
     ids=["dense_layer", "expert_layer", "model_1_plus_2",
-         "model_1_plus_2_recomputed"])
-def test_program_matches_the_reference(layers, dense, remat):
-    cfg = small_cfg(layers, dense)
+         "model_1_plus_2_recomputed", "model_1_plus_2_two_layouts",
+         "model_1_plus_2_two_layouts_recomputed"])
+def test_program_matches_the_reference(layers, dense, remat, experts):
+    """At 8 experts the compact row budget is the worst-case one (one
+    layout, as before budgets were two); at 32 every routed layer traces
+    both and its step runs the compact one."""
+    cfg = small_cfg(layers, dense, experts=experts)
     model = TransformerParallel.from_config(one_chip(), cfg, remat=remat)
     assert ({n: tuple(s) for n, (s, _) in model.param_table().items()}
             == {n: tuple(s) for n, (s, _) in ref.param_table(cfg).items()})
@@ -208,18 +213,36 @@ def _routing(case, N, k, E):
     return jnp.stack([jnp.full(N, 3), jnp.full(N, 7)], 1).astype(jnp.int32)
 
 
-@pytest.mark.parametrize("case", ["uneven", "empty_expert", "one_takes_all"])
+@pytest.mark.parametrize("case,layout", [
+    ("uneven", "worst_case"), ("empty_expert", "worst_case"),
+    ("one_takes_all", "worst_case"),
+    ("uneven", "compact"), ("empty_expert", "compact"),
+    ("uneven", "the_one_that_fits"), ("empty_expert", "the_one_that_fits"),
+    ("one_takes_all", "the_one_that_fits")])
 @pytest.mark.parametrize("tm", [8, 16])
-def test_grouped_matmul_against_a_per_expert_loop(case, tm):
-    N, k, E, held, d, f = 40, 2, 8, (2, 6), 16, 24
+def test_grouped_matmul_against_a_per_expert_loop(case, layout, tm):
+    """``worst_case``: 4 of 8 experts held, where the two budgets are one.
+    The others hold 4 of 32: ``compact`` lays the rows out in the compact
+    budget, ``the_one_that_fits`` lets the routing choose, and where one
+    expert takes every row that is the worst-case layout: no pair is
+    dropped in either."""
+    N, k, held, d, f = 40, 2, (2, 6), 16, 24
+    E = 8 if layout == "worst_case" else 32
     idx = _routing(case, N, k, E)
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     x = jax.random.normal(ks[0], (N, d), F32)
     w = 0.1 * jax.random.normal(ks[1], (held[1] - held[0], d, f), F32)
     weight = jax.random.uniform(ks[2], (N, k), F32)
     g = jax.random.normal(ks[3], (N, f), F32)
-    plan = moe.plan_dispatch(idx, held, tm)
-    counts = np.asarray(plan["counts"])
+    worst = moe.plan_dispatch(idx, held, tm)
+    R = moe.row_budget(N, k, 4, tm)
+    Rc = moe.compact_row_budget(N, k, 4, E, tm)
+    assert (Rc == R) == (layout == "worst_case")
+    compact = moe.plan_dispatch(idx, held, tm, Rc)
+    fits = int(worst["n_live"][0]) * tm <= Rc
+    assert fits == (case != "one_takes_all" or layout == "worst_case")
+    counts = np.asarray(worst["counts"])
+    np.testing.assert_array_equal(counts, np.asarray(compact["counts"]))
     assert counts.sum() == int(np.sum((np.asarray(idx) >= held[0])
                                       & (np.asarray(idx) < held[1])))
     if case == "uneven":
@@ -228,11 +251,30 @@ def test_grouped_matmul_against_a_per_expert_loop(case, tm):
         assert counts[2] == 0
     if case == "one_takes_all":
         assert counts.tolist() == [0, N, 0, 0]
+    if fits:    # the compact layout is the worst-case one cut short
+        for name in ("pair_of_row", "tile_expert", "tile_first", "tile_last"):
+            np.testing.assert_array_equal(
+                np.asarray(compact[name]),
+                np.asarray(worst[name])[:compact[name].shape[0]])
+        np.testing.assert_array_equal(
+            np.asarray(compact["row_of_pair"]),
+            np.minimum(np.asarray(worst["row_of_pair"]), Rc))
+
+    def block(plan, x, w, weight):
+        rows = moe.dispatch(x, plan)
+        out = moe.combine(moe.gmm(rows, w, plan, block_rows=tm), weight, plan)
+        # a last row that says which layout this was
+        return jnp.concatenate(
+            [out, jnp.full((1, f), plan["pair_of_row"].shape[0], F32)])
 
     def program(x, w, weight):
-        rows = moe.dispatch(x, plan)
-        return moe.combine(moe.gmm(rows, w, plan, block_rows=tm), weight,
-                           plan)
+        if layout == "the_one_that_fits":
+            out = moe.in_the_layout_that_fits(block, compact, worst,
+                                              x, w, weight)
+        else:
+            out = block(compact if layout == "compact" else worst,
+                        x, w, weight)
+        return out[:N], out[N, 0]
 
     def loop(x, w, weight):
         out = jnp.zeros((N, f), F32)
@@ -242,13 +284,70 @@ def test_grouped_matmul_against_a_per_expert_loop(case, tm):
         return out
 
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(
-            lambda *a: jnp.sum(program(*a) * g), (0, 1, 2))(x, w, weight)
+        (got, rows_run), got_grads = jax.value_and_grad(
+            lambda *a: (lambda out, rows: (jnp.sum(out * g), rows))(
+                *program(*a)), (0, 1, 2), has_aux=True)(x, w, weight)
         want = jax.value_and_grad(
             lambda *a: jnp.sum(loop(*a) * g), (0, 1, 2))(x, w, weight)
-    assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
-    for a, b, what in zip(got[1], want[1], ("dx", "dw", "dweight")):
+    assert int(rows_run) == (Rc if fits else R)
+    assert abs(float(got) - float(want[0])) < 1e-4 * abs(float(want[0]))
+    for a, b, what in zip(got_grads, want[1], ("dx", "dw", "dweight")):
         assert rel(a, b) < 1e-5, what
+
+
+def _conds_outside_kernels(jaxpr):
+    """``cond`` equations of a jaxpr and of what it calls, the Pallas
+    kernels' bodies (whose ``pl.when`` is one) left out."""
+    from jax.extend import core
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        n += eqn.primitive.name == "cond"
+        for sub in jax.tree_util.tree_leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda v: isinstance(v, (core.Jaxpr,
+                                                 core.ClosedJaxpr))):
+            if isinstance(sub, core.ClosedJaxpr):
+                sub = sub.jaxpr
+            if isinstance(sub, core.Jaxpr):
+                n += _conds_outside_kernels(sub)
+    return n
+
+
+def test_two_budgets_trace_a_conditional_and_one_budget_none():
+    """Where every expert is held the two budgets are equal and the routed
+    block is traced once, in that layout; where they differ the forward is
+    one conditional and the backward another, on the same predicate."""
+    N, k, tm = 40, 2, 8
+    x = jnp.ones((N, 16), F32)
+    weight = jnp.ones((N, k), F32)
+
+    def traced(E, held):
+        idx = _routing("uneven", N, k, E)
+        n_held = held[1] - held[0]
+        w = jnp.ones((n_held, 16, 8), F32)
+        budgets = (moe.compact_row_budget(N, k, n_held, E, tm),
+                   moe.row_budget(N, k, n_held, tm))
+
+        def loss(x, w, weight):
+            plans = [moe.plan_dispatch(idx, held, tm, R) for R in budgets]
+            return jnp.sum(moe.in_the_layout_that_fits(
+                lambda plan, x, w, weight: moe.combine(
+                    moe.gmm(moe.dispatch(x, plan), w, plan, block_rows=tm),
+                    weight, plan), *plans, x, w, weight))
+
+        return budgets, _conds_outside_kernels(jax.make_jaxpr(
+            jax.grad(loss, (0, 1, 2)))(x, w, weight).jaxpr)
+
+    (compact, worst), conds = traced(8, (0, 8))
+    assert compact == worst == N * k + 8 * tm and conds == 0
+    (compact, worst), conds = traced(32, (2, 6))
+    assert compact == 2 * 10 + 4 * tm < worst == N * k + 4 * tm
+    assert conds == 2
+    assert moe.compact_row_budget(8192, 8, 16, 128, 256) == 20480
+    assert moe.row_budget(8192, 8, 16, 256) == 69632
 
 
 def test_no_pair_is_dropped_when_every_choice_is_held():
@@ -277,26 +376,33 @@ def test_no_pair_is_dropped_when_every_choice_is_held():
     assert rel(out, want) < 1e-5
 
 
-def test_routing_stats_and_the_trace_time_counters():
-    cfg = small_cfg(3, 1)
+@pytest.mark.parametrize("experts", [8, 32])
+def test_routing_stats_and_the_trace_time_counters(experts):
+    cfg = small_cfg(3, 1, experts=experts)
     model = TransformerParallel.from_config(one_chip(), cfg)
     params = seeded_params(model, 5)
     tok, _ = batch(1)
     obs.set_enabled(True)
-    before = {n: obs.metrics.get_value(n, 0)
-              for n in ("moe.experts_held", "moe.row_budget")}
+    names = ("moe.experts_held", "moe.row_budget", "moe.compact_row_budget")
+    before = {n: obs.metrics.get_value(n, 0) for n in names}
     stats = model.routing_stats(params, tok)
     assert [s["layer"] for s in stats] == [1, 2]
     budget = moe.row_budget(tok.size, 2, 4, moe.GMM_BLOCK_ROWS)
+    compact = moe.compact_row_budget(tok.size, 2, 4, experts,
+                                     moe.GMM_BLOCK_ROWS)
+    assert (compact == budget) == (experts == 8)
     for s in stats:
         assert s["row_budget"] == budget and len(s["load"]) == 4
         assert s["pairs_held"] == sum(s["load"]) <= tok.size * 2
         assert max(s["load"]) <= budget
+        assert s["compact_budget"] == compact
+        # 64 tokens: every held expert's group is its one tile
+        assert s["live_rows"] == 4 * moe.GMM_BLOCK_ROWS <= compact
+        assert s["fits"] is True
     assert sum(s["pairs_held"] for s in stats) > 0
-    assert obs.metrics.get_value("moe.experts_held", 0) - before[
-        "moe.experts_held"] == 2 * 4
-    assert obs.metrics.get_value("moe.row_budget", 0) - before[
-        "moe.row_budget"] == 2 * budget
+    moved = {n: obs.metrics.get_value(n, 0) - before[n] for n in names}
+    assert moved == {"moe.experts_held": 2 * 4, "moe.row_budget": 2 * budget,
+                     "moe.compact_row_budget": 2 * compact}
 
 
 # --- (f) closed forms --------------------------------------------------------
@@ -452,12 +558,15 @@ def test_new_kinds_refuse_a_mesh_that_is_not_data_parallel():
         TransformerParallel(one_chip(), layers=[("mla", "dense")])
 
 
-def test_a_model_of_new_kinds_trains_data_parallel_on_two_devices():
-    """On a dp mesh every device routes its own rows under ``shard_map``:
-    the loss, every leaf after the step (the replicated weights' gradients
-    are summed over the devices) and the routing counts are the one-device
+@pytest.mark.parametrize("experts", [8, 32],
+                         ids=["one_layout", "two_layouts"])
+def test_a_model_of_new_kinds_trains_data_parallel_on_two_devices(experts):
+    """On a dp mesh every device routes its own rows under ``shard_map``
+    (and, of two layouts, takes the one its own routing fits): the loss,
+    every leaf after the step (the replicated weights' gradients are
+    summed over the devices) and the routing counts are the one-device
     model's."""
-    cfg = small_cfg(2, 1)
+    cfg = small_cfg(2, 1, experts=experts)
     two = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     tok, tgt = batch(7)
     runs = []
@@ -476,3 +585,6 @@ def test_a_model_of_new_kinds_trains_data_parallel_on_two_devices():
     assert [s["load"] for s in stats1] == [s["load"] for s in stats2]
     assert stats2[0]["row_budget"] == moe.row_budget(
         tok.size // 2, 2, 4, moe.GMM_BLOCK_ROWS)
+    assert stats2[0]["compact_budget"] == moe.compact_row_budget(
+        tok.size // 2, 2, 4, experts, moe.GMM_BLOCK_ROWS)
+    assert all(s["fits"] for s in stats1 + stats2)

@@ -263,6 +263,11 @@ def test_reader_returns_none_for_a_program_without_the_spans(clean_ring,
      ("backward", "l1/attn")),
     ("jit(step)/jvp(head_loss)/jit(log_softmax)/reduce_max",
      ("forward", "head_loss/log_softmax")),
+    ("jit(step)/jvp()/cond/branch_1_fun/l1/moe/combine/take",
+     ("forward", "l1/moe/combine")),
+    ("jit(step)/transpose(jvp(jvp()))/checkpoint/cond/branch_0_fun/"
+     "transpose(jvp(l2/moe/dispatch))/segment_sum/scatter-add",
+     ("backward", "l2/moe/dispatch/segment_sum")),
     ("jit(step)/sub", ("unscoped", "")),
     ("", ("unscoped", "")),
 ])
@@ -270,6 +275,8 @@ def test_split_op_name(op_name, expected):
     assert trace_report.split_op_name(op_name) == expected
     assert trace_report.program_scope("l17/attn/flash_fwd") == "l*/attn"
     assert trace_report.program_scope("head_loss/log_softmax") == "head_loss"
+    assert (trace_report.program_scope("l2/moe/dispatch/segment_sum")
+            == "l*/moe/dispatch")
 
 
 XSPACE = """

@@ -128,6 +128,56 @@ def test_grouped_matmul_kernels_lower_for_tpu():
     assert sorted(names) == ["moe_gmm_dw", "moe_gmm_fwd", "moe_gmm_fwd"]
 
 
+def test_the_routed_block_lowers_for_tpu_in_both_layouts():
+    # the cell's routed block (8,192 tokens, top 8, 16 of 128 experts held,
+    # 4096 x 2048), forward and backward: a conditional each, both layouts
+    # in it, and under the ``moe_gmm`` prefix (which the benchmark's
+    # readers sum over) the two kernels and no other
+    from mxnet_tpu.parallel import moe
+
+    N, k, held, E, d, f = 8192, 8, 16, 128, 4096, 2048
+    tile = moe.GMM_BLOCK_ROWS
+    budgets = (moe.compact_row_budget(N, k, held, E, tile),
+               moe.row_budget(N, k, held, tile))
+    assert budgets == (20480, 69632)
+
+    def block(plan, x, weight, wg, wu, wd):
+        rows = moe.dispatch(x, plan)
+        act = (jax.nn.silu(moe.gmm(rows, wg, plan, interpret=False))
+               * moe.gmm(rows, wu, plan, interpret=False))
+        return moe.combine(moe.gmm(act, wd, plan, interpret=False), weight,
+                           plan)
+
+    def loss(layouts, x, weight, wg, wu, wd, idx):
+        plans = [moe.plan_dispatch(idx, (0, held), tile, R) for R in layouts]
+        return jnp.sum(moe.in_the_layout_that_fits(
+            block, *plans, x, weight, wg, wu, wd).astype(jnp.float32))
+
+    avals = (_aval((N, d), "bfloat16"), _aval((N, k), "float32"),
+             _aval((held, d, f), "bfloat16"), _aval((held, d, f), "bfloat16"),
+             _aval((held, f, d), "bfloat16"), _aval((N, k), "int32"))
+
+    def lowered(layouts):
+        fn = jax.value_and_grad(lambda *a: loss(layouts, *a),
+                                argnums=(0, 1, 2, 3, 4))
+        return jax.export.export(jax.jit(fn), platforms=["tpu"])(
+            *avals).mlir_module()
+
+    both = lowered(budgets)
+    names = re.findall(r'kernel_name = "([^"]*)"', both)
+    assert set(names) == {"moe_gmm_fwd", "moe_gmm_dw"}
+    assert both.count("stablehlo.case") == 2
+    assert re.search(r"tensor<69632x4096xbf16>", both)
+    # the compact layout alone: no float array as long as the worst-case
+    # layout (69,632 rows) or as the (token, slot) pairs (65,536)
+    compact = lowered((budgets[0], budgets[0]))
+    assert "stablehlo.case" not in compact
+    wide = re.findall(r"tensor<(?:65536|69632)(?:x\d+)*x(?:bf16|f32)>",
+                      compact)
+    assert not wide, sorted(set(wide))
+    assert re.search(r"tensor<20480x4096xbf16>", compact)
+
+
 def test_flash_declines_a_length_with_no_legal_block():
     # 1100 > the backward bound 1024 and no multiple of 128 divides it: a
     # static decline to the dense formula, not a lowering error

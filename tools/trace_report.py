@@ -614,7 +614,9 @@ def split_op_name(op_name):
     checkpoint sits inside it and counts there), ``jvp(...)`` the
     forward, a leading ``update`` scope the optimizer; the scope is the
     path less the jitted function's name, the transform wrappers,
-    ``checkpoint`` / ``rematted_computation`` segments, a leading
+    ``checkpoint`` / ``rematted_computation`` segments, a conditional's
+    ``cond`` / ``branch_<i>_fun`` (the routed layer runs in one of two
+    layouts: both count under the scopes inside them), a leading
     ``forward`` and the primitive's own name."""
     path = (op_name or "").split(";")[0]
     if "transpose(" in path:
@@ -631,7 +633,8 @@ def split_op_name(op_name):
     segs = [seg for seg in path.split("/") if seg][jitted:-1]
     # a recomputed layer's operations sit under jax.checkpoint's own scope
     segs = [seg for seg in segs
-            if seg not in ("checkpoint", "rematted_computation")]
+            if seg not in ("checkpoint", "rematted_computation", "cond")
+            and not re.fullmatch(r"branch_\d+_fun", seg)]
     if segs[:1] == ["forward"]:
         segs = segs[1:]
     if phase is None:
