@@ -10,7 +10,7 @@ repo's own means. One process, all local chips, no arguments:
 
 Legs (every leg runs even after another failed; exit 0 only if all passed):
 
-1. ``resnet50_sharded``     bench.py's path at its protocol: ResNet-50 NHWC
+1. ``resnet50_sharded``     the ResNet cells' path: ResNet-50 NHWC
                             bf16 through ``ShardedTrainer`` over a dp mesh.
 2. ``resnet50_module_fit``  the entry point users call: ``mx.mod.Module(...,
                             context=[mx.tpu(i)...]).fit`` over an NDArrayIter.
@@ -77,7 +77,7 @@ def sizes(dryrun):
     return dict(
         resnet=dict(num_classes=1000, num_layers=50),
         image=224, per_chip=32, classes=1000, dtype="bfloat16", lr=0.1,
-        # bench_all.py bench_transformer_lm: the LM at its full width
+        # a 59M LM at full width (the pre-chip rounds' transformer)
         lm=dict(vocab=32768, d_model=512, n_heads=8, n_layers=8, d_ff=2048,
                 n_experts=1),
         lm_batch=(8, 2048), lm_dtype="bfloat16",

@@ -1,15 +1,14 @@
 """Search-based autotuner (ISSUE 6; ROADMAP open item 2).
 
-Turns the repo's hand-picked performance constants — Pallas
-flash-attention block bounds, the serving bucket ladder, per-graph
-layout and remat policy — into one tuned, persisted, observable
-subsystem:
+Turns the repo's hand-picked performance constants — the fused
+kernel's block bounds, the serving bucket ladder, per-graph layout and
+remat policy — into one tuned, persisted, observable subsystem:
 
 * :mod:`.registry` — call sites declare their knob + search space
-  (``flash_attention.fwd``/``.bwd``, ``serving.buckets``,
-  ``graph.layout``, ``exec.remat``),
+  (``fusion.blocks``, ``serving.buckets``, ``graph.layout``,
+  ``exec.remat``),
 * :mod:`.cost_model` — analytic roofline estimates prune candidates
-  (measured ceilings from PERF_NOTES.md, VMEM feasibility),
+  (the chip's published peaks, VMEM feasibility),
 * :mod:`.search` — measured search decides (median-of-k, warmup
   discarded, incumbent default always in the running),
 * :mod:`.cache` — winners persist per device fingerprint in
@@ -21,27 +20,26 @@ measure; ``1`` additionally search on a miss at shape-local call sites
 (outside any jax trace); ``-1`` bypass lookups entirely (A/B baseline).
 Quick start: docs/autotune.md.
 """
-from . import cache, cost_model, learned, registry, search
+from . import cache, cost_model, registry, search
 from .cache import (cache_path, device_fingerprint, lookup, lookup_entry,
                     record, reload, reset, reset_stats, scrub_stale, stats)
 from .registry import declare, get as get_tunable, names as tunable_names
 from .search import SearchConfig, SearchResult, median_time, tune_and_record
 
-__all__ = ["cache", "registry", "cost_model", "learned", "search",
+__all__ = ["cache", "registry", "cost_model", "search",
            "cache_path", "device_fingerprint", "lookup", "lookup_entry",
            "lookup_or_tune", "record", "reload", "reset", "reset_stats",
            "scrub_stale", "stats", "declare", "get_tunable",
            "tunable_names", "SearchConfig", "SearchResult", "median_time",
            "tune_and_record", "mode", "enabled",
-           "tune_flash_attention", "tune_fused_matmul",
-           "tune_serving_buckets", "tune_layout",
+           "tune_fused_matmul", "tune_serving_buckets", "tune_layout",
            "tune_remat", "tune_generation", "tune_generation_kv",
            "tune_generation_spec", "tune_quantize_layers",
-           "tune_input_pipeline", "tune_control", "flash_shape_key"]
+           "tune_input_pipeline", "tune_control"]
 
 
 # the layout knob has no single in-package call site (models take
-# layout= at construction), so unlike the flash/serving/remat tunables
+# layout= at construction), so unlike the serving/remat tunables
 # it is declared here at package import — registry.get("graph.layout")
 # must work without the lazily-loaded tuners module; its generic
 # measured-choice tuner is tuners.tune_layout
@@ -49,8 +47,8 @@ declare(
     "graph.layout",
     space={"layout": ("NHWC", "NCHW")},
     default=lambda ctx: {"layout": str(ctx.get("default", "NHWC"))},
-    doc="Per-graph data layout: NHWC feeds the MXU lanes on TPU "
-        "(LAYOUT_AUDIT*.json); NCHW can win on other backends. Measured "
+    doc="Per-graph data layout: NHWC feeds the MXU lanes on TPU; "
+        "NCHW can win on other backends. Measured "
         "through a caller-supplied train/infer step (tune_layout).")
 
 
@@ -292,14 +290,13 @@ def __getattr__(name):
     # first use keeps `import mxnet_tpu` free of the heavy path.
     # (importlib, not `from . import`: the latter probes this very
     # __getattr__ through hasattr and recurses)
-    if name in ("tune_flash_attention", "tune_fused_matmul",
-                "tune_serving_buckets",
+    if name in ("tune_fused_matmul", "tune_serving_buckets",
                 "tune_layout", "tune_remat", "tune_generation",
                 "tune_generation_kv", "tune_generation_spec",
                 "tune_quantize_layers",
                 "tune_input_pipeline", "tune_control",
                 "control_replay_measurer", "pipeline_replay_measurer",
-                "generation_replay_measurer", "flash_shape_key", "tuners"):
+                "generation_replay_measurer", "tuners"):
         import importlib
 
         tuners = importlib.import_module(__name__ + ".tuners")

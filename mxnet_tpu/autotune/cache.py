@@ -4,7 +4,7 @@ artifact, its "tuning log").
 
 One JSON file maps ``(device fingerprint, op, shape-bucket, dtype)`` to the
 winning candidate of a measured search (autotune/search.py). Consumers
-(:func:`mxnet_tpu.parallel.flash_attention.flash_attention`, the executor's
+(:func:`mxnet_tpu.parallel.fused.resolve_blocks`, the executor's
 program build, ``serving.InferenceServer``) call :func:`lookup` at trace
 time: a hit costs one dict probe, a miss falls back to the hand-picked
 config.py defaults — searching only ever happens through the explicit

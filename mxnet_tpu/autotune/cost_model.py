@@ -1,23 +1,23 @@
 """Analytic roofline cost model — the cheap pruning half of the search
-(ISSUE 6; "A Learned Performance Model for TPUs" is the graduation path,
-this is the start-analytic rung ROADMAP item 2 names).
+(ISSUE 6).
 
 Estimates are in SECONDS and deliberately coarse: the model's only job is
 to rank candidates well enough that the measured search (search.py) never
-wastes a compile on a block pair that overflows VMEM or a ladder that
-pads 4x, not to predict absolute times. Ceilings are the repo's own
-measured numbers (PERF_NOTES.md round-5 calibration, the same basis as
-tools/flops_anchor.py), not spec-sheet values.
+wastes a compile on a block triple that overflows VMEM or a ladder that
+pads 4x, not to predict absolute times. The ceilings are the chip's
+published peaks (``context.DEVICE_PEAKS``), the same basis as ``mfu_pct``
+in PERF_LEDGER.jsonl.
 """
 from __future__ import annotations
 
 import math
 
-__all__ = ["MEASURED_MATMUL_TF", "MEASURED_HBM_GBPS", "SPEC_MATMUL_TF",
-           "VMEM_BYTES", "CEILINGS", "ridge_intensity",
-           "roofline_seconds", "flash_fwd_cost", "flash_bwd_cost",
-           "flash_vmem_bytes", "ladder_cost", "expected_padding",
-           "fused_vmem_bytes", "fused_matmul_cost", "pow2_at_least"]
+from ..context import DEVICE_PEAKS
+
+__all__ = ["PEAK_FLOPS_PER_S", "PEAK_HBM_BYTES_PER_S", "VMEM_BYTES",
+           "CEILINGS", "ridge_intensity", "roofline_seconds", "ladder_cost",
+           "expected_padding", "fused_vmem_bytes", "fused_matmul_cost",
+           "pow2_at_least"]
 
 
 def pow2_at_least(n):
@@ -27,141 +27,39 @@ def pow2_at_least(n):
         p <<= 1
     return p
 
-# measured ceilings (PERF_NOTES.md: 8192^3 matmul scan; bf16 stream,
-# round-5 recalibration) — THE one calibrated table every FLOP/ceiling
-# consumer cites (ISSUE 13): tools/flops_anchor.py, tools/
-# chip_calibration.py, observability/perf.py and bench_all.py's MFU
-# fields all import from here, so an MFU% printed anywhere in the tree
-# is always relative to the same basis.
-MEASURED_MATMUL_TF = 128.6
-MEASURED_HBM_GBPS = 634.0
-# spec-sheet bf16 matmul peak of the chip (v5-lite datasheet) — the
-# denominator of the *_spec MFU numbers (BENCH_ALL.json mfu_spec);
-# measured vs spec: achieved-of-attainable vs achieved-of-advertised
-SPEC_MATMUL_TF = 197.0
+
+# the published peaks of the chip the estimates are for: what
+# observability/perf.py's MFU and roofline gauges are shares of
+_PEAKS = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_PER_S = _PEAKS["bf16_flops_per_s"]
+PEAK_HBM_BYTES_PER_S = _PEAKS["hbm_bytes_per_s"]
 # per-core VMEM; Pallas tiles + double-buffered input windows must fit
 VMEM_BYTES = 16 * 2 ** 20
 
-#: the exported calibration table (single source of truth; see
-#: tools/chip_calibration.py for the microbench that re-measures it)
+#: the table as reports carry it (perf_program_cost()["ceilings"])
 CEILINGS = {
-    "matmul_tf_s": MEASURED_MATMUL_TF,
-    "hbm_gb_s": MEASURED_HBM_GBPS,
-    "spec_matmul_tf_s": SPEC_MATMUL_TF,
+    "matmul_tf_s": PEAK_FLOPS_PER_S / 1e12,
+    "hbm_gb_s": PEAK_HBM_BYTES_PER_S / 1e9,
     "vmem_bytes": VMEM_BYTES,
-    "source": "PERF_NOTES.md round-5 calibration "
-              "(tools/chip_calibration.py)",
+    "source": 'mxnet_tpu.context.DEVICE_PEAKS["TPU v5 lite"] (%s)'
+              % _PEAKS["source"],
 }
 
 
 def ridge_intensity():
-    """The roofline ridge point in FLOPs/byte at the measured ceilings:
+    """The roofline ridge point in FLOPs/byte at the published peaks:
     ops whose arithmetic intensity sits below it are bandwidth-bound."""
-    return (MEASURED_MATMUL_TF * 1e12) / (MEASURED_HBM_GBPS * 1e9)
+    return PEAK_FLOPS_PER_S / PEAK_HBM_BYTES_PER_S
+
+
 _VMEM_BUDGET = int(VMEM_BYTES * 0.75)  # headroom for Mosaic's own buffers
 # fixed cost per grid step (loop + DMA issue) — dominates tiny blocks
 _GRID_STEP_S = 2e-7
 
 
 def roofline_seconds(flops, hbm_bytes):
-    """max(compute, bandwidth) time at the measured ceilings."""
-    return max(flops / (MEASURED_MATMUL_TF * 1e12),
-               hbm_bytes / (MEASURED_HBM_GBPS * 1e9))
-
-
-def _dtype_bytes(ctx):
-    return int(ctx.get("dtype_bytes", 2))  # bf16 default
-
-
-def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
-                     Dv=None):
-    """Live VMEM of one grid step (input and output tiles double-buffered
-    by the pipeline, fp32 accumulators single-buffered). With ``T``, the
-    fused backward: the whole head's fp32 dq scratch (T, D) and its
-    resident (1, T, D) output block on top of the dk/dv pass's tiles.
-    ``D`` is the q/k width; ``Dv`` the v/o width where it differs."""
-    db = dtype_bytes
-    Dv = D if Dv is None else Dv
-    if not backward:
-        tiles = (bq * D * db            # q
-                 + bk * (D + Dv) * db   # k, v
-                 + bq * Dv * db)        # out
-        scratch = bq * Dv * 4 + 2 * bq * 4     # acc, m, l (fp32)
-    else:
-        # the dk/dv pass (the dq pass holds one accumulator fewer)
-        tiles = (bq * (D + Dv) * db         # q, do
-                 + 2 * bk * (D + Dv) * db   # k, v, dk, dv
-                 + 2 * bq * 4)              # lse, delta rows
-        scratch = bk * (D + Dv) * 4         # dk_acc, dv_acc
-        if T is not None:
-            tiles += T * D * db       # dq out
-            scratch += T * D * 4      # dq_acc
-    # score/probability intermediates, fp32: the backward's (bq, bk) s^T
-    # and dp^T; the forward works its tile through 256 rows at a time
-    inter = (bq if backward else min(bq, 256)) * bk * 4 * 2
-    return 2 * tiles + scratch + inter
-
-
-def _live_tiles(n_q, n_k, bq, bk, causal, grain=None):
-    """Score tiles a pass computes: all, or those a causal diagonal
-    leaves live (last query row of the tile >= its first key). Square
-    tiles ON the diagonal are worked through in ``grain``-wide sub-chunks
-    that stop at it: each counts as the stepped share it computes."""
-    if not causal:
-        return n_q * n_k
-    live = sum(min(n_k, ((i + 1) * bq - 1) // bk + 1) for i in range(n_q))
-    if grain and bq == bk:
-        n = max(1, bq // grain)
-        live -= n_q * (1 - (n + 1) / (2.0 * n))
-    return live
-
-
-def _flash_cost(ctx, bq, bk, backward):
-    from ..parallel.flash_attention import (_BWD_SUB_KEYS, _FWD_SUB_ROWS,
-                                            _VMEM_LIMIT, _bwd_is_fused)
-
-    T = int(ctx["T"])
-    D = int(ctx.get("D", 64))
-    BH = int(ctx.get("B", 1)) * int(ctx.get("H", 1))
-    causal = bool(ctx.get("causal", False))
-    db = _dtype_bytes(ctx)
-    bq = min(bq, T)
-    bk = min(bk, T)
-    # the kernels raise Mosaic's scoped-VMEM limit; keep its headroom
-    fused = backward and _bwd_is_fused(T, D, bq, bk, db)
-    if not fused and flash_vmem_bytes(
-            bq, bk, D, db, backward=backward) > 0.75 * _VMEM_LIMIT:
-        return math.inf
-    n_q, n_k = -(-T // bq), -(-T // bk)
-    passes = 2 if backward and not fused else 1
-    steps = passes * BH * n_q * n_k
-    # 2*bq*bk*D flops a matmul a live tile — forward: s, pv; fused
-    # backward: s, dp, dv, dk, dq; the two passes compute s and dp twice
-    matmuls = (5 if fused else 7) if backward else 2
-    flops = (2 * bq * bk * D * matmuls * BH
-             * _live_tiles(n_q, n_k, bq, bk, causal,
-                           _BWD_SUB_KEYS if backward else _FWD_SUB_ROWS))
-    # a live step moves the inner axis's two tiles (a dead one names its
-    # neighbour's block: no DMA); the outer axis's tiles and the outputs
-    # move once: 4 T x D tensors forward (q, k, v, o), 8 backward
-    traffic = BH * D * db * (
-        passes * _live_tiles(n_q, n_k, bq, bk, causal) * 2 * max(bq, bk)
-        + (8 if backward else 4) * T)
-    return roofline_seconds(flops, traffic) + steps * _GRID_STEP_S
-
-
-def flash_fwd_cost(candidate, ctx):
-    """Estimated seconds of one flash-attention forward at this block
-    pair; inf when the tiles overflow VMEM."""
-    return _flash_cost(ctx, int(candidate["block_q"]),
-                       int(candidate["block_k"]), backward=False)
-
-
-def flash_bwd_cost(candidate, ctx):
-    """Estimated seconds of the backward at this block pair: the fused
-    pass where the shape selects it, else the two tiled passes."""
-    return _flash_cost(ctx, int(candidate["block_q"]),
-                       int(candidate["block_k"]), backward=True)
+    """max(compute, bandwidth) time at the published peaks."""
+    return max(flops / PEAK_FLOPS_PER_S, hbm_bytes / PEAK_HBM_BYTES_PER_S)
 
 
 # --------------------------------------------------- fused matmul regions

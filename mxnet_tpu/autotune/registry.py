@@ -1,16 +1,17 @@
 """Tunable-parameter registry: call sites declare their knob and its
 search space, replacing the read-the-env-var-global pattern (ISSUE 6).
 
-A :class:`Tunable` names one knob family (``flash_attention.fwd``,
+A :class:`Tunable` names one knob family (``fusion.blocks``,
 ``serving.buckets``, ``graph.layout``, ``exec.remat``), its candidate
 space, the hand-picked default (so a cache miss costs nothing), and an
 optional analytic cost function used by the search driver to prune
 candidates before any on-device measurement (autotune/cost_model.py).
 
-Declarations live AT the call site — ``parallel/flash_attention.py``,
-``serving/buckets.py``, ``executor.py`` each register their own knob at
-import — so the tuner's view of the space and the consumer's view of the
-knob can never drift apart.
+Declarations live AT the call site — ``serving/buckets.py`` and
+``executor.py`` each register their own knob at import; knobs whose
+consumer loads lazily are declared in ``autotune/__init__.py`` — so the
+tuner's view of the space and the consumer's view of the knob can never
+drift apart.
 """
 from __future__ import annotations
 
@@ -28,7 +29,7 @@ class Tunable:
 
     ``space``: dict ``param -> sequence of candidate values``, or a
     callable ``ctx -> such a dict`` when the space depends on the shape
-    being tuned (e.g. flash blocks are bounded by T).
+    being tuned (e.g. fused-matmul blocks are bounded by M, N, K).
     ``default``: callable ``ctx -> value dict`` returning the hand-picked
     fallback (usually read from config.py flags).
     ``cost``: callable ``(candidate, ctx) -> estimated seconds`` (lower

@@ -1,17 +1,13 @@
-"""Measured search driver: analytic pruning, learned ranking, then real
-timings decide (ISSUE 6; ISSUE 15 graduates the ranking — the TVM
-schedule-search shape: cost model prunes, measurement picks, cache
-remembers, and the measurements train the next ranking).
+"""Measured search driver: analytic pruning, then real timings decide
+(ISSUE 6 — the TVM schedule-search shape: cost model prunes,
+measurement picks, cache remembers).
 
 The driver is a grid/refinement hybrid over a :class:`~.registry.Tunable`'s
 candidate space:
 
 1. the tunable's analytic cost function scores every candidate and drops
-   infeasible ones (``inf`` — e.g. VMEM overflow); the survivors are
-   ranked by the LEARNED cost model when its held-out accuracy gate
-   passed (autotune/learned.py — otherwise the analytic order stands,
-   never anything worse), and the cheapest candidates fill the
-   measurement budget (``MXNET_TUNE_TRIALS``),
+   infeasible ones (``inf`` — e.g. VMEM overflow); the cheapest
+   survivors fill the measurement budget (``MXNET_TUNE_TRIALS``),
 2. each surviving candidate is timed by the caller-supplied ``measure``
    callable (median of k runs, warmup discarded — :func:`median_time`),
 3. the remaining budget hill-climbs: one-notch neighbors of the current
@@ -22,11 +18,9 @@ The hand-picked default is ALWAYS measured first (budget permitting), so
 a tuned value can only beat or match it — the tuner never regresses a
 config below the incumbent except for measurement noise.
 
-Every measured candidate increments the cache's ``measurements`` counter
-AND (under ``MXNET_COST_MODEL=1``) lands in the sample dataset beside
-the tuning cache — every ``MXNET_TUNE=1`` run is free training data for
-the learned model; a warm cache hit never reaches this module at all
-(the zero-measurement acceptance bar).
+Every measured candidate increments the cache's ``measurements``
+counter; a warm cache hit never reaches this module at all (the
+zero-measurement acceptance bar).
 """
 from __future__ import annotations
 
@@ -54,21 +48,18 @@ class SearchConfig:
 
 
 class SearchResult:
-    __slots__ = ("best", "best_s", "measured", "pruned", "log", "ranker")
+    __slots__ = ("best", "best_s", "measured", "pruned", "log")
 
-    def __init__(self, best, best_s, measured, pruned, log,
-                 ranker="analytic"):
+    def __init__(self, best, best_s, measured, pruned, log):
         self.best = best          # winning candidate dict
         self.best_s = best_s      # its measured seconds
         self.measured = measured  # number of candidates actually timed
         self.pruned = pruned      # dropped by the cost model
         self.log = log            # [(candidate, seconds)] in measure order
-        self.ranker = ranker      # "learned" | "analytic" pre-measure order
 
     def as_dict(self):
         return {"best": self.best, "best_ms": round(self.best_s * 1e3, 4),
-                "measured": self.measured, "pruned": self.pruned,
-                "ranker": self.ranker}
+                "measured": self.measured, "pruned": self.pruned}
 
 
 def median_time(fn, repeats=3, warmup=1):
@@ -133,22 +124,6 @@ def search(tunable, measure, ctx=None, cfg=None):
     if not candidates:
         raise ValueError("tunable %r: every candidate pruned (space %r)"
                          % (tunable.name, space))
-    # learned re-ranking of the analytic survivors (ISSUE 15): consults
-    # the persisted model only when its holdout gate passed; any other
-    # state — cold, thin, gate-failed, load error — keeps the analytic
-    # order, so the ranking can never fall below the roofline's
-    ranker = "analytic"
-    try:
-        from . import learned
-
-        reranked = learned.rank_candidates(tunable.name, candidates, ctx,
-                                           cost_fn=tunable.cost)
-        if reranked is not None:
-            candidates = reranked
-            ranker = "learned"
-    except Exception:
-        pass
-
     # incumbent first: the tuned value may only beat or match it
     ordered = []
     default = tunable.default_value(ctx)
@@ -196,19 +171,7 @@ def search(tunable, measure, ctx=None, cfg=None):
             _measure(n)
 
     best_c, best_s = _best()
-    # every measured candidate is free training data for the learned
-    # model (docs/autotune.md); recording and auto-retraining happen
-    # OUTSIDE any trace (we just ran real measurements) and are never
-    # allowed to fail a search
-    try:
-        from . import learned
-
-        learned.note_samples(tunable.name, ctx, log, cost_fn=tunable.cost)
-        learned.maybe_train()
-    except Exception:
-        pass
-    return SearchResult(best_c, best_s, len(log), pruned, log,
-                        ranker=ranker)
+    return SearchResult(best_c, best_s, len(log), pruned, log)
 
 
 def tune_and_record(op, key, measure, ctx=None, dtype=None, cfg=None):

@@ -4,20 +4,20 @@ drive the search (ISSUE 6).
 Each ``tune_*`` function is the explicit "tune once, ship the cache"
 entry point for one knob family:
 
-* :func:`tune_flash_attention` — sweeps the Pallas forward/backward
-  block bounds by timing the actual kernels at the given shape (the
-  per-call block overrides in ``flash_attention`` mean no env mutation),
+* :func:`tune_fused_matmul` — sweeps the fused matmul+epilogue kernel's
+  block bounds by timing the actual kernel at the given shape (per-call
+  block overrides, no env mutation),
 * :func:`tune_serving_buckets` — replays a traffic sample of request
   sizes against a live :class:`~mxnet_tpu.serving.InferenceServer` per
   candidate ladder,
 * :func:`tune_layout` / :func:`tune_remat` — generic measured choices
-  over a caller-supplied step measurer (bench_all.py --autotune supplies
-  the ResNet train step).
+  over a caller-supplied step measurer.
 
 :func:`auto_tune` is the ``MXNET_TUNE=1`` miss hook: shape-local knobs
-(flash blocks) can be tuned on the spot from their call-site context;
-workload-dependent knobs (bucket ladders, layout, remat) need a traffic
-sample or a train step and only tune through their explicit entry point.
+(the fused kernel's blocks) can be tuned on the spot from their
+call-site context; workload-dependent knobs (bucket ladders, layout,
+remat) need a traffic sample or a train step and only tune through
+their explicit entry point.
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ from ..base import MXNetError
 from . import cache, registry
 from .search import SearchConfig, median_time, search
 
-__all__ = ["flash_shape_key", "tune_flash_attention", "tune_fused_matmul",
+__all__ = ["tune_fused_matmul",
            "serving_replay_measurer", "tune_serving_buckets",
            "tune_layout", "tune_remat", "tune_generation",
            "tune_generation_kv", "tune_generation_spec",
@@ -36,103 +36,16 @@ __all__ = ["flash_shape_key", "tune_flash_attention", "tune_fused_matmul",
            "pipeline_replay_measurer", "tune_input_pipeline", "auto_tune"]
 
 
-from .cost_model import pow2_at_least as _pow2_at_least
-
-
-def flash_shape_key(T, D, causal, Dv=None):
-    """Shape-bucket key for flash-attention entries: T rounds up to a
-    power of two (one tuning per T-bucket, not per exact length). ``Dv``
-    is the v/o width where it differs from the q/k width ``D``."""
-    width = "D%d" % int(D)
-    if Dv is not None and int(Dv) != int(D):
-        width += "v%d" % int(Dv)
-    return ("T%d" % _pow2_at_least(int(T)), width,
-            "causal" if causal else "full")
-
-
-def tune_flash_attention(T, D=64, B=1, H=4, dtype="bfloat16", causal=True,
-                         forward=True, backward=True, interpret=None,
-                         trials=None, repeats=3, fwd_blocks=None):
-    """Measured search over the Pallas flash-attention block bounds at
-    one (T, D) shape; records ``flash_attention.fwd`` (and ``.bwd``)
-    cache entries under the shape-bucket key. Returns
-    ``{op: winning value dict}``.
-
-    ``forward=False`` skips the forward sweep (and leaves any existing
-    fwd cache entry untouched); the backward measurer then runs on
-    ``fwd_blocks`` (or the config-flag defaults) — the bwd-only path
-    :func:`auto_tune` uses when only the bwd entry is missing.
-    ``interpret=None`` auto-detects: Pallas interpret mode off-TPU (the
-    numbers are then only meaningful relative to each other on the same
-    host — real block tuning belongs on the chip).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..config import get_flag
-    from ..parallel.flash_attention import flash_attention
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    dt = jnp.dtype(dtype)
-    rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(B, H, T, D), dt) for _ in range(3))
-    key = flash_shape_key(T, D, causal)
-    ctx = {"T": T, "D": D, "B": B, "H": H, "causal": causal,
-           "dtype_bytes": dt.itemsize}
-    cfg = SearchConfig(trials=trials, repeats=repeats, warmup=1)
-    out = {}
-
-    if forward:
-        def fwd_measure(c):
-            fn = jax.jit(lambda q, k, v: flash_attention(  # graftlint: disable=G002 — one fresh program per measured candidate is the point of the sweep
-                q, k, v, causal=causal, block_q=int(c["block_q"]),
-                block_k=int(c["block_k"]), interpret=interpret))
-            return median_time(lambda: jax.block_until_ready(fn(q, k, v)),
-                               repeats=cfg.repeats, warmup=cfg.warmup)
-
-        res_f = search(registry.get("flash_attention.fwd"), fwd_measure,
-                       ctx=ctx, cfg=cfg)
-        cache.record("flash_attention.fwd", key, res_f.best, dtype=str(dt),
-                     ms=res_f.best_s * 1e3, trials=res_f.measured)
-        out["flash_attention.fwd"] = res_f.best
-        fwd_blocks = (int(res_f.best["block_q"]),
-                      int(res_f.best["block_k"]))
-    elif fwd_blocks is None:
-        fwd_blocks = (get_flag("MXNET_FLASH_BLOCK_Q"),
-                      get_flag("MXNET_FLASH_BLOCK_K"))
-
-    if backward:
-        fq, fk = int(fwd_blocks[0]), int(fwd_blocks[1])
-
-        def bwd_measure(c):
-            def loss(q, k, v):
-                return jnp.sum(flash_attention(
-                    q, k, v, causal=causal, block_q=fq, block_k=fk,
-                    block_q_bwd=int(c["block_q"]),
-                    block_k_bwd=int(c["block_k"]),
-                    interpret=interpret).astype(jnp.float32))
-
-            fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))  # graftlint: disable=G002 — one fresh program per measured candidate is the point of the sweep
-            return median_time(lambda: jax.block_until_ready(fn(q, k, v)),
-                               repeats=cfg.repeats, warmup=cfg.warmup)
-
-        res_b = search(registry.get("flash_attention.bwd"), bwd_measure,
-                       ctx=ctx, cfg=cfg)
-        cache.record("flash_attention.bwd", key, res_b.best, dtype=str(dt),
-                     ms=res_b.best_s * 1e3, trials=res_b.measured)
-        out["flash_attention.bwd"] = res_b.best
-    return out
-
-
 def tune_fused_matmul(M, N, K, dtype="float32", epilogue=("bias",
                                                           ("act", "relu")),
                       wt=True, interpret=None, trials=None, repeats=3):
     """Measured search over the fused matmul+epilogue kernel's block
     bounds at one (M, N, K) shape (parallel/fused.py); records a
     ``fusion.blocks`` entry under the pow2 shape-bucket key and returns
-    the winning value dict.  ``interpret=None`` auto-detects (interpret
-    mode off-TPU, the flash-attention tuner convention).
+    the winning value dict.  ``interpret=None`` auto-detects: Pallas
+    interpret mode off-TPU (the numbers are then only meaningful
+    relative to each other on the same host — real block tuning belongs
+    on the chip).
 
     The default epilogue — bias + relu — is the modal carved region;
     block choice is dominated by the matmul tiling, not the epilogue
@@ -176,8 +89,7 @@ def tune_fused_matmul(M, N, K, dtype="float32", epilogue=("bias",
 
     res = search(registry.get("fusion.blocks"), measure, ctx=ctx, cfg=cfg)
     cache.record("fusion.blocks", key, res.best, dtype=str(dt),
-                 ms=res.best_s * 1e3, trials=res.measured,
-                 extra={"ranker": res.ranker})
+                 ms=res.best_s * 1e3, trials=res.measured)
     return res.best
 
 
@@ -194,9 +106,8 @@ def serving_replay_measurer(symbol, arg_params, data_shapes, sizes,
                             repeats=3, warmup=1):
     """``measure(candidate)`` for bucket-ladder candidates: build a live
     InferenceServer with the candidate ladder, warm every bucket, replay
-    the traffic sample, return median wall seconds. ONE protocol shared
-    by :func:`tune_serving_buckets` and ``bench_all.py --autotune`` —
-    the search and the bench comparison can never drift apart."""
+    the traffic sample, return median wall seconds (the protocol of
+    :func:`tune_serving_buckets`)."""
     from ..serving import InferenceServer, ServingConfig
 
     row_shapes = [tuple(d[1][1:]) for d in data_shapes]
@@ -272,9 +183,8 @@ def generation_replay_measurer(model, params, prompts, max_new=8,
     """``measure(candidate)`` for generation knobs: build a live
     continuous-batching :class:`~mxnet_tpu.serving.generation.Generator`
     with the candidate knob (merged over ``fixed``), warm every program,
-    replay the prompt sample end to end, return median wall seconds.
-    Shared by :func:`tune_generation` and ``bench_all.py`` so the search
-    and any benchmark comparison measure the same protocol."""
+    replay the prompt sample end to end, return median wall seconds
+    (the protocol of :func:`tune_generation`)."""
     from ..serving.generation import (GenerationConfig, Generator,
                                       SamplingParams)
 
@@ -405,8 +315,7 @@ def control_replay_measurer(model, params, prompts=None, shared_prefix=32,
     candidate knob (merged over ``fixed``), replay a shared-prefix
     prompt sample TWICE — the first pass seeds the radix tree on
     eviction, the second serves from it — and return median wall
-    seconds. Shared by :func:`tune_control` and ``bench_all.py
-    --control`` so search and benchmark measure the same protocol."""
+    seconds (the protocol of :func:`tune_control`)."""
     from ..serving.generation import (GenerationConfig, Generator,
                                       SamplingParams)
 
@@ -727,8 +636,7 @@ def _ambient_passes_plus_quantize():
 
 def tune_layout(measure, key, default="NHWC", trials=None):
     """Measured NHWC-vs-NCHW choice: ``measure({"layout": L}) ->
-    seconds`` (the caller owns the model/step — bench_all.py --autotune
-    supplies a ResNet train step). Records ``graph.layout`` under
+    seconds`` (the caller owns the model/step). Records ``graph.layout`` under
     ``key`` and returns the winning layout string."""
     cfg = SearchConfig(trials=trials or 2, repeats=3, warmup=1)
     res = search(registry.get("graph.layout"), measure,
@@ -828,47 +736,12 @@ def auto_tune(op, key, ctx):
     """MXNET_TUNE=1 cache-miss hook (called via ``lookup_or_tune`` from
     consulting call sites, never inside a jax trace). Only shape-local
     knobs can tune from call-site context; returns the freshly recorded
-    value, or None when the op needs an explicit workload.
-
-    Only the MISSING entries are searched: an existing (possibly
-    shipped, on-chip-measured) fwd or bwd entry is reused as-is, never
-    re-measured or overwritten by an opportunistic local sweep."""
-    if op == "fusion.blocks":
-        # shape-local like flash blocks: the region's (M, N, K) rides
-        # in the consult context (parallel/fused.py resolve_blocks)
-        if not all(k in ctx for k in ("M", "N", "K")):
-            return None
-        db = int(ctx.get("dtype_bytes", 4))
-        dtype = {2: "bfloat16", 4: "float32"}.get(db, "float32")
-        return tune_fused_matmul(int(ctx["M"]), int(ctx["N"]),
-                                 int(ctx["K"]), dtype=dtype)
-    if op not in ("flash_attention.fwd", "flash_attention.bwd"):
+    value, or None when the op needs an explicit workload."""
+    # shape-local: the region's (M, N, K) rides in the consult context
+    # (parallel/fused.py resolve_blocks)
+    if op != "fusion.blocks" or not all(k in ctx for k in ("M", "N", "K")):
         return None
-    dtype = ctx.get("dtype", "bfloat16")
-    fwd_cached = cache.lookup("flash_attention.fwd", key, dtype=dtype)
-    bwd_cached = cache.lookup("flash_attention.bwd", key, dtype=dtype)
-    need_fwd = fwd_cached is None
-    need_bwd = bwd_cached is None
-    fwd_blocks = None
-    if not need_fwd:
-        try:
-            fwd_blocks = (int(fwd_cached["block_q"]),
-                          int(fwd_cached["block_k"]))
-        except (TypeError, KeyError, ValueError):
-            fwd_blocks = None  # corrupt entry: bwd measures on defaults
-    if not (need_fwd or need_bwd):
-        # both present — the "miss" was for another dtype/shape variant
-        # of the same bucket resolved concurrently; nothing to do
-        return {"flash_attention.fwd": fwd_cached,
-                "flash_attention.bwd": bwd_cached}.get(op)
-    # cap the batch*heads grid the sweep pays for: block choice is
-    # per-(T, D); the grid axis is embarrassingly parallel
-    bh = max(1, min(int(ctx.get("B", 1)) * int(ctx.get("H", 1)), 8))
-    out = tune_flash_attention(
-        T=int(ctx["T"]), D=int(ctx.get("D", 64)), B=1, H=bh,
-        dtype=dtype, causal=bool(ctx.get("causal", False)),
-        forward=need_fwd, backward=need_bwd, fwd_blocks=fwd_blocks,
-        interpret=ctx.get("interpret"))
-    out.setdefault("flash_attention.fwd", fwd_cached)
-    out.setdefault("flash_attention.bwd", bwd_cached)
-    return out.get(op)
+    db = int(ctx.get("dtype_bytes", 4))
+    dtype = {2: "bfloat16", 4: "float32"}.get(db, "float32")
+    return tune_fused_matmul(int(ctx["M"]), int(ctx["N"]), int(ctx["K"]),
+                             dtype=dtype)

@@ -40,31 +40,6 @@ Flags currently honored:
     ``config.set_flag("MXNET_DEBUG_NANS", 1)`` at runtime. Combine with
     MXNET_EXEC_DISABLE_JIT=1 to localize to a single eager op.
 
-``MXNET_FLASH_ATTENTION_BWD`` (default 1)
-    Run the flash-attention backward as the tiled recompute Pallas
-    kernels (parallel/flash_attention.py): the forward saves only
-    (q, k, v, o, lse) and the backward recomputes block scores — in one
-    fused pass where the head's dq fits VMEM, else in two — so training
-    is O(T) in attention memory. 0 restores the pre-kernel behavior —
-    XLA autodiff of the dense formula, which materializes the T x T
-    score matrix in the backward.
-
-``MXNET_FLASH_BLOCK_Q`` / ``MXNET_FLASH_BLOCK_K`` (default 2048)
-    Upper bounds for the forward kernel's q/k block sizes. The tile
-    that runs is the whole sequence when it fits under the bound, else
-    the largest divisor of T that is a multiple of 128 (any divisor in
-    the Pallas interpreter); a T with no such tile lowers the dense
-    formula (a static decline, docs/flash_attention.md). The kernel
-    works a tile through in 256-row sub-chunks that stop at the causal
-    diagonal, so a large tile wastes no more than a small one and pays
-    fewer grid steps. Defaults from the v5e sweep of PR 27 at T = 1024
-    to 8192, D = 128 (PERF.md section 6).
-
-``MXNET_FLASH_BWD_BLOCK_Q`` / ``MXNET_FLASH_BWD_BLOCK_K`` (default 1024)
-    Same bounds for the backward kernels (the same sweep: 1024/1024
-    wins at every T from 1024 to 8192; tiles on the diagonal are worked
-    through in 128-key sub-chunks).
-
 ``MXNET_RING_ATTENTION_FLASH`` (default 1)
     Per-ring-step local attention in ring_attention: 1 = use the Pallas
     flash kernel for each K/V block when running on TPU (dense XLA
@@ -228,12 +203,11 @@ Flags currently honored:
     folding of frozen-parameter subgraphs; ``all`` additionally enables
     the opt-in bf16 ``amp`` rewrite (fp32 islands for
     softmax/norm/loss); ``off`` disables the layer; ``-<pass>`` drops
-    one pass (``-fuse`` is the unfused A/B arm bench_all.py --fusion
-    measures); ``layout=NHWC`` forces the layout target. Grammar in
-    docs/graph_passes.md. String-valued and read by graph_pass straight
-    from the environment (runtime override: ``graph_pass.set_passes``)
-    — like MXNET_HEALTH, NOT routed through the integer get_flag
-    machinery.
+    one pass (``-fuse`` is the unfused A/B arm); ``layout=NHWC`` forces
+    the layout target. Grammar in docs/graph_passes.md. String-valued
+    and read by graph_pass straight from the environment (runtime
+    override: ``graph_pass.set_passes``) — like MXNET_HEALTH, NOT
+    routed through the integer get_flag machinery.
 
 ``MXNET_FUSION_BLOCK_M`` / ``MXNET_FUSION_BLOCK_N`` /
 ``MXNET_FUSION_BLOCK_K`` (defaults 128 / 128 / 512)
@@ -262,36 +236,14 @@ Flags currently honored:
     bytes`` candidate formula) for a region to be carved; smaller
     matches are reported as rejected with ``below_min_bytes``.
 
-``MXNET_COST_MODEL`` (default 1)
-    Learned cost model for the autotuner's candidate ranking
-    (autotune/learned.py, docs/autotune.md): 1 = record every measured
-    search sample beside the tuning cache, train the feature-hashed
-    regressor, and let it re-rank candidates when its held-out Spearman
-    beats the analytic roofline's (it degrades to the analytic ranking
-    otherwise — never below it); 0 = analytic ranking only, no sample
-    recording.
-
-``MXNET_COST_MODEL_MIN_SAMPLES`` (default 48)
-    Measured samples required before the first training run; below it
-    the ranking stays analytic.
-
-``MXNET_COST_MODEL_RETRAIN`` (default 32)
-    New samples accumulated since the last training run that trigger an
-    automatic retrain (at search time, outside any trace).
-
-``MXNET_COST_MODEL_PATH`` (default ``<tuning cache>.model.json``)
-    Persisted model file (weights + holdout-gate metadata), loaded by a
-    warm process with zero re-training. String-valued, env-only.
-
 ``MXNET_TUNE`` (default 0)
     Autotuner mode (autotune/, docs/autotune.md): ``0`` consults the
-    persistent tuning cache at the wired call sites (flash-attention
+    persistent tuning cache at the wired call sites (fused-kernel
     block bounds, serving bucket ladder, executor remat) — a hit is one
     dict probe, a miss falls back to the defaults below, and no
     measurement ever runs; ``1`` additionally runs the measured search
     on a miss at shape-local call sites (outside any jax trace);
-    ``-1`` bypasses cache lookups entirely (the A/B baseline the
-    ``bench_all.py --autotune`` overhead gate uses).
+    ``-1`` bypasses cache lookups entirely (an A/B baseline).
 
 ``MXNET_TUNE_TRIALS`` (default 12)
     Measurement budget per search: total candidates timed (median-of-k
@@ -307,8 +259,8 @@ Flags currently honored:
     Deterministic fault-injection spec for the resilience layer
     (resilience/faults.py; grammar in docs/resilience.md), e.g.
     ``kvstore.push:drop@p=0.01;serving.replica_execute:raise@call=7``.
-    Unset, every declared injection point is a few-nanosecond no-op
-    (gated by ``bench_all.py --resilience-overhead``). String-valued,
+    Unset, every declared injection point is a few-nanosecond no-op.
+    String-valued,
     env-only (``resilience.faults.configure`` overrides at runtime).
 
 ``MXNET_RETRY_MAX`` (default 3)
@@ -409,8 +361,7 @@ Flags currently honored:
     ``RequestTrace`` from submit to completion with exact
     queue/batch/compute/fetch (serving) or queue/prefill/decode
     (generation) latency attribution. 0 = tracing off (shared no-op
-    trace, gated < 1%/request by ``bench_all.py --obs-overhead``),
-    1 = every request, N = 1-in-N.
+    trace), 1 = every request, N = 1-in-N.
 
 ``MXNET_OBS_RESERVOIR`` (default 32)
     Capacity of the request-trace tail reservoir: the slowest-K
@@ -436,8 +387,7 @@ Flags currently honored:
     quantiles) and the ``timeseries`` flight-recorder provider. Started
     with the exposition plane (or ``timeseries.start_sampler()``).
     0 = no sampler (and /varz explains why). Per-sample cost is one
-    locked registry walk, gated < 1% duty cycle by ``bench_all.py
-    --ts-overhead``.
+    locked registry walk.
 
 ``MXNET_OBS_TS_RETAIN`` (default 600)
     Ring depth of the time-series sampler, in samples per instrument —
@@ -523,9 +473,8 @@ Flags currently honored:
 
 ``MXNET_MESH_PROCS`` (default 2)
     Process count of the CPU fake cluster spawned by
-    ``tools/mesh_smoke.py`` and ``bench_all.py --dist-train`` (real
-    deployments size the cluster via the launcher / jax.distributed,
-    not this flag).
+    ``tools/mesh_smoke.py`` (real deployments size the cluster via the
+    launcher / jax.distributed, not this flag).
 
 ``MXNET_PERF`` (default 1)
     Roofline attribution layer (observability/perf.py): analytic
@@ -534,8 +483,8 @@ Flags currently honored:
     fit-loop step-time waterfall (data-wait / host dispatch / device
     compute / kvstore segments that sum to the step wall exactly).
     Cost walks run once per (program, shape signature); steady-state
-    steps pay dict probes only (gated < 1%/step by ``bench_all.py
-    --perf-overhead``). 0 = the whole layer off.
+    steps pay dict probes only. 0 = the whole layer off
+    (``tests/test_perf.py::test_perf_disabled_is_inert``).
 
 ``MXNET_PERF_RING`` (default 64)
     Capacity of the per-step waterfall ring surfaced by the flight
@@ -572,11 +521,6 @@ _DEFAULTS = {
     # tied maxima; see ops/nn.py _maxpool_mask_bwd)
     "MXNET_POOLING_MASK_BWD": 0,
     "MXNET_DEBUG_NANS": 0,
-    "MXNET_FLASH_ATTENTION_BWD": 1,
-    "MXNET_FLASH_BLOCK_Q": 2048,
-    "MXNET_FLASH_BLOCK_K": 2048,
-    "MXNET_FLASH_BWD_BLOCK_Q": 1024,
-    "MXNET_FLASH_BWD_BLOCK_K": 1024,
     "MXNET_RING_ATTENTION_FLASH": 1,
     "MXNET_TELEMETRY": 0,
     "MXNET_TELEMETRY_MEMSTATS": 1,
@@ -593,9 +537,6 @@ _DEFAULTS = {
     "MXNET_FUSION_KERNEL": 1,
     "MXNET_FUSION_INTERPRET": 0,
     "MXNET_FUSION_MIN_BYTES": 0,
-    "MXNET_COST_MODEL": 1,
-    "MXNET_COST_MODEL_MIN_SAMPLES": 48,
-    "MXNET_COST_MODEL_RETRAIN": 32,
     "MXNET_GEN_PAGE_SIZE": 16,
     "MXNET_GEN_DECODE_BLOCKS": 128,
     "MXNET_GEN_MAX_BATCH": 8,
@@ -706,8 +647,8 @@ def flag_doc():
 
 def enable_compile_cache():
     """Turn on JAX's persistent compilation cache for an entry point
-    (``chip_smoke.py``, ``bench.py``, ``bench_all.py`` call this before
-    their first jit) and return the directory in use.
+    (``chip_smoke.py``, ``perfbench/run.py`` call this before their
+    first jit) and return the directory in use.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
     nothing is set in code — the cache can be placed from outside. Else

@@ -21,8 +21,9 @@ __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
            "num_gpus", "DEVICE_PEAKS", "device_peaks"]
 
 #: Published per-chip peaks, keyed by jax ``device_kind`` — the ONE table
-#: utilization figures divide by (bench.py, chip_smoke.py). A device that
-#: is not here is an error, never a default.
+#: utilization figures divide by (chip_smoke.py, autotune/cost_model.py,
+#: observability/perf.py). A device that is not here is an error, never a
+#: default.
 DEVICE_PEAKS = {
     "TPU v5 lite": {
         "bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,

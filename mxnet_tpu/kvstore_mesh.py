@@ -437,7 +437,7 @@ class KVStoreMesh(KVStore):
     # ------------------------------------------------------------- misc
     def optimizer_state_bytes(self):
         """Host-visible bytes of THIS rank's optimizer state — the
-        ZeRO-1 ~1/N-per-chip witness (bench_all.py --dist-train)."""
+        ZeRO-1 ~1/N-per-chip witness (tools/mesh_smoke.py)."""
         def walk(v):
             data = getattr(v, "_data", None)
             if data is not None:

@@ -25,7 +25,7 @@ Three pillars, one import:
   reservoir, and chrome-trace export (``tools/trace_report.py
   --requests``).
 * :mod:`.perf` — roofline attribution (ISSUE 13): analytic FLOPs/HBM
-  bytes per compiled program on the autotuner's measured-ceiling basis,
+  bytes per compiled program against the chip's published peaks,
   achieved-vs-roofline MFU / HBM-utilization gauges, the fit-loop
   step-time waterfall (data-wait / host / device / kvstore, summing to
   the step wall exactly), and the ``BENCH_LEDGER.jsonl`` perf-ledger

@@ -27,8 +27,8 @@ One call per optimization step — :func:`guard_step` — does all of:
     LAG: the (n, 3) result is a device future stashed at step N and
     read at step N+1 — by then it has long completed, so the loop's
     async dispatch pipeline never drains (a synchronous per-step fetch
-    costs far more in lost overlap than the reduction itself; measured
-    by ``bench_all.py --health-overhead``). Attribution stays exact —
+    costs far more in lost overlap than the reduction itself).
+    Attribution stays exact —
     the stash carries its own step/tensor metadata, so the dump and
     triage report name the step the NaN occurred, one step after it ran.
     Pending stats are flushed at fit end, on any dump, and at exit.

@@ -1,15 +1,13 @@
-"""Roofline attribution: where do the other 72–83% of each step go?
-
-ROADMAP item 3 states the gap — resnet50 trains at ~17% MFU, the 59M
-transformer at 28% — but until now nothing in the tree could say *which*
-part of a step is slow or *which* regions are compute- vs bandwidth-
-bound.  This module is that attribution layer (ISSUE 13), four pieces:
+"""Roofline attribution: which part of a step is slow, and which
+regions are compute- vs bandwidth-bound (ISSUE 13).  Four pieces:
 
 * **Analytic cost accounting per compiled program** — walk the bound
   graph once per (program, shape signature) and compute FLOPs + HBM
   bytes per node (conv / FC / matmul / attention / elemwise rules) and
-  per program, on the SAME measured-ceiling basis as the autotuner
-  (``autotune.cost_model.CEILINGS`` + ``roofline_seconds``).  Cached on
+  per program, against the chip's published peaks — the autotuner's
+  basis (``autotune.cost_model.CEILINGS`` + ``roofline_seconds``, read
+  from ``context.DEVICE_PEAKS``) and what ``mfu_pct`` in
+  PERF_LEDGER.jsonl means.  Cached on
   the ``_GraphProgram`` alongside its ``tuning_key``.
 * **Achieved-vs-roofline attribution** — the executor's fenced
   host/device split (the PR 2 discipline) feeds measured device time
@@ -26,17 +24,17 @@ bound.  This module is that attribution layer (ISSUE 13), four pieces:
   ring surfaced by the flight-recorder ``perf`` provider, ``/statusz``,
   ``get_stats()`` and ``tools/perf_report.py``.
 * **Perf ledger** — append-only ``BENCH_LEDGER.jsonl`` rows (one per
-  ``bench_all.py`` run: env/device fingerprint, per-bench throughput +
-  MFU, predicted-vs-measured residual per program) with a regression
-  verdict computed over the CPU-stable quantities.  The residual
-  dataset is the on-ramp to the learned cost model ("A Learned
-  Performance Model for TPUs", PAPERS.md).
+  benchmark run: env/device fingerprint, per-bench throughput + MFU,
+  predicted-vs-measured residual per program) with a regression
+  verdict computed over the CPU-stable quantities.  Nothing in the
+  tree writes such rows since PR 30 (ROADMAP Design): the driver's
+  PERF_LEDGER.jsonl is the record.
 
 Everything here is host-side arithmetic: the only device interaction is
 the ``block_until_ready`` fence the executor already performs for the
 profiler, now shared.  Cost walks run once per (program, shape) —
-steady-state steps do dict probes only (gated <1%/step by ``bench_all.py
---perf-overhead``).  ``MXNET_PERF=0`` turns the whole layer off.
+steady-state steps do dict probes only.  ``MXNET_PERF=0`` turns the
+whole layer off.
 """
 from __future__ import annotations
 
@@ -107,7 +105,7 @@ def _ceilings():
 #: walk: the backward re-runs ~2 matmuls per layer (dgrad + wgrad), so
 #: FLOPs triple; activations are re-read and gradients written, so
 #: traffic is modeled with the same integer multiplier (coarse on
-#: purpose — the measured residual is what the learned model trains on)
+#: purpose — ``residual`` reports measured / predicted)
 TRAIN_FLOPS_MULT = 3
 TRAIN_BYTES_MULT = 3
 
@@ -249,10 +247,10 @@ def flash_attention_cost(B, H, T, D, causal=True, dtype_bytes=2,
     attention regions that live below the symbol layer (Pallas kernels
     in parallel/flash_attention.py).  FLOPs: ``4*B*H*T*T*D`` (qk^T + pv,
     2 FLOPs per MAC each), halved under causal masking (dead-block
-    skip); the tiled backward recomputes ≈2.5x that (same factor as
-    ``cost_model.flash_bwd_cost``).  Bytes: the streaming traffic —
-    q, k, v read + o written once (``4*B*H*T*D``), doubled for the
-    backward's second pass over the tiles."""
+    skip); the fused backward's five matmuls a tile are 2.5x that.
+    Bytes: the streaming traffic — q, k, v read + o written once
+    (``4*B*H*T*D``), doubled for the backward's second pass over the
+    tiles."""
     flops = 4 * B * H * T * T * D
     if causal:
         flops //= 2
@@ -266,7 +264,7 @@ def flash_attention_cost(B, H, T, D, causal=True, dtype_bytes=2,
 def program_cost(symbol, topo, var_shapes, dtype_bytes=4, train=False,
                  graph="program"):
     """Walk a bound graph once: per-node FLOPs/bytes rows + program
-    totals + roofline seconds at the measured ceilings.
+    totals + roofline seconds at the published peaks.
 
     ``var_shapes`` maps every variable (args + aux) to its bound shape;
     internal shapes come from partial shape inference.  ``train=True``
@@ -436,10 +434,9 @@ def note_program_run(cost, device_s, host_s, replicas=1):
     key = (cost["graph"], cost["mode"])
     mfu = hbm = None
     if device_s > 0:
-        mfu = 100.0 * (cost["flops"] / device_s) / (cm.MEASURED_MATMUL_TF
-                                                    * 1e12)
-        hbm = 100.0 * (cost["hbm_bytes"] / device_s) / (cm.MEASURED_HBM_GBPS
-                                                        * 1e9)
+        mfu = 100.0 * (cost["flops"] / device_s) / cm.PEAK_FLOPS_PER_S
+        hbm = (100.0 * (cost["hbm_bytes"] / device_s)
+               / cm.PEAK_HBM_BYTES_PER_S)
     warmup = False
     with _lock:
         entry = _programs.get(key)
@@ -482,8 +479,8 @@ def note_program_run(cost, device_s, host_s, replicas=1):
                 entry["mfu_pct"] = mfu
                 entry["hbm_util_pct"] = hbm
             if entry["roofline_ms"] > 0:
-                # measured / predicted — the learned-cost-model training
-                # signal (>1 = slower than roofline, i.e. the MFU gap)
+                # measured / predicted (>1 = slower than roofline,
+                # i.e. the MFU gap)
                 entry["residual"] = (entry["device_ms_ema"]
                                      / entry["roofline_ms"])
     if mfu is not None and not warmup and metrics.enabled():
@@ -491,11 +488,11 @@ def note_program_run(cost, device_s, host_s, replicas=1):
         # device wait is trace+compile-distorted, exactly the number the
         # registry's warmup exclusion suppresses
         metrics.gauge("perf.mfu_pct", labels={"scope": "program"},
-                      help="achieved FLOP/s as % of the measured matmul "
-                           "ceiling (autotune.cost_model.CEILINGS)").set(mfu)
+                      help="achieved FLOP/s as % of the published bf16 "
+                           "peak (context.DEVICE_PEAKS)").set(mfu)
         metrics.gauge("perf.hbm_util_pct", labels={"scope": "program"},
-                      help="achieved HBM traffic as % of the measured "
-                           "bandwidth ceiling").set(hbm)
+                      help="achieved HBM traffic as % of the published "
+                           "bandwidth").set(hbm)
     scope = getattr(_tls, "step", None)
     if scope is not None:
         scope["device_s"] += device_s
@@ -616,10 +613,9 @@ def step_end(step=None):
         # step MFU charges the WHOLE step wall (the honest training
         # number: data stalls and host dispatch count against you)
         "mfu_pct": (100.0 * (scope["flops"] / wall)
-                    / (cm.MEASURED_MATMUL_TF * 1e12)) if wall > 0 else None,
+                    / cm.PEAK_FLOPS_PER_S) if wall > 0 else None,
         "hbm_util_pct": (100.0 * (scope["hbm_bytes"] / wall)
-                         / (cm.MEASURED_HBM_GBPS * 1e9)) if wall > 0
-                        else None,
+                         / cm.PEAK_HBM_BYTES_PER_S) if wall > 0 else None,
     }
     if scope.get("collective"):
         rec["collective"] = True
@@ -630,12 +626,12 @@ def step_end(step=None):
         _waterfalls.append(rec)
     if metrics.enabled() and rec["mfu_pct"] is not None:
         metrics.gauge("perf.mfu_pct", labels={"scope": "step"},
-                      help="achieved FLOP/s as % of the measured matmul "
-                           "ceiling (autotune.cost_model.CEILINGS)"
+                      help="achieved FLOP/s as % of the published bf16 "
+                           "peak (context.DEVICE_PEAKS)"
                       ).set(rec["mfu_pct"])
         metrics.gauge("perf.hbm_util_pct", labels={"scope": "step"},
-                      help="achieved HBM traffic as % of the measured "
-                           "bandwidth ceiling").set(rec["hbm_util_pct"])
+                      help="achieved HBM traffic as % of the published "
+                           "bandwidth").set(rec["hbm_util_pct"])
     return rec
 
 

@@ -18,8 +18,7 @@ formation vs device compute vs host fetch. This module is that answer
 * **Sampling** — ``begin(kind)`` honors ``MXNET_OBS_TRACE_SAMPLE``
   (0 = off, 1 = every request, N = 1-in-N) and returns a shared no-op
   trace when this request is not sampled, so the disabled path is a few
-  method calls per request (gated < 1%/request by ``bench_all.py
-  --obs-overhead``).
+  method calls per request.
 * :class:`TraceReservoir` — a bounded keep of full span timelines for
   the *tail*: the slowest-K requests ever seen (the p99 exemplars a
   latency regression needs) plus the most-recent-K (the "what is the

@@ -24,8 +24,7 @@ and the autoscaler (serving/control/autoscale.py) are pure functions of:
   ``MXNET_OBS_TS_RETAIN`` samples. The clock is injectable, so every
   windowed query is unit-testable against hand-computed values with a
   fake clock (the PR 8 fault-injection discipline). Per-sample cost is
-  one registry walk — gated < 1% duty cycle of the interval by
-  ``bench_all.py --ts-overhead`` on the stable-quantities basis.
+  one registry walk.
 * pre-sample hooks — ``register_pre_sample(name, fn)`` lets owners of
   *derived* gauges refresh them just before each snapshot (the kvstore
   server's per-rank heartbeat AGES grow while ranks stay silent; a

@@ -33,9 +33,9 @@ materializes a T x T tensor in HBM.
 Standard flash-attention recurrence (Dao et al. 2022, public algorithm);
 the kernel implementation is original. Falls back to the XLA reference
 implementation when the sequence length has no TPU-legal block (a static
-rule, ``_pick_block``), and to XLA autodiff of the dense formula for the
-backward when ``MXNET_FLASH_ATTENTION_BWD=0`` (see config.py for the
-knobs). Every kernel traces through :func:`.pallas_common.pallas_call`
+rule, ``_pick_block``). Tile bounds are the caller's arguments or the
+constants below — no flag, no tuning cache. Every kernel traces through
+:func:`.pallas_common.pallas_call`
 (x64 scoped off) and moves its per-row softmax statistics (lse, delta)
 as lane-dense (1, bq) row blocks of (B*H, 1, T) arrays, a block shape
 the TPU lowering accepts where (1, bq) of a 2-d (B*H, T) array is not.
@@ -47,46 +47,16 @@ import math
 
 import numpy as np
 
-from ..autotune import cost_model as _tune_cost
-from ..autotune.registry import declare as _declare_tunable
-from ..config import get_flag
 from .pallas_common import LANES, aligned_block, pallas_call
 
 __all__ = ["flash_attention", "paged_decode_attention",
            "paged_verify_attention"]
 
 
-def _block_space(ctx):
-    """Candidate block bounds at this shape: powers of two up to
-    min(T, 2048) — bounds, not exact sizes (the largest divisor of T at
-    or below the bound is what actually runs)."""
-    T = int(ctx.get("T", 2048))
-    vals = [b for b in (128, 256, 512, 1024, 2048) if b <= T]
-    return tuple(vals) if vals else (T,)
-
-
-# the knob + search-space declaration lives AT the call site (ISSUE 6):
-# the tuner sweeps per-call overrides below, no env mutation involved
-_declare_tunable(
-    "flash_attention.fwd",
-    space=lambda ctx: {"block_q": _block_space(ctx),
-                       "block_k": _block_space(ctx)},
-    default=lambda ctx: {"block_q": get_flag("MXNET_FLASH_BLOCK_Q"),
-                         "block_k": get_flag("MXNET_FLASH_BLOCK_K")},
-    cost=_tune_cost.flash_fwd_cost,
-    doc="Forward kernel q/k block upper bounds (config defaults from "
-        "the v5e sweep of PR 27, PERF.md section 6).")
-_declare_tunable(
-    "flash_attention.bwd",
-    space=lambda ctx: {"block_q": _block_space(ctx),
-                       "block_k": _block_space(ctx)},
-    default=lambda ctx: {"block_q": get_flag("MXNET_FLASH_BWD_BLOCK_Q"),
-                         "block_k": get_flag("MXNET_FLASH_BWD_BLOCK_K")},
-    cost=_tune_cost.flash_bwd_cost,
-    doc="Backward block upper bounds: of the fused dq/dk/dv pass where "
-        "the shape selects it, else of the dk/dv and dq passes.")
-
-
+#: q and k tile upper bounds where the caller names none: one winner at
+#: every length on the v5e (chip sweep, PERF.md §6 PR 27), so constants
+_FWD_BLOCK = 2048
+_BWD_BLOCK = 1024
 #: VMEM the fused backward may plan for (``flash_vmem_bytes``: the whole
 #: head's fp32 dq (T, D), its resident (1, T, D) output block and the
 #: step's tiles). A shape over it runs the two-pass kernels — the static
@@ -113,14 +83,34 @@ def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _tuned_block(value):
-    """Positive-int coercion of a tuning-cache value; a corrupt or
-    hand-edited entry degrades to the config default, never a crash."""
-    try:
-        value = int(value)
-    except (TypeError, ValueError):
-        return None
-    return value if value > 0 else None
+def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
+                     Dv=None):
+    """Live VMEM of one grid step (input and output tiles double-buffered
+    by the pipeline, fp32 accumulators single-buffered). With ``T``, the
+    fused backward: the whole head's fp32 dq scratch (T, D) and its
+    resident (1, T, D) output block on top of the dk/dv pass's tiles.
+    ``D`` is the q/k width; ``Dv`` the v/o width where it differs."""
+    db = dtype_bytes
+    Dv = D if Dv is None else Dv
+    if not backward:
+        tiles = (bq * D * db            # q
+                 + bk * (D + Dv) * db   # k, v
+                 + bq * Dv * db)        # out
+        scratch = bq * Dv * 4 + 2 * bq * 4     # acc, m, l (fp32)
+    else:
+        # the dk/dv pass (the dq pass holds one accumulator fewer)
+        tiles = (bq * (D + Dv) * db         # q, do
+                 + 2 * bk * (D + Dv) * db   # k, v, dk, dv
+                 + 2 * bq * 4)              # lse, delta rows
+        scratch = bk * (D + Dv) * 4         # dk_acc, dv_acc
+        if T is not None:
+            tiles += T * D * db       # dq out
+            scratch += T * D * 4      # dq_acc
+    # score/probability intermediates, fp32: the backward's (bq, bk) s^T
+    # and dp^T; the forward works its tile through _FWD_SUB_ROWS rows at
+    # a time
+    inter = (bq if backward else min(bq, _FWD_SUB_ROWS)) * bk * 4 * 2
+    return 2 * tiles + scratch + inter
 
 
 def _pick_block(T, bound, interpret):
@@ -136,7 +126,7 @@ def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None):
     """The static rule that picks the backward: one fused pass while the
     whole head's dq fits the VMEM budget beside the tiles, else two.
     ``D`` is the q/k width, ``Dv`` the v/o width where it differs."""
-    return _tune_cost.flash_vmem_bytes(
+    return flash_vmem_bytes(
         bq, bk, D, itemsize, backward=True, T=T,
         Dv=Dv) <= _FUSED_BWD_VMEM_BUDGET
 
@@ -573,17 +563,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     Block arguments are upper bounds; the largest TPU-legal tiles at or
     below them are used (``_pick_block``: the whole sequence or a
     multiple of 128 dividing T when compiled, any divisor interpreted; a
-    T with no such tile lowers the dense XLA formula). Unset bounds
-    resolve through the autotuner first — a persistent per-device
-    tuning-cache entry for this (shape-bucket, dtype) wins
-    (docs/autotune.md; a miss with MXNET_TUNE=1 outside a trace runs the
-    measured sweep on the spot) — then fall back to config.py
-    (MXNET_FLASH_BLOCK_Q/K for the forward, MXNET_FLASH_BWD_BLOCK_Q/K
-    for the backward; both from the v5e sweep of PR 27, PERF.md
-    section 6). Differentiable: the vjp runs the tiled recompute
-    backward above — fused, or in two passes, by ``_bwd_is_fused`` (dense
-    XLA autodiff of the reference formula when
-    MXNET_FLASH_ATTENTION_BWD=0).
+    T with no such tile lowers the dense XLA formula). An unset bound
+    is this module's constant: 2048/2048 forward, 1024/1024 backward
+    (the v5e sweep of PR 27, PERF.md section 6). Differentiable: the vjp
+    runs the tiled recompute backward above — fused, or in two passes,
+    by ``_bwd_is_fused``.
 
     With ``return_lse`` the per-row logsumexp of the scaled scores is
     returned alongside the output, shape (batch, heads, T) fp32 — the
@@ -602,44 +586,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         raise ValueError("flash_attention: q and k widths differ (%d, %d)"
                          % (D, k.shape[-1]))
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
-    # block resolution: explicit per-call override > tuning-cache entry
-    # for this (device, shape-bucket, dtype) > config.py flag. The cache
-    # consult is one dict probe at trace time; a miss under MXNET_TUNE=1
-    # (outside any jax trace) runs the measured sweep right here.
-    tuned_fwd = tuned_bwd = None
-    if None in (block_q, block_k, block_q_bwd, block_k_bwd):
-        from .. import autotune
-
-        key = autotune.flash_shape_key(T, D, causal, Dv=Dv)
-        ctx = {"T": T, "D": D, "Dv": Dv, "B": B, "H": H, "causal": causal,
-               "dtype": str(q.dtype), "dtype_bytes": q.dtype.itemsize,
-               "interpret": interpret or None}
-        if block_q is None or block_k is None:
-            tuned_fwd = autotune.lookup_or_tune(
-                "flash_attention.fwd", key, dtype=str(q.dtype), ctx=ctx)
-        if block_q_bwd is None or block_k_bwd is None:
-            tuned_bwd = autotune.lookup_or_tune(
-                "flash_attention.bwd", key, dtype=str(q.dtype), ctx=ctx)
-    # corrupt/hand-edited entries (including non-dict values) degrade to
-    # the config defaults — tuning is an optimization, never a crash
-    tuned_fwd = tuned_fwd if isinstance(tuned_fwd, dict) else {}
-    tuned_bwd = tuned_bwd if isinstance(tuned_bwd, dict) else {}
-    block_q = int(block_q or _tuned_block(tuned_fwd.get("block_q"))
-                  or get_flag("MXNET_FLASH_BLOCK_Q"))
-    block_k = int(block_k or _tuned_block(tuned_fwd.get("block_k"))
-                  or get_flag("MXNET_FLASH_BLOCK_K"))
-    block_q_bwd = int(block_q_bwd or _tuned_block(tuned_bwd.get("block_q"))
-                      or get_flag("MXNET_FLASH_BWD_BLOCK_Q"))
-    block_k_bwd = int(block_k_bwd or _tuned_block(tuned_bwd.get("block_k"))
-                      or get_flag("MXNET_FLASH_BWD_BLOCK_K"))
     # block sizes are upper bounds; _pick_block turns each into a tile
     # the TPU lowering accepts or declines (no 128-multiple divisor
     # compiled, or prime-ish T with only tiny divisors) — a declined
     # shape lowers the XLA formula instead, a static decision.
-    block_q = _pick_block(T, block_q, interpret)
-    block_k = _pick_block(T, block_k, interpret)
-    block_q_bwd = _pick_block(T, block_q_bwd, interpret)
-    block_k_bwd = _pick_block(T, block_k_bwd, interpret)
+    block_q = _pick_block(T, int(block_q or _FWD_BLOCK), interpret)
+    block_k = _pick_block(T, int(block_k or _FWD_BLOCK), interpret)
+    block_q_bwd = _pick_block(T, int(block_q_bwd or _BWD_BLOCK), interpret)
+    block_k_bwd = _pick_block(T, int(block_k_bwd or _BWD_BLOCK), interpret)
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale)
         return (out, lse) if return_lse else out
@@ -685,16 +639,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         return (out, lse), (q, k, v, out, lse)
 
     def _bwd(res, g):
-        q, k, v, out, lse = res
-        do, dlse = g
-        if not get_flag("MXNET_FLASH_ATTENTION_BWD"):
-            # escape hatch: XLA autodiff of the dense formula (the
-            # forward's memory win stands; backward materializes T x T)
-            _, vjp = jax.vjp(
-                lambda q, k, v: _dense_with_lse(q, k, v, causal=causal,
-                                                scale=scale), q, k, v)
-            return vjp((do, dlse))
-        return _flash_bwd_impl(q, k, v, out, lse, do, dlse)
+        return _flash_bwd_impl(*res, *g)
 
     _flash.defvjp(_fwd, _bwd)
 
@@ -872,8 +817,8 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, lengths,
 
 
 def _dense_with_lse(q, k, v, causal=False, scale=None):
-    """XLA reference returning (out, lse) — the fallback for prime-ish T
-    and the MXNET_FLASH_ATTENTION_BWD=0 escape hatch."""
+    """XLA reference returning (out, lse) — the lowering of a T with no
+    legal tile, and the tests' oracle."""
     import jax
     import jax.numpy as jnp
 

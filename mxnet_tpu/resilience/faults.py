@@ -6,7 +6,7 @@ registry discipline as ``autotune/registry.py``, so the chaos spec's
 view of the fault surface and the code's view can never drift — and
 drop one ``faults.inject("point")`` call at the top of the guarded
 operation. With no spec configured that call is a few-nanosecond global
-read (regression-gated by ``bench_all.py --resilience-overhead``).
+read.
 
 Under a spec — the ``MXNET_FAULTS`` environment variable or
 :func:`configure` — matching calls deterministically misbehave::

@@ -1,5 +1,5 @@
 """Autotuner tests (ISSUE 6): persistent tuning cache semantics, search
-driver behavior, and the three consulting call sites (flash-attention
+driver behavior, and the three consulting call sites (fused-kernel
 blocks, executor remat, serving bucket ladder).
 
 The acceptance-critical properties regression-tested here:
@@ -44,16 +44,16 @@ def tune_env(tmp_path, monkeypatch):
 
 # --------------------------------------------------------------- cache
 def test_round_trip_persistence(tune_env):
-    key = ("T512", "D64", "causal")
-    autotune.record("flash_attention.fwd", key,
-                    {"block_q": 256, "block_k": 512},
+    key = ("M512", "N64", "K64")
+    autotune.record("fusion.blocks", key,
+                    {"block_m": 256, "block_n": 512},
                     dtype="bfloat16", ms=1.25, trials=5)
     # fresh-process simulation: drop every in-memory structure
     cache.reset()
-    assert autotune.lookup("flash_attention.fwd", key,
-                           dtype="bfloat16") == {"block_q": 256,
-                                                 "block_k": 512}
-    entry = autotune.lookup_entry("flash_attention.fwd", key,
+    assert autotune.lookup("fusion.blocks", key,
+                           dtype="bfloat16") == {"block_m": 256,
+                                                 "block_n": 512}
+    entry = autotune.lookup_entry("fusion.blocks", key,
                                   dtype="bfloat16")
     assert entry["fingerprint"] == "fp-A"
     assert entry["ms"] == 1.25 and entry["trials"] == 5
@@ -61,7 +61,7 @@ def test_round_trip_persistence(tune_env):
         payload = json.load(f)
     assert payload["version"] == 1
     assert list(payload["entries"]) == [
-        "fp-A|flash_attention.fwd|T512,D64,causal|bfloat16"]
+        "fp-A|fusion.blocks|M512,N64,K64|bfloat16"]
 
 
 def test_dtype_and_key_separate_entries(tune_env):
@@ -121,13 +121,13 @@ def test_cross_process_merge_on_write(tune_env):
 
 
 def test_stale_fingerprint_invalidation(tune_env, monkeypatch):
-    key = ("T512", "D64", "causal")
-    autotune.record("flash_attention.fwd", key, {"block_q": 256},
+    key = ("M512", "N64", "K64")
+    autotune.record("fusion.blocks", key, {"block_m": 256},
                     dtype="bfloat16")
     # same cache file, different chip: the entry must never match
     monkeypatch.setenv("MXNET_TUNE_FINGERPRINT", "fp-B")
     cache.reset()
-    assert autotune.lookup("flash_attention.fwd", key,
+    assert autotune.lookup("fusion.blocks", key,
                            dtype="bfloat16") is None
     assert autotune.scrub_stale() == 1
     with open(os.environ["MXNET_TUNE_CACHE"]) as f:
@@ -135,7 +135,7 @@ def test_stale_fingerprint_invalidation(tune_env, monkeypatch):
     # back on fp-A: entry is gone from disk too
     monkeypatch.setenv("MXNET_TUNE_FINGERPRINT", "fp-A")
     cache.reset()
-    assert autotune.lookup("flash_attention.fwd", key,
+    assert autotune.lookup("fusion.blocks", key,
                            dtype="bfloat16") is None
 
 
@@ -200,25 +200,23 @@ def test_cache_hit_never_triggers_measurement(tune_env):
 
 
 def test_second_process_zero_measurements(tune_env):
-    """A fresh process with a warm cache resolves flash blocks through
-    the real flash_attention call site with ZERO measurements, even
-    under MXNET_TUNE=1 (the compile/measure-counter regression)."""
-    key = autotune.flash_shape_key(128, 16, False)
-    autotune.record("flash_attention.fwd", key,
-                    {"block_q": 64, "block_k": 64}, dtype="float32")
-    autotune.record("flash_attention.bwd", key,
-                    {"block_q": 64, "block_k": 64}, dtype="float32")
+    """A fresh process with a warm cache resolves the fused kernel's
+    blocks through the real call site (``resolve_blocks``) with ZERO
+    measurements, even under MXNET_TUNE=1 (the compile/measure-counter
+    regression)."""
+    from mxnet_tpu.parallel.fused import fused_shape_key
+
+    tuned = {"block_m": 64, "block_n": 64, "block_k": 128}
+    autotune.record("fusion.blocks", fused_shape_key(128, 128, 256), tuned,
+                    dtype="float32")
     child_src = (
         "import sys; sys.path.insert(0, %r)\n"
-        "import numpy as np, jax.numpy as jnp\n"
         "from mxnet_tpu import autotune\n"
-        "from mxnet_tpu.parallel.flash_attention import flash_attention\n"
-        "q = jnp.asarray(np.random.RandomState(0).randn(1, 2, 128, 16),\n"
-        "                jnp.float32)\n"
-        "out = flash_attention(q, q, q, interpret=True)\n"
+        "from mxnet_tpu.parallel.fused import resolve_blocks\n"
+        "assert resolve_blocks(128, 128, 256) == (64, 64, 128)\n"
         "s = autotune.stats()\n"
         "assert s['measurements'] == 0 and s['searches'] == 0, s\n"
-        "assert s['hits'] >= 2, s\n"
+        "assert s['hits'] == 1, s\n"
         "print('OK', s)\n" % _REPO)
     child = subprocess.run(
         [sys.executable, "-c", child_src],
@@ -251,57 +249,6 @@ def test_lookup_or_tune_never_searches_inside_trace(tune_env):
 
 
 # ---------------------------------------------------------- cost model
-def test_flash_cost_prunes_vmem_overflow():
-    ctx = {"T": 8192, "D": 256, "B": 1, "H": 8, "causal": True,
-           "dtype_bytes": 4}
-    big = cost_model.flash_fwd_cost({"block_q": 8192, "block_k": 8192},
-                                    ctx)
-    sane = cost_model.flash_fwd_cost({"block_q": 512, "block_k": 512},
-                                     ctx)
-    assert big == float("inf")
-    assert np.isfinite(sane) and sane > 0
-
-
-def test_flash_cost_penalizes_tiny_blocks():
-    ctx = {"T": 4096, "D": 64, "B": 1, "H": 8, "causal": False,
-           "dtype_bytes": 2}
-    tiny = cost_model.flash_fwd_cost({"block_q": 8, "block_k": 8}, ctx)
-    sane = cost_model.flash_fwd_cost({"block_q": 512, "block_k": 512},
-                                     ctx)
-    assert tiny > sane  # grid-step overhead dominates 512x512 grids
-
-
-def test_flash_cost_counts_live_tiles_and_the_fused_backward(monkeypatch):
-    import importlib
-
-    # tiles a causal diagonal leaves live, exactly: 10 of 16, 3 of 4, and
-    # 512-row q blocks against 1024-wide k blocks
-    assert cost_model._live_tiles(4, 4, 512, 512, True) == 10
-    assert cost_model._live_tiles(2, 2, 1024, 1024, True) == 3
-    assert cost_model._live_tiles(4, 2, 512, 1024, True) == 6
-    assert cost_model._live_tiles(4, 2, 512, 1024, False) == 8
-    # a square tile ON the diagonal, worked through in 256-wide
-    # sub-chunks that stop at it, computes 10 of its 16 sub-blocks
-    assert cost_model._live_tiles(2, 2, 1024, 1024, True,
-                                  grain=256) == 1 + 2 * 10 / 16
-    # the fused backward holds the whole head's fp32 dq and its resident
-    # output block (double-buffered like every tile) on top of the tiles
-    assert (cost_model.flash_vmem_bytes(512, 512, 128, 2, backward=True,
-                                        T=2048)
-            - cost_model.flash_vmem_bytes(512, 512, 128, 2, backward=True)
-            == 2048 * 128 * 4 + 2 * 2048 * 128 * 2)
-    # 5 matmuls a live tile in one pass against 7 in two
-    ctx = {"T": 2048, "D": 128, "B": 2, "H": 16, "causal": True,
-           "dtype_bytes": 2}
-    blocks = {"block_q": 512, "block_k": 512}
-    fused = cost_model.flash_bwd_cost(blocks, ctx)
-    monkeypatch.setattr(
-        importlib.import_module("mxnet_tpu.parallel.flash_attention"),
-        "_FUSED_BWD_VMEM_BUDGET", 0)
-    two_pass = cost_model.flash_bwd_cost(blocks, ctx)
-    assert 1.4 <= two_pass / fused < 1.7   # 7/5 and twice the grid steps
-
-
 def test_expected_padding_math():
     # ladder (1,2,4): sizes 1->1, 2->2, 3->4, 4->4 : alloc 11 / real 10
     assert cost_model.expected_padding((1, 2, 4), [1, 2, 3, 4]) == \
@@ -312,29 +259,6 @@ def test_expected_padding_math():
 
 
 # ------------------------------------------------- consulting call sites
-def test_flash_attention_consults_tuned_blocks(tune_env):
-    """A tuned entry steers the kernel's block choice and numerics stay
-    exact vs the dense reference."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu.parallel.flash_attention import (_dense_with_lse,
-                                                    flash_attention)
-
-    key = autotune.flash_shape_key(128, 16, True)
-    autotune.record("flash_attention.fwd", key,
-                    {"block_q": 32, "block_k": 64}, dtype="float32")
-    cache.reset_stats()
-    rng = np.random.RandomState(3)
-    q, k, v = (jnp.asarray(rng.randn(1, 2, 128, 16), jnp.float32)
-               for _ in range(3))
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    ref, _ = _dense_with_lse(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5)
-    assert cache.stats()["hits"] >= 1  # the fwd entry was consulted
-    assert cache.stats()["measurements"] == 0
-
-
 def test_graph_tuning_key_stable_and_shape_free():
     from mxnet_tpu.executor import _GraphProgram
 
@@ -474,11 +398,8 @@ def test_ladder_candidates_and_signature():
 def test_corrupt_cache_entries_degrade_to_defaults(tune_env):
     """A hand-edited/corrupt cache entry must degrade to the config
     defaults at every consulting call site, never crash."""
-    import jax.numpy as jnp
-
     from mxnet_tpu.autotune.tuners import model_key
-    from mxnet_tpu.parallel.flash_attention import (_dense_with_lse,
-                                                    flash_attention)
+    from mxnet_tpu.parallel.fused import fused_shape_key, resolve_blocks
     from mxnet_tpu.serving import InferenceServer
     from mxnet_tpu.serving.buckets import DEFAULT_BUCKETS
 
@@ -493,24 +414,22 @@ def test_corrupt_cache_entries_degrade_to_defaults(tune_env):
                           data_shapes=[("data", (1, 4))], start=False)
     assert srv._cfg.buckets == DEFAULT_BUCKETS
 
-    key = autotune.flash_shape_key(128, 16, False)
-    autotune.record("flash_attention.fwd", key,
-                    {"block_q": "garbage", "block_k": -5},
+    key = fused_shape_key(128, 128, 256)
+    flags = tuple(mxconfig.get_flag("MXNET_FUSION_BLOCK_" + d)
+                  for d in "MNK")
+    autotune.record("fusion.blocks", key,
+                    {"block_m": "garbage", "block_n": -5, "block_k": None},
                     dtype="float32")
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(1, 2, 128, 16), jnp.float32)
-    out = flash_attention(q, q, q, interpret=True)
-    ref, _ = _dense_with_lse(q, q, q)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5)
+    assert resolve_blocks(128, 128, 256) == flags
+    # a sound field beside corrupt ones is still honoured
+    autotune.record("fusion.blocks", key,
+                    {"block_m": 64, "block_n": "x"}, dtype="float32")
+    assert resolve_blocks(128, 128, 256) == (64,) + flags[1:]
 
     # NON-DICT values (a hand-edited "value": [...]) must degrade too
-    autotune.record("flash_attention.fwd", key, [128, 256],
-                    dtype="float32")
-    autotune.record("flash_attention.bwd", key, "64", dtype="float32")
-    out = flash_attention(q, q, q, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5)
+    for value in ([128, 256], "64"):
+        autotune.record("fusion.blocks", key, value, dtype="float32")
+        assert resolve_blocks(128, 128, 256) == flags
     autotune.record("serving.buckets", (model_key(net), "default"),
                     [1, 2, 4])
     srv2 = InferenceServer(net, {"fc_weight": mx.nd.zeros((8, 4)),
@@ -561,31 +480,6 @@ def test_scrub_preserves_other_process_entries(tune_env, monkeypatch):
         assert "fp-B|op.old|k|-" not in json.load(f)["entries"]
 
 
-def test_auto_tune_bwd_miss_preserves_shipped_fwd_entry(tune_env):
-    """MXNET_TUNE=1 with only the bwd entry missing must search ONLY the
-    backward space — a shipped fwd winner is reused, not re-measured or
-    overwritten by a local sweep."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu.parallel.flash_attention import flash_attention
-
-    key = autotune.flash_shape_key(64, 8, True)
-    shipped = {"block_q": 64, "block_k": 64, "marker": "shipped"}
-    autotune.record("flash_attention.fwd", key, shipped, dtype="float32")
-    mxconfig.set_flag("MXNET_TUNE", 1)
-    try:
-        rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.randn(1, 1, 64, 8), jnp.float32)
-        flash_attention(q, q, q, causal=True, interpret=True)
-    finally:
-        mxconfig.set_flag("MXNET_TUNE", None)
-    assert autotune.lookup("flash_attention.fwd", key,
-                           dtype="float32") == shipped
-    assert autotune.lookup("flash_attention.bwd", key,
-                           dtype="float32") is not None
-    assert cache.stats()["searches"] == 1  # bwd only — no fwd re-sweep
-
-
 def test_all_tunables_registered_at_package_import(tune_env):
     """Every declared knob — including graph.layout, which has no
     in-package call site — must be visible in a FRESH process without
@@ -595,9 +489,7 @@ def test_all_tunables_registered_at_package_import(tune_env):
          "import sys; sys.path.insert(0, %r)\n"
          "from mxnet_tpu.autotune import registry, tunable_names\n"
          "names = tunable_names()\n"
-         "for n in ('exec.remat', 'flash_attention.fwd',\n"
-         "          'flash_attention.bwd', 'serving.buckets',\n"
-         "          'graph.layout'):\n"
+         "for n in ('exec.remat', 'serving.buckets', 'graph.layout'):\n"
          "    assert n in names, (n, names)\n"
          "    registry.get(n)\n"
          "print('OK')\n" % _REPO],

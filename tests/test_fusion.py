@@ -1,16 +1,13 @@
-"""Fusion-region codegen + learned cost model tests (ISSUE 15).
+"""Fusion-region codegen tests (ISSUE 15).
 
-Four surfaces:
+Three surfaces:
 
 * the ``fuse`` graph pass — region grammar, parity (reference AND
   Pallas-kernel lowering), training-bind grads, re-bind caching,
 * the fused matmul+epilogue kernels (interpret mode on CPU) vs a numpy
   reference,
 * the post-fusion perf accounting — the fused-vs-unfused analytic byte
-  identity is pinned EXACTLY,
-* the learned cost model — featurization, Spearman, the holdout gate,
-  persistence, search-ranking consult and the degrade-to-analytic
-  contract.
+  identity is pinned EXACTLY.
 """
 import numpy as np
 import pytest
@@ -33,15 +30,10 @@ def _passes_reset():
 
 @pytest.fixture
 def own_tune_cache(tmp_path, monkeypatch):
-    from mxnet_tpu.autotune import learned
-
     monkeypatch.setenv("MXNET_TUNE_CACHE", str(tmp_path / "tuning.json"))
-    monkeypatch.delenv("MXNET_COST_MODEL_PATH", raising=False)
     autotune.reset()
-    learned.reset()
     yield
     autotune.reset()
-    learned.reset()
 
 
 @pytest.fixture
@@ -453,211 +445,15 @@ def test_perf_report_fusion_adoption():
     assert "FUSED" in text and "op:softmax" in text
 
 
-# ------------------------------------------------- learned cost model
-
-def test_spearman_math():
-    from mxnet_tpu.autotune import learned
-
-    assert learned.spearman([1, 2, 3], [10, 20, 30]) == pytest.approx(1.0)
-    assert learned.spearman([1, 2, 3], [30, 20, 10]) == pytest.approx(-1.0)
-    assert learned.spearman([1, 1, 1], [1, 2, 3]) == 0.0
-    # tie-averaging: monotone with a tie still correlates positively
-    assert learned.spearman([1, 2, 2, 3], [1, 2, 3, 4]) > 0.9
-
-
-def test_featurize_deterministic():
-    from mxnet_tpu.autotune import learned
-
-    a = learned.featurize("op", {"block_m": 128}, {"M": 512}, 1e-3)
-    b = learned.featurize("op", {"block_m": 128}, {"M": 512}, 1e-3)
-    np.testing.assert_array_equal(a, b)
-    c = learned.featurize("op", {"block_m": 256}, {"M": 512}, 1e-3)
-    assert not np.array_equal(a, c)
-
-
-def _make_samples(n_groups=8, per_group=8):
-    """Synthetic searches where the measured time is learnable and the
-    analytic cost ranks BACKWARD (the case the graduation exists for)."""
-    rows = []
-    for g in range(n_groups):
-        for i in range(per_group):
-            a = 2 ** (i % 4)
-            rows.append({
-                "op": "toy.knob", "candidate": {"a": a},
-                "ctx": {"M": 64 * (g + 1)},
-                "s": 1e-3 * (abs(a - 4) + 1) * (1 + 0.05 * g),
-                "analytic_s": 1e-3 / a})
-    return rows
-
-
-def test_train_gate_and_rank(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-
-    learned.append_samples(_make_samples())
-    model = learned.train(min_samples=4)
-    assert model is not None
-    assert model.meta["gate_ok"], model.meta
-    assert model.meta["spearman_learned"] > model.meta["spearman_analytic"]
-    # persisted + warm-loadable with identical weights
-    loaded = learned.load()
-    np.testing.assert_allclose(loaded.w, model.w)
-    # ranking consult serves the gated model
-    assert learned.ranking_model() is not None
-    ranked = learned.rank_candidates(
-        "toy.knob", [{"a": 1}, {"a": 4}, {"a": 16}], {"M": 64},
-        cost_fn=lambda c, ctx: 1e-3 / c["a"])
-    assert ranked is not None and ranked[0] == {"a": 4}
-
-
-def test_degenerate_holdout_never_passes_gate(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-
-    # ONE search group: whatever the hash says, there is no disjoint
-    # fit/holdout split — in-sample evidence must not open the gate
-    learned.append_samples(_make_samples(n_groups=1, per_group=12))
-    model = learned.train(min_samples=4, holdout_frac=1.0)
-    assert model is not None
-    assert model.meta["in_sample"] is True
-    assert model.meta["gate_ok"] is False
-    assert learned.ranking_model() is None
-
-
-def test_foreign_fingerprint_model_degrades(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-
-    learned.append_samples(_make_samples())
-    model = learned.train(min_samples=4)
-    assert model is not None and model.meta["gate_ok"]
-    # a model trained on another chip must not rank this one's searches
-    model.meta["fingerprint"] = "tpu:some-other-chip"
-    model.save()
-    learned.reset()
-    assert learned.ranking_model() is None
-    # foreign-fingerprint SAMPLES are excluded from training too
-    learned.append_samples([{"op": "x", "candidate": {"a": 1},
-                             "ctx": {}, "s": 1e-3,
-                             "fingerprint": "tpu:some-other-chip"}])
-    rows = [r for r in learned.read_samples()
-            if r.get("fingerprint") == "tpu:some-other-chip"]
-    assert rows
-    model2 = learned.train(min_samples=4)
-    assert model2.meta["n_samples"] == model.meta["n_samples"]
-
-
-def test_gate_failure_degrades_to_analytic(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-
-    learned.append_samples(_make_samples())
-    model = learned.train(min_samples=4)
-    model.meta["gate_ok"] = False
-    model.save()
-    learned.reset()
-    assert learned.ranking_model() is None
-    assert learned.rank_candidates("toy.knob", [{"a": 1}], {}) is None
-    # MXNET_COST_MODEL=0 turns the whole layer off
-    model.meta["gate_ok"] = True
-    model.save()
-    learned.reset()
-    set_flag("MXNET_COST_MODEL", 0)
-    try:
-        assert learned.ranking_model() is None
-        assert learned.note_samples("x", {}, [({"a": 1}, 1e-3)]) is None
-    finally:
-        set_flag("MXNET_COST_MODEL", None)
-
-
-def test_search_records_samples_and_ranks(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-    from mxnet_tpu.autotune import search as S
-
-    tun = autotune.declare(
-        "fusiontest.knob",
-        space={"a": (1, 2, 4, 8, 16), "b": (1, 2, 4)},
-        default=lambda ctx: {"a": 4, "b": 2},
-        cost=lambda c, ctx: 1e-3 / (c["a"] * c["b"]))
-
-    def measure_for(i):
-        return lambda c: (abs(c["a"] - 4) + abs(c["b"] - 2) + 1) \
-            * 1e-3 * (1 + 0.1 * i)
-
-    n0 = learned.sample_count()
-    for i in range(8):
-        S.search(tun, measure_for(i), ctx={"M": 64 * (i + 1)},
-                 cfg=S.SearchConfig(trials=10))
-    assert learned.sample_count() > n0
-    # enough groups accumulated: auto-training ran and the gate holds
-    model = learned.train(min_samples=8)
-    assert model is not None and model.meta["gate_ok"]
-    res = S.search(tun, measure_for(9), ctx={"M": 4096},
-                   cfg=S.SearchConfig(trials=3))
-    assert res.ranker == "learned"
-    assert res.as_dict()["ranker"] == "learned"
-
-
-def test_maybe_train_thresholds(own_tune_cache, monkeypatch):
-    from mxnet_tpu.autotune import learned
-
-    monkeypatch.setenv("MXNET_COST_MODEL_MIN_SAMPLES", "1000000")
-    assert learned.maybe_train() is None  # below min: no training
-    monkeypatch.setenv("MXNET_COST_MODEL_MIN_SAMPLES", "8")
-    learned.append_samples(_make_samples(n_groups=4, per_group=4))
-    model = learned.maybe_train(retrain_delta=4)
-    assert model is not None
-    # no new samples: retrain threshold not met
-    assert learned.maybe_train(retrain_delta=4) is None
-    # foreign-fingerprint rows count toward the RAW delta baseline, so
-    # a dataset holding them cannot trip a retrain on every search
-    learned.append_samples([{"op": "x", "candidate": {"a": 1}, "ctx": {},
-                             "s": 1e-3, "fingerprint": "tpu:other"}
-                            for _ in range(4)])
-    assert learned.maybe_train(retrain_delta=4) is not None  # delta met
-    assert learned.maybe_train(retrain_delta=4) is None      # and consumed
-
-
-def test_ingest_ledger(own_tune_cache, tmp_path):
-    from mxnet_tpu.autotune import learned
-
-    ledger = str(tmp_path / "ledger.jsonl")
-    perf.append_ledger({
-        "ts": "t", "fingerprint": {"device": "cpu"},
-        "programs": [{"graph": "g", "mode": "train", "flops": 10 ** 9,
-                      "hbm_bytes": 10 ** 7, "roofline_ms": 1.0,
-                      "device_ms_ema": 3.0}]}, ledger)
-    n = learned.ingest_ledger(ledger)
-    assert n == 1
-    rows = learned.read_samples()
-    assert rows[-1]["op"] == "program"
-    assert rows[-1]["analytic_s"] == pytest.approx(1e-3)
-    # idempotent: re-ingesting the same ledger appends nothing
-    assert learned.ingest_ledger(ledger) == 0
-    assert len(learned.read_samples()) == len(rows)
-
-
-def test_ingest_tune_cache(own_tune_cache):
-    from mxnet_tpu.autotune import learned
-
-    autotune.cache.record("fusion.blocks", {"M": 64}, {"bm": 128},
-                          dtype="float32", ms=2.5, trials=3)
-    autotune.cache.record("io.prefetch", "bs64", {"depth": 4})  # no ms
-    n0 = learned.sample_count()
-    assert learned.ingest_tune_cache() == 1
-    row = learned.read_samples()[-1]
-    assert row["op"] == "fusion.blocks"
-    assert row["candidate"] == {"bm": 128}
-    assert row["s"] == pytest.approx(2.5e-3)
-    assert row["ctx"]["dtype"] == "float32"
-    assert learned.sample_count() == n0 + 1
-    # idempotent: the same winner never duplicates
-    assert learned.ingest_tune_cache() == 0
-    assert learned.sample_count() == n0 + 1
-
+# ----------------------------------------------------------- the tuner
 
 def test_tune_fused_matmul_records(own_tune_cache):
-    from mxnet_tpu.autotune import learned
     from mxnet_tpu.parallel.fused import fused_shape_key
 
+    autotune.reset_stats()
     best = autotune.tune_fused_matmul(64, 64, 128, trials=3, repeats=1)
-    entry = autotune.lookup("fusion.blocks", fused_shape_key(64, 64, 128),
-                            dtype="float32")
-    assert entry == best
-    assert learned.sample_count() >= 3
+    entry = autotune.lookup_entry("fusion.blocks",
+                                  fused_shape_key(64, 64, 128),
+                                  dtype="float32")
+    assert entry["value"] == best and entry["trials"] == 3
+    assert autotune.stats()["measurements"] == 3

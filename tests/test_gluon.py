@@ -369,11 +369,9 @@ def test_model_zoo_parameter_counts():
 
 
 def test_bench_gluon_config_engages_fusion():
-    """Guard for the BENCH_ALL gluon config: the exact bench_all setup
-    (hybridized zoo net + Trainer(kvstore='local') on one device) must
-    take the FUSED update path — the recorded 2.0 img/s came from the
-    per-param dispatch path (one dispatch per parameter; PERF_NOTES
-    round 4)."""
+    """A hybridized zoo net + Trainer(kvstore='local') on one device must
+    take the FUSED update path, not the per-param dispatch path (one
+    dispatch per parameter; PERF_NOTES round 4)."""
     from mxnet_tpu import autograd
     from mxnet_tpu.gluon.model_zoo.vision import resnet18_v1
 
@@ -394,7 +392,7 @@ def test_bench_gluon_config_engages_fusion():
     assert tr._can_fuse()
     assert tr._fused is not None        # the fused program actually ran
 
-    # the BENCH_ALL config itself drives compile_step (whole-step fusion);
+    # the same setup drives compile_step (whole-step fusion);
     # guard that this exact setup compiles and runs it
     step = tr.compile_step(net, loss_fn)
     step(x, y)

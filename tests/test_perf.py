@@ -219,12 +219,18 @@ def test_roofline_seconds_basis_is_cost_model():
     assert cost["roofline_s"] == cost_model.roofline_seconds(
         cost["flops"], cost["hbm_bytes"])
     assert cost["ridge_intensity"] == cost_model.ridge_intensity()
-    # the three historic ceiling statements now share one table
-    assert cost_model.CEILINGS["matmul_tf_s"] == \
-        cost_model.MEASURED_MATMUL_TF
-    from tools.flops_anchor import MEASURED_MATMUL_TF as anchor_tf
+    # one peak table in the program: the ceilings ARE the published
+    # peaks, what ``mfu_pct`` in PERF_LEDGER.jsonl is a share of
+    from mxnet_tpu.context import DEVICE_PEAKS
 
-    assert anchor_tf == cost_model.MEASURED_MATMUL_TF
+    peaks = DEVICE_PEAKS["TPU v5 lite"]
+    assert cost_model.PEAK_FLOPS_PER_S == peaks["bf16_flops_per_s"] == 197e12
+    assert cost_model.PEAK_HBM_BYTES_PER_S == peaks["hbm_bytes_per_s"]
+    assert cost_model.CEILINGS["matmul_tf_s"] == 197.0
+    assert cost_model.CEILINGS["hbm_gb_s"] == 819.0
+    assert "DEVICE_PEAKS" in cost_model.CEILINGS["source"]
+    assert cost_model.roofline_seconds(197e12, 0) == pytest.approx(1.0)
+    assert cost_model.roofline_seconds(0, 819e9) == pytest.approx(1.0)
 
 
 def test_fusion_candidates_ranked_by_saved_bytes():
@@ -343,7 +349,7 @@ def test_scope_suspended_hides_and_restores():
 def test_warmup_run_does_not_publish_program_gauge(telemetry):
     cost = {"graph": "g", "mode": "train", "flops": 10 ** 9,
             "hbm_bytes": 10 ** 6, "roofline_s": 1e-4,
-            "ridge_intensity": 202.8, "basis": "forward walk",
+            "ridge_intensity": 240.5, "basis": "forward walk",
             "ops": [], "fusion_candidates": []}
     # the instrument may already exist (earlier tests in a full run);
     # the property under test is that the WARMUP note does not touch it

@@ -45,7 +45,7 @@ def _aval(shape, dtype):
 @pytest.mark.parametrize("shape,dtype", [
     ((2, 16, 2048, 128), "bfloat16"),  # the cell lm_train_t2048_b2
     ((1, 16, 8192, 128), "bfloat16"),  # the longest local length queued
-    ((8, 8, 2048, 64), "bfloat16"),   # the LM step (bench_all, chip_smoke)
+    ((8, 8, 2048, 64), "bfloat16"),   # the LM step (chip_smoke)
     ((1, 8, 1024, 64), "bfloat16"),   # chip_smoke's parity shape
     ((1, 8, 128, 64), "bfloat16"),    # the smallest prefill bucket
     ((2, 4, 100, 64), "float32"),     # misaligned, fits one block

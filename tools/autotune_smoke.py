@@ -28,22 +28,24 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(_HERE)
 sys.path.insert(0, _REPO)
 
-_KEY = ("T256", "D32", "causal")
-_OPT = {"block_q": 128, "block_k": 256}
+_SHAPE = {"M": 256, "N": 256, "K": 512}
+_OPT = {"block_m": 256, "block_n": 128, "block_k": 256}
 
 _CHILD = """
 import json, sys
 sys.path.insert(0, %r)
 from mxnet_tpu import autotune
+from mxnet_tpu.parallel.fused import fused_shape_key
 
-val = autotune.lookup("flash_attention.fwd", %r, dtype="bfloat16")
+val = autotune.lookup("fusion.blocks", fused_shape_key(**%r),
+                      dtype="bfloat16")
 stats = autotune.stats()
 assert val == %r, "warm-cache lookup returned %%r" %% (val,)
 assert stats["hits"] == 1, stats
 assert stats["measurements"] == 0 and stats["searches"] == 0, (
     "a warm cache must never measure: %%s" %% stats)
 print(json.dumps(stats))
-""" % (_REPO, _KEY, _OPT)
+""" % (_REPO, _SHAPE, _OPT)
 
 
 def main(out_path=None):
@@ -54,6 +56,7 @@ def main(out_path=None):
 
     from mxnet_tpu import autotune
     from mxnet_tpu.autotune import SearchConfig, registry, search
+    from mxnet_tpu.parallel.fused import fused_shape_key
 
     # stubbed measurer: a deterministic cost surface with its optimum at
     # _OPT — exercises pruning/refinement/counters without a device
@@ -61,25 +64,23 @@ def main(out_path=None):
 
     def measure(c):
         calls.append(dict(c))
-        return (1e-3 + abs(c["block_q"] - _OPT["block_q"]) * 1e-6
-                + abs(c["block_k"] - _OPT["block_k"]) * 1e-7)
+        return 1e-3 + sum(abs(c[k] - _OPT[k]) for k in _OPT) * 1e-6
 
-    tunable = registry.get("flash_attention.fwd")
-    ctx = {"T": 256, "D": 32, "causal": True}
-    res = search.search(tunable, measure, ctx=ctx,
-                        cfg=SearchConfig(trials=6))
+    tunable = registry.get("fusion.blocks")
+    res = search.search(tunable, measure, ctx=dict(_SHAPE, dtype_bytes=2),
+                        cfg=SearchConfig(trials=12))
     assert res.best == _OPT, "search missed the stub optimum: %r" % res.best
     assert res.measured == len(calls) > 0, (res.measured, len(calls))
     assert autotune.stats()["measurements"] == len(calls), autotune.stats()
 
-    autotune.record("flash_attention.fwd", _KEY, res.best,
+    autotune.record("fusion.blocks", fused_shape_key(**_SHAPE), res.best,
                     dtype="bfloat16", ms=res.best_s * 1e3,
                     trials=res.measured)
     assert os.path.exists(cache_file), "cache file was not written"
     with open(cache_file) as f:
         payload = json.load(f)
     keys = list(payload["entries"])
-    assert keys == ["smoke-device|flash_attention.fwd|T256,D32,causal"
+    assert keys == ["smoke-device|fusion.blocks|M256,N256,K512"
                     "|bfloat16"], keys
 
     # second process, warm cache: hit, zero measurements

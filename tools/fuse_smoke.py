@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""CPU-fast fusion-region + learned-cost-model smoke (tier-1 CI guard,
-docs/fusion.md).
+"""CPU-fast fusion-region smoke (tier-1 CI guard, docs/fusion.md).
 
 End-to-end in seconds on CPU, the way production uses the layer:
 
@@ -12,19 +11,13 @@ End-to-end in seconds on CPU, the way production uses the layer:
    (``default,-fuse``) at fp32 tolerances, on the reference-composition
    path AND on the real Pallas kernel path (MXNET_FUSION_INTERPRET=1),
 3. **flat re-bind cost** — reshaping to an already-seen batch shape
-   re-runs neither the pass pipeline nor XLA compilation,
-4. **cost model lifecycle** — a measured ``fusion.blocks`` sweep
-   records samples, training persists the model + holdout-gate verdict,
-   and a SECOND PROCESS warm-loads it with zero re-training (the
-   tuning-cache acceptance bar applied to the model file); the search
-   ranking provably degrades to analytic when the gate fails.
+   re-runs neither the pass pipeline nor XLA compilation.
 
 Prints a one-line JSON summary (optionally written to argv[1]); any
 violation raises, failing the CI step.
 """
 import json
 import os
-import subprocess
 import sys
 import tempfile
 
@@ -33,23 +26,17 @@ _REPO = os.path.dirname(_HERE)
 sys.path.insert(0, _REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_WORKDIR = tempfile.mkdtemp(prefix="fuse_smoke_")
-# FORCE scratch paths (not setdefault): the smoke appends synthetic
-# training rows, overwrites the model file, and finally re-saves it
-# with gate_ok=False (the degrade witness) — none of which may ever
-# touch a user's real cache/samples/model (the bench_fusion scratch
-# discipline); the warm-load subprocess inherits the scratch env
-os.environ["MXNET_TUNE_CACHE"] = os.path.join(_WORKDIR, "tuning.json")
-os.environ["MXNET_COST_MODEL_PATH"] = os.path.join(_WORKDIR,
-                                                   "cost_model.json")
+# FORCE a scratch tuning cache (not setdefault): the fused kernels
+# consult ``fusion.blocks``, and a user's real cache must neither steer
+# the parity check nor be touched by it
+os.environ["MXNET_TUNE_CACHE"] = os.path.join(
+    tempfile.mkdtemp(prefix="fuse_smoke_"), "tuning.json")
 os.environ["MXNET_TUNE_FINGERPRINT"] = "fuse_smoke"
-os.environ.setdefault("MXNET_COST_MODEL_MIN_SAMPLES", "6")
 
 import numpy as np  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu import autotune, graph_pass  # noqa: E402
-from mxnet_tpu.autotune import learned  # noqa: E402
+from mxnet_tpu import graph_pass  # noqa: E402
 from mxnet_tpu.config import set_flag  # noqa: E402
 from mxnet_tpu.io import NDArrayIter  # noqa: E402
 from mxnet_tpu.observability import metrics as M  # noqa: E402
@@ -176,76 +163,10 @@ def check_rebind_flat():
     return {"compile_flat": True}
 
 
-def check_cost_model():
-    # a real measured sweep over the fused kernel (interpret mode) —
-    # every timing is a training sample
-    autotune.tune_fused_matmul(128, 128, 256, trials=6, repeats=2)
-    n_samples = learned.sample_count()
-    assert n_samples >= 5, ("sweep recorded too few samples: %d"
-                            % n_samples)
-    # widen the dataset across enough search GROUPS that the holdout
-    # split is genuine (one real sweep is a single group — the gate
-    # rightly refuses to pass on in-sample evidence): deterministic
-    # synthetic searches whose measured time is learnable and whose
-    # analytic cost ranks backward
-    rows = []
-    for g in range(8):
-        for i in range(8):
-            a = 2 ** (i % 4)
-            rows.append({"op": "fusesmoke.knob", "candidate": {"a": a},
-                         "ctx": {"M": 64 * (g + 1)},
-                         "s": 1e-3 * (abs(a - 4) + 1) * (1 + 0.05 * g),
-                         "analytic_s": 1e-3 / a})
-    learned.append_samples(rows)
-    model = learned.train(min_samples=4)
-    assert model is not None, "training did not run"
-    meta = dict(model.meta)
-    assert not meta.get("in_sample"), "holdout split was degenerate"
-    assert meta.get("n_holdout_groups", 0) >= 1
-    assert os.path.exists(learned.model_path()), "model not persisted"
-
-    # second process: warm-load, ZERO re-training, and the ranking
-    # honors the persisted gate verdict
-    code = (
-        "import os, sys, json\n"
-        "sys.path.insert(0, %r)\n"
-        "from mxnet_tpu.autotune import learned\n"
-        "m = learned.load()\n"
-        "assert m is not None, 'warm process failed to load the model'\n"
-        "st = learned.stats()\n"
-        "assert st['trainings'] == 0, 'warm process re-trained'\n"
-        "rm = learned.ranking_model()\n"
-        "gate = bool(m.meta.get('gate_ok'))\n"
-        "assert (rm is not None) == gate, 'ranking ignored the gate'\n"
-        "print(json.dumps({'warm_gate_ok': gate,\n"
-        "                  'warm_trainings': st['trainings']}))\n"
-        % _REPO)
-    env = dict(os.environ)
-    res = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=300)
-    if res.returncode != 0:
-        raise AssertionError("warm-load subprocess failed:\n%s\n%s"
-                             % (res.stdout, res.stderr))
-    warm = json.loads(res.stdout.strip().splitlines()[-1])
-
-    # degrade witness: force the gate off, the next search must rank
-    # analytically
-    model.meta["gate_ok"] = False
-    model.save()
-    learned.reset()
-    assert learned.ranking_model() is None, \
-        "gate-failed model still served for ranking"
-    return {"samples": n_samples,
-            "spearman_learned": meta.get("spearman_learned"),
-            "spearman_analytic": meta.get("spearman_analytic"),
-            "gate_ok": meta.get("gate_ok"), **warm}
-
-
 def main(out_path=None):
     summary = {}
     summary["parity"] = check_regions_and_parity()
     summary["rebind"] = check_rebind_flat()
-    summary["cost_model"] = check_cost_model()
     summary["ok"] = True
     line = json.dumps(summary, sort_keys=True)
     print(line)

@@ -1,8 +1,8 @@
 """HLO layout audit of the fused ResNet train step (VERDICT r4 item 3).
 
 The round-3/4 profile attributed ~3.6 ms/step to layout copies and
-~1.5 ms to maxpool select-and-scatter. This tool compiles the SAME fused
-train step bench.py measures, dumps the optimized HLO, and reports every
+~1.5 ms to maxpool select-and-scatter. This tool compiles the fused
+``ShardedTrainer`` train step, dumps the optimized HLO, and reports every
 transpose/copy/select-and-scatter with operand shapes and an estimated
 byte volume — so layout work is attributable to specific graph sites
 rather than a lump in the profile. Run on the TPU backend for the real
@@ -19,8 +19,7 @@ Usage:
 ``--compare`` prints a per-op regression diff (count and byte deltas,
 positive = B is worse) in the same shape as ``trace_report.py
 --compare`` — the artifact a layout-tuning PR pastes to prove its claim.
-Library use: :func:`run_audit`, :func:`compare_reports` (bench_all.py
---autotune wires the audit artifact through them).
+Library use: :func:`run_audit`, :func:`compare_reports`.
 """
 import argparse
 import json
@@ -67,7 +66,7 @@ def audit(hlo_text):
 def run_audit(layers=50, batch=32, layout="NHWC", dtype="bfloat16",
               cpu=False, dump=None, size=224):
     """Compile the fused ResNet train step and return the layout-op
-    report dict (the CLI's JSON, importable for bench_all.py)."""
+    report dict (the CLI's JSON)."""
     import jax
 
     if cpu:
@@ -161,7 +160,7 @@ def main():
     ap.add_argument("--size", type=int, default=224,
                     help="square image size (CPU smoke runs shrink it)")
     ap.add_argument("--layout", default="NHWC", choices=("NHWC", "NCHW"),
-                    help="NHWC is the bench.py protocol")
+                    help="NHWC is the ResNet cells' layout")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--dump", default=None,

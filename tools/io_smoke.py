@@ -32,7 +32,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def build_rec(path, n=36, size=16, fmt=".jpg"):
     """Synthetic labeled record file — THE tools/ builder (also used by
-    bench_input_pipeline.py and bench_all.py --input-pipeline). Labels
+    bench_input_pipeline.py). Labels
     are the distinct record ids, which the exactness assertions key on."""
     from mxnet_tpu import recordio
 

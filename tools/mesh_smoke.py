@@ -33,9 +33,8 @@ contract from inside the job:
 5. **Clean teardown** — workers exit 0 with no leaked ``mxnet-``
    threads.
 
-Replaces ``tools/two_controller_dryrun.py`` as the multi-host CI leg:
-the dryrun drove ShardedTrainer's jit-sharded step; this drives the
-Module/kvstore training path users actually run.
+The multi-host CI leg: it drives the Module/kvstore training path
+users actually run.
 
 Usage: ``python tools/mesh_smoke.py [summary.json]`` (parent mode);
 ``--worker <outdir>`` is the internal child entry point.

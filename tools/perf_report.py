@@ -4,17 +4,18 @@
 Three report surfaces over the observability.perf layer:
 
 * **Roofline** — per-program achieved-vs-roofline table (analytic FLOPs
-  / HBM bytes at the measured ceilings vs the fenced device time) plus
+  / HBM bytes at the published peaks vs the fenced device time) plus
   the per-op roofline table and the ranked fusion candidates: the op
   sequences whose achieved arithmetic intensity sits furthest under the
   ridge point — the work list for ROADMAP item 3's fusion-region pass.
 * **Waterfall** — the fit loop's per-step wall-time partition
   (data-wait / host dispatch / device compute / kvstore), which sums to
   the step wall exactly by construction.
-* **Ledger** — the append-only ``BENCH_LEDGER.jsonl`` trajectory
-  (one row per ``bench_all.py`` run): last-N table, per-bench deltas
-  against the previous comparable row, and the regression verdict
-  (``--gate`` exits nonzero on a CPU-stable regression — the CI hook).
+* **Ledger** — an append-only ``BENCH_LEDGER.jsonl`` trajectory
+  (rows of ``observability.perf.append_ledger``; nothing in the tree
+  writes them since PR 30, ROADMAP Design): last-N table, per-bench
+  deltas against the previous comparable row, and the regression
+  verdict (``--gate`` exits nonzero on a CPU-stable regression).
 
 Inputs: a flight-recorder dump (``providers.perf``), a ``/statusz``
 capture, or a ledger row (``BENCH_LEDGER.jsonl`` optionally suffixed

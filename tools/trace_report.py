@@ -42,7 +42,7 @@ directory that contains one (searched recursively, newest wins — the
 layout ``jax.profiler`` writes: ``plugins/profile/<run>/*.trace.json.gz``).
 
 Library use: :func:`load_events`, :func:`aggregate`, :func:`report_rows`
-are importable (bench_all.py --telemetry and tests use them).
+are importable (tests use them).
 """
 from __future__ import annotations
 
