@@ -18,12 +18,27 @@ import numpy as np
 from ..observability import counter, device_scope
 from . import moe as _moe
 
-__all__ = ["ATTENTION_KINDS", "FFN_KINDS", "layer_table", "rope_tables",
+__all__ = ["ATTENTION_KINDS", "FFN_KINDS", "KEPT_BY_A_RECOMPUTED_LAYER",
+           "kept", "recomputed", "layer_table", "rope_tables",
            "yarn_inv_freq", "yarn_mscale", "mla_scale", "rms_norm",
            "apply_rope"]
 
 ATTENTION_KINDS = ("mha", "mla")
 FFN_KINDS = ("soft_moe", "swiglu", "moe")
+#: what a layer that the backward pass recomputes (``remat=True``) keeps of
+#: its forward beside its inputs, by the names :func:`kept` gives the values
+#: where they are made: cheap to hold and dear to make again, in the order
+#: of time bought per byte held (docs/lm_layers.md, Recomputation). The
+#: first two are the flash kernel's own (``flash_attention.py``). All else
+#: (norms, k and v, SwiGLU's elementwise part, the routed block) is made
+#: again from these and the layer's input
+KEPT_BY_A_RECOMPUTED_LAYER = (
+    "flash_out", "flash_lse",
+    "router_logits", "route_idx", "route_weight", "moe_plan", "mla_kva",
+    "attn_residual",
+    "mla_q",
+    "ffn_gate", "ffn_up",
+    "shared_gate", "shared_up")
 
 _NORMAL = ("normal", 0.02)
 
@@ -141,6 +156,37 @@ def mla_scale(arch):
     return dq ** -0.5 * m * m
 
 
+# --- what a recomputed layer keeps ---------------------------------------
+_recomputed_layers = []     # one entry a recomputed layer being traced
+
+
+def recomputed(layer):
+    """``layer`` as ``jax.checkpoint`` is to wrap it: while it is traced,
+    the bytes of what it keeps by name go to ``remat.kept_bytes``."""
+    def counted(*args):
+        _recomputed_layers.append(True)
+        try:
+            return layer(*args)
+        finally:
+            _recomputed_layers.pop()
+
+    return counted
+
+
+def kept(value, name):
+    """``value`` under a name of ``KEPT_BY_A_RECOMPUTED_LAYER``: a layer
+    that is recomputed holds it for its backward pass in place of making
+    it again; anywhere else the name is an identity that lowers to
+    nothing."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    if name not in KEPT_BY_A_RECOMPUTED_LAYER:
+        raise ValueError("%r is not kept by a recomputed layer" % (name,))
+    if _recomputed_layers:
+        counter("remat.kept_bytes").inc(value.size * value.dtype.itemsize)
+    return checkpoint_name(value, name)
+
+
 # --- forwards ----------------------------------------------------------
 def mla_attention(params, li, x, cfg, arch, attend):
     """Latent attention's residual branch on (B, T, d); ``attend(q, k, v,
@@ -158,14 +204,15 @@ def mla_attention(params, li, x, cfg, arch, attend):
         h = rms_norm(x, params[p + "attn_norm"], eps)
         q = jnp.einsum("btd,dhe->bhte", h,
                        params[p + "wq"].reshape(-1, H, dn + dr))
-        kva = h @ params[p + "wkva"]
+        kva = kept(h @ params[p + "wkva"], "mla_kva")
         c_kv = rms_norm(kva[..., :r], params[p + "kv_norm"], eps)
         kvb = jnp.einsum("btr,rhe->bhte", c_kv,
                          params[p + "wkvb"].reshape(r, H, dn + dv))
     with device_scope("l%d/attn/rope" % li):
         cos, sin = rope_tables(T, dr, arch["rope"])
-        q = jnp.concatenate(
-            [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
+        q = kept(jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1),
+            "mla_q")                       # as the kernel takes it
         k_rope = apply_rope(kva[..., r:], cos, sin)        # (B, T, dr)
         k = jnp.concatenate(
             [kvb[..., :dn],
@@ -178,10 +225,16 @@ def mla_attention(params, li, x, cfg, arch, attend):
                           params[p + "wo"].reshape(H, dv, -1))
 
 
-def _swiglu(u, wg, wu, wd):
+def _swiglu(u, wg, wu, wd, whose):
+    """``(silu(u wg) * (u wu)) wd`` with the two matmuls' outputs kept
+    under ``whose``'s names. ``silu`` is written out: the jitted
+    ``jax.nn.silu`` of a kept value would hold its own residual, as large
+    as the value, where the elementwise part is to be made again."""
     import jax
 
-    return (jax.nn.silu(u @ wg) * (u @ wu)) @ wd
+    gate = kept(u @ wg, whose + "_gate")
+    return (gate * jax.lax.logistic(gate)
+            * kept(u @ wu, whose + "_up")) @ wd
 
 
 def swiglu_ffn(params, li, x, arch):
@@ -189,7 +242,7 @@ def swiglu_ffn(params, li, x, arch):
     with device_scope("l%d/ffn" % li):
         u = rms_norm(x, params[p + "ffn_norm"], arch["rms_norm_eps"])
         return _swiglu(u, params[p + "wg"], params[p + "wu"],
-                       params[p + "wd"])
+                       params[p + "wd"], "ffn")
 
 
 def moe_ffn(params, li, x, arch):
@@ -207,15 +260,18 @@ def moe_ffn(params, li, x, arch):
     u = rms_norm(x, params[p + "ffn_norm"], arch["rms_norm_eps"])
     rows_in = u.reshape(B * T, d)
     with device_scope("l%d/moe/router" % li):
-        logits = jnp.dot(rows_in.astype(jnp.float32),
-                         params[p + "router"].astype(jnp.float32),
-                         precision="highest")
-        idx, weight = _moe.route(logits, params[p + "router_bias"],
-                                 m["top_k"], m["scale"])
+        logits = kept(jnp.dot(rows_in.astype(jnp.float32),
+                              params[p + "router"].astype(jnp.float32),
+                              precision="highest"), "router_logits")
+        idx = kept(_moe.select(logits, params[p + "router_bias"],
+                               m["top_k"]), "route_idx")
+        weight = kept(_moe.weigh(logits, idx, m["scale"]), "route_weight")
     with device_scope("l%d/moe/dispatch" % li):
         plan = _moe.plan_dispatch(idx, held, tile)
         compact = _moe.plan_dispatch(idx, held, tile, _moe.compact_row_budget(
             B * T, m["top_k"], held[1] - held[0], m["n_experts"], tile))
+        plan, compact = jax.tree_util.tree_map(
+            lambda a: kept(a, "moe_plan"), (plan, compact))
         counter("moe.experts_held").inc(held[1] - held[0])
         counter("moe.row_budget").inc(plan["pair_of_row"].shape[0])
         counter("moe.compact_row_budget").inc(compact["pair_of_row"].shape[0])
@@ -235,7 +291,7 @@ def moe_ffn(params, li, x, arch):
         *(params[p + n] for n in ("moe_wg", "moe_wu", "moe_wd")))
     with device_scope("l%d/moe/shared" % li):
         shared = _swiglu(u, params[p + "shared_wg"], params[p + "shared_wu"],
-                         params[p + "shared_wd"])
+                         params[p + "shared_wd"], "shared")
     with device_scope("l%d/moe/combine" % li):
         return (shared + out.reshape(B, T, d),
                 {"counts": plan["counts"], "live_tiles": plan["n_live"]})

@@ -38,9 +38,9 @@ import functools
 
 from .pallas_common import pallas_call
 
-__all__ = ["route", "plan_dispatch", "dispatch", "combine", "gmm",
-           "in_the_layout_that_fits", "row_budget", "compact_row_budget",
-           "GMM_BLOCK_ROWS"]
+__all__ = ["route", "select", "weigh", "plan_dispatch", "dispatch",
+           "combine", "gmm", "in_the_layout_that_fits", "row_budget",
+           "compact_row_budget", "GMM_BLOCK_ROWS"]
 
 #: rows of a grouped-matmul tile, and what each expert's group is padded
 #: to; interpreted (tests) any multiple of 8 works
@@ -60,16 +60,16 @@ def route(logits, bias, top_k, scale):
     """``logits``: (N, E) float32 router outputs; ``bias``: (E,) the expert
     bias, used for the selection only (no gradient reaches it). Returns
     (idx (N, k) int32, weight (N, k) float32): the k experts with the
-    largest ``sigmoid(z) + b`` and ``scale * sigmoid(z_e) / sum_topk``."""
-    import jax
-    import jax.numpy as jnp
+    largest ``sigmoid(z) + b`` and ``scale * sigmoid(z_e) / sum_topk``.
 
-    score = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(score)
-                           + bias.astype(jnp.float32), top_k)
-    picked = jnp.take_along_axis(score, idx, axis=-1)
-    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weight
+    The selection and the weights are :func:`select` and :func:`weigh`, in
+    turn. A layer that is recomputed calls the two itself and keeps the
+    ids by name between them, so that its backward pass weighs and plans
+    from the kept ids and selects nothing again. (The two stand at the
+    file's end: no line of the kernels below moves for them, and the
+    kernels' serialized bodies carry their line numbers.)"""
+    idx = select(logits, bias, top_k)
+    return idx, weigh(logits, idx, scale)
 
 
 def row_budget(n_tokens, top_k, n_held, block_rows):
@@ -481,3 +481,25 @@ def gmm(x, w, plan, block_rows=GMM_BLOCK_ROWS, interpret=None):
     return _gmm_of(int(block_rows), bool(interpret))(
         x, w, {k: plan[k] for k in ("tile_expert", "tile_first",
                                     "tile_last", "n_live")})
+
+
+# --- the router's two halves ------------------------------------------------
+def select(logits, bias, top_k):
+    """The selection of :func:`route` alone: idx (N, k) int32."""
+    import jax
+    import jax.numpy as jnp
+
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(score)
+                           + bias.astype(jnp.float32), top_k)
+    return idx.astype(jnp.int32)
+
+
+def weigh(logits, idx, scale):
+    """The weights :func:`route` gives the selected experts ``idx``."""
+    import jax
+    import jax.numpy as jnp
+
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    picked = jnp.take_along_axis(score, idx, axis=-1)
+    return scale * picked / jnp.sum(picked, axis=-1, keepdims=True)

@@ -43,8 +43,8 @@ class TransformerParallel:
     which this rank holds the range ``experts_held``, plus shared experts)
     — take their widths from ``arch``, have learned norm weights and a
     learned final norm, and train on dp meshes. ``remat`` recomputes each
-    layer in the backward pass (the flash kernel's output and row
-    statistics are kept, so the kernel's forward is not run twice).
+    layer in the backward pass, but for what it keeps by name
+    (``lm_layers.KEPT_BY_A_RECOMPUTED_LAYER``: the flash output among it).
     """
 
     def __init__(self, mesh, vocab=64, d_model=32, n_heads=4, n_layers=2,
@@ -233,8 +233,8 @@ class TransformerParallel:
                 att = att.transpose(0, 2, 1, 3).reshape(B, T, d)
                 x = x + att @ params[p + "wo"]
         else:
-            x = x + lm_layers.mla_attention(params, li, x, c, self.arch,
-                                            self._attend)
+            x = lm_layers.kept(x + lm_layers.mla_attention(
+                params, li, x, c, self.arch, self._attend), "attn_residual")
         if ffn == "soft_moe":
             # --- MoE FFN: soft top-2-ish gate over ep-sharded experts ---
             with device_scope("l%d/ffn" % li):
@@ -283,10 +283,10 @@ class TransformerParallel:
         for li in range(len(self.layers)):
             if self.remat and collect is None:
                 names = [n for n in params if n.startswith("l%d_" % li)]
-                x = jax.checkpoint(
-                    lambda sub, x, li=li: self._layer(li, sub, x),
+                x = jax.checkpoint(lm_layers.recomputed(
+                    lambda sub, x, li=li: self._layer(li, sub, x)),
                     policy=jax.checkpoint_policies.save_only_these_names(
-                        "flash_out", "flash_lse"))(
+                        *lm_layers.KEPT_BY_A_RECOMPUTED_LAYER))(
                     {n: params[n] for n in names}, x)
             else:
                 x = self._layer(li, params, x, collect)

@@ -295,16 +295,17 @@ def test_grouped_matmul_against_a_per_expert_loop(case, layout, tm):
         assert rel(a, b) < 1e-5, what
 
 
-def _conds_outside_kernels(jaxpr):
-    """``cond`` equations of a jaxpr and of what it calls, the Pallas
-    kernels' bodies (whose ``pl.when`` is one) left out."""
+def _eqns(jaxpr, into=("cond",)):
+    """Every equation of a jaxpr and of what it calls; the Pallas kernels'
+    bodies left out, and the branches of a ``cond`` unless ``into`` has
+    it."""
     from jax.extend import core
 
-    n = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
+        yield eqn
+        if eqn.primitive.name == "pallas_call" or (
+                eqn.primitive.name == "cond" and "cond" not in into):
             continue
-        n += eqn.primitive.name == "cond"
         for sub in jax.tree_util.tree_leaves(
                 list(eqn.params.values()),
                 is_leaf=lambda v: isinstance(v, (core.Jaxpr,
@@ -312,8 +313,13 @@ def _conds_outside_kernels(jaxpr):
             if isinstance(sub, core.ClosedJaxpr):
                 sub = sub.jaxpr
             if isinstance(sub, core.Jaxpr):
-                n += _conds_outside_kernels(sub)
-    return n
+                yield from _eqns(sub, into)
+
+
+def _conds_outside_kernels(jaxpr):
+    """``cond`` equations of a jaxpr and of what it calls, the Pallas
+    kernels' bodies (whose ``pl.when`` is one) left out."""
+    return sum(eqn.primitive.name == "cond" for eqn in _eqns(jaxpr))
 
 
 def test_two_budgets_trace_a_conditional_and_one_budget_none():
@@ -588,3 +594,191 @@ def test_a_model_of_new_kinds_trains_data_parallel_on_two_devices(experts):
     assert stats2[0]["compact_budget"] == moe.compact_row_budget(
         tok.size // 2, 2, 4, experts, moe.GMM_BLOCK_ROWS)
     assert all(s["fits"] for s in stats1 + stats2)
+
+
+# --- (i) what a recomputed layer keeps ---------------------------------------
+#: one layer of each kind a model is recomputed by: (dense layers, experts)
+RECOMPUTED_LAYERS = {"dense_layer": (1, 8), "routed_one_layout": (0, 8),
+                     "routed_two_layouts": (0, 16)}
+
+
+def _one_layer(kind, remat):
+    dense, experts = RECOMPUTED_LAYERS[kind]
+    cfg = small_cfg(1, dense, experts=experts)
+    return cfg, TransformerParallel.from_config(one_chip(), cfg, remat=remat)
+
+
+def _grad_jaxpr(model, params, flash_alone=False, monkeypatch=None):
+    """The jaxpr of the model's loss gradient; ``flash_alone``: with the
+    policy of a layer that keeps the flash kernel's two names alone."""
+    if flash_alone:
+        only = jax.checkpoint_policies.save_only_these_names
+        monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                            lambda *names: only("flash_out", "flash_lse"))
+    tok, tgt = batch(0)
+    return jax.make_jaxpr(jax.grad(model.loss_fn))(params, tok, tgt).jaxpr
+
+
+def _matmuls(jaxpr, lhs, rhs, into=("cond",)):
+    """``dot_general``s of these two operand shapes, either way round."""
+    return sum(eqn.primitive.name == "dot_general"
+               and sorted(tuple(v.aval.shape) for v in eqn.invars)
+               == sorted([tuple(lhs), tuple(rhs)])
+               for eqn in _eqns(jaxpr, into))
+
+
+@pytest.mark.parametrize("kind", list(RECOMPUTED_LAYERS))
+def test_a_recomputed_layer_gives_the_gradients_and_losses_of_one_that_is_not(
+        kind):
+    runs = []
+    for remat in (False, True):
+        cfg, model = _one_layer(kind, remat)
+        params = seeded_params(model, 3)
+        with jax.default_matmul_precision("highest"):
+            loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
+                params, *batch(0))
+            step = model.step_fn(lr=cfg["optimizer"]["learning_rate"])
+            losses = []
+            for i in range(3):
+                params, after = step(params, *model.shard_batch(*batch(i)))
+                losses.append(float(after))
+        runs.append((float(loss), grads, losses))
+    (loss, grads, losses), (loss_r, grads_r, losses_r) = runs
+    assert abs(loss_r - loss) < 2e-6 * loss
+    for name in grads:
+        assert rel(grads_r[name], grads[name]) < 2e-4, name
+    np.testing.assert_allclose(losses_r, losses, rtol=5e-6)
+
+
+@pytest.mark.parametrize("kind", list(RECOMPUTED_LAYERS))
+def test_a_recomputed_layer_holds_its_inputs_and_what_the_constant_names(
+        kind, monkeypatch):
+    """The backward pass's recomputation of the layer takes in: the
+    layer's leaves and input, the output's cotangent, the rotary tables
+    (constants) and the values named by ``kept`` — every one of them, and
+    nothing else the size of an activation. (Off the TPU attention is the
+    dense formula: ``flash_out`` / ``flash_lse`` name nothing here.)"""
+    from collections import Counter
+
+    cfg, model = _one_layer(kind, True)
+    named = []
+
+    def kept(value, name, kept=lm_layers.kept):
+        named.append((name, tuple(value.shape), str(value.dtype)))
+        return kept(value, name)
+
+    monkeypatch.setattr(lm_layers, "kept", kept)
+    jaxpr = _grad_jaxpr(model, seeded_params(model, 3))
+    assert {n for n, _, _ in named} <= set(
+        lm_layers.KEPT_BY_A_RECOMPUTED_LAYER)
+    assert {n for n, _, _ in named} == (
+        {"mla_kva", "mla_q", "attn_residual"}
+        | ({"ffn_gate", "ffn_up"} if kind == "dense_layer" else
+           {"router_logits", "route_idx", "route_weight", "moe_plan",
+            "shared_gate", "shared_up"}))
+    recomputed = [e for e in _eqns(jaxpr) if e.primitive.name == "remat2"]
+    assert len(recomputed) == 1
+    taken = Counter((tuple(v.aval.shape), str(v.aval.dtype))
+                    for v in recomputed[0].invars)
+    floats = Counter({k: n for k, n in taken.items() if k[1] == "float32"})
+    for _, shape, dtype in named:
+        if dtype == "float32":
+            assert floats[(shape, dtype)] > 0, (shape, "is made again")
+            floats[(shape, dtype)] -= 1
+    B, T = batch(0)[0].shape
+    leaves = Counter((tuple(s), "float32") for n, (s, _)
+                     in model.param_table().items() if n.startswith("l0_"))
+    allowed = leaves + Counter({
+        ((B, T, cfg["hidden_size"]), "float32"): 2,            # x, d_out
+        ((T, cfg["qk_rope_head_dim"] // 2), "float32"): 2})    # cos, sin
+    assert not (+floats - allowed), +floats - allowed
+    ints = {shape for _, shape, dtype in named if dtype == "int32"}
+    assert {k[0] for k in taken if k[1] == "int32"} <= ints
+
+
+def test_a_recomputed_dense_layer_projects_q_and_out_once(monkeypatch):
+    """With q (rotated) and the residual after attention kept, the
+    backward pass holds no second ``d -> H dq`` projection and no second
+    out-projection; a layer that keeps the flash names alone runs both
+    again, and so does its kv down-projection."""
+    cfg, model = _one_layer("dense_layer", True)
+    params = seeded_params(model, 3)
+    B, T = batch(0)[0].shape
+    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
+    dq = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    shapes = {"wq": ((B, T, d), (d, H, dq)),
+              "wo": ((B, H, T, cfg["v_head_dim"]),
+                     (H, cfg["v_head_dim"], d)),
+              "wkva": ((B, T, d),
+                       (d, cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])),
+              "wg": ((B, T, d), (d, cfg["intermediate_size"]))}
+    plain = _grad_jaxpr(_one_layer("dense_layer", False)[1], params)
+    kept = _grad_jaxpr(model, params)
+    bare = _grad_jaxpr(model, params, flash_alone=True,
+                       monkeypatch=monkeypatch)
+    for name, (lhs, rhs) in shapes.items():
+        again = 2 if name == "wg" else 1   # wu is alike, and so is the head
+        once = _matmuls(plain, lhs, rhs)
+        assert once == (3 if name == "wg" else 1)
+        assert _matmuls(kept, lhs, rhs) == once, name
+        assert _matmuls(bare, lhs, rhs) == once + again, name
+
+
+@pytest.mark.parametrize("kind", ["routed_one_layout", "routed_two_layouts"])
+def test_a_recomputed_routed_layer_routes_and_plans_once(kind, monkeypatch):
+    """The backward pass of a recomputed routed layer holds no second
+    router matmul, no second top-k and builds no plan again: no sort of the
+    N k pair keys outside the ``cond`` branches (inside them the compact
+    layout sorts its rows, as before)."""
+    cfg, model = _one_layer(kind, True)
+    params = seeded_params(model, 3)
+    tok, _ = batch(0)
+    N, k = tok.size, cfg["num_experts_per_tok"]
+    router = ((N, cfg["hidden_size"]),
+              (cfg["hidden_size"], cfg["published"]["num_experts"]))
+
+    def counts(jaxpr):
+        outside = list(_eqns(jaxpr, into=()))
+        return (_matmuls(jaxpr, *router, into=()),
+                sum(e.primitive.name == "top_k" for e in outside),
+                sum(e.primitive.name == "sort"
+                    and e.invars[0].aval.shape == (N * k,) for e in outside))
+
+    plain = counts(_grad_jaxpr(_one_layer(kind, False)[1], params))
+    assert plain == (1, 1, 2)       # one routing, a plan at either budget
+    kept = counts(_grad_jaxpr(model, params))
+    # (of two plans alike, a recomputed forward traces the one it uses)
+    assert kept == (1, 1, 2 if kind == "routed_two_layouts" else 1)
+    bare = counts(_grad_jaxpr(model, params, flash_alone=True,
+                              monkeypatch=monkeypatch))
+    assert bare == (2, 2, 2 * kept[2])
+
+
+@pytest.mark.parametrize("remat", [True, False],
+                         ids=["recomputed", "not_recomputed"])
+def test_kept_bytes_are_the_bytes_of_what_the_shapes_say(remat):
+    """``remat.kept_bytes``: once a traced recomputed layer, the bytes of
+    every value it keeps by name; nothing where no layer is recomputed."""
+    cfg = small_cfg(3, 1, experts=32)
+    model = TransformerParallel.from_config(one_chip(), cfg, remat=remat)
+    tok, tgt = batch(0)
+    B, T = tok.shape
+    N, k, d = B * T, cfg["num_experts_per_tok"], cfg["hidden_size"]
+    H, r = cfg["num_attention_heads"], cfg["kv_lora_rank"]
+    dn, dr = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    held, tile = cfg["num_experts"], moe.GMM_BLOCK_ROWS
+    attention = N * (r + dr) + N * H * (dn + dr) + N * d   # kva, q, residual
+    plans = sum(N * k + rows + 3 * (rows // tile) + 1 + held for rows in (
+        moe.row_budget(N, k, held, tile),
+        moe.compact_row_budget(N, k, held, 32, tile)))
+    routed = (N * 32 + 2 * N * k + plans               # logits, idx, weight
+              + 2 * N * cfg["moe_intermediate_size"])   # the shared expert
+    want = 4 * (3 * attention + 2 * N * cfg["intermediate_size"] + 2 * routed)
+    obs.set_enabled(True)
+    before = obs.metrics.get_value("remat.kept_bytes", 0)
+    jax.make_jaxpr(jax.grad(model.loss_fn))(seeded_params(model, 3), tok, tgt)
+    moved = obs.metrics.get_value("remat.kept_bytes", 0) - before
+    print("remat.kept_bytes", moved, "of a (3 layer, %d token) step" % N)
+    assert moved == (want if remat else 0)
+    with pytest.raises(ValueError, match="not kept"):
+        lm_layers.kept(jnp.zeros(3), "k_and_v")
