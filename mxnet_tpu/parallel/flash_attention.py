@@ -65,22 +65,35 @@ _FUSED_BWD_VMEM_BUDGET = 40 * 2 ** 20
 #: scoped VMEM handed to Mosaic for every kernel here (its default is
 #: 16 MiB of the v5e's 128 MiB; a 2048-wide forward tile needs 18)
 _VMEM_LIMIT = 64 * 2 ** 20
+#: the same two for a call with fewer k/v heads than query heads: its fused
+#: backward holds the whole group's dq, ``group * T`` rows, and is still
+#: the faster one at 6 and 8 heads a group and T 8192 (24.7 against 36.5 ms
+#: a full layer; chip sweep, PERF.md §6 PR 33) — the v5e has 128 MiB
+_GROUPED_FUSED_BWD_VMEM_BUDGET = 96 * 2 ** 20
+_GROUPED_VMEM_LIMIT = 112 * 2 ** 20
 #: rows of q a forward tile is worked through at a time, and keys a
 #: backward tile ON the diagonal is: the grain at which a tile follows
 #: the diagonal (chip sweep, PERF.md §6 PR 27)
 _FWD_SUB_ROWS = 256
 _BWD_SUB_KEYS = 128
+#: the same bounds under a window: a tile wider than the window computes
+#: mostly dead scores, so the band is walked in tiles of about its width
+#: (chip sweep at window 512, T 8192, PERF.md §6 PR 33)
+_WINDOW_FWD_BLOCK = 512
+_WINDOW_BWD_BLOCK = 512
 
 _NT = (((1,), (1,)), ((), ()))      # a . b^T
 _NN = (((1,), (0,)), ((), ()))      # a . b
 _TN = (((0,), (0,)), ((), ()))      # a^T . b
 
 
-def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
+def _compiler_params(semantics=("parallel", "parallel", "arbitrary"),
+                     grouped=False):
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=_GROUPED_VMEM_LIMIT if grouped else _VMEM_LIMIT)
 
 
 def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
@@ -122,13 +135,15 @@ def _pick_block(T, bound, interpret):
     return aligned_block(T, bound, 1 if interpret else LANES)
 
 
-def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None):
+def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None, group=1):
     """The static rule that picks the backward: one fused pass while the
-    whole head's dq fits the VMEM budget beside the tiles, else two.
-    ``D`` is the q/k width, ``Dv`` the v/o width where it differs."""
+    whole head's dq — of ``group`` query heads to a k/v head, the whole
+    group's — fits the VMEM budget beside the tiles, else two. ``D`` is
+    the q/k width, ``Dv`` the v/o width where it differs."""
     return flash_vmem_bytes(
-        bq, bk, D, itemsize, backward=True, T=T,
-        Dv=Dv) <= _FUSED_BWD_VMEM_BUDGET
+        bq, bk, D, itemsize, backward=True, T=group * T, Dv=Dv) <= (
+        _FUSED_BWD_VMEM_BUDGET if group == 1
+        else _GROUPED_FUSED_BWD_VMEM_BUDGET)
 
 
 def _last_live_k(q_idx, bq, bk):
@@ -141,17 +156,52 @@ def _first_live_q(kv_idx, bq, bk):
     return (kv_idx * bk) // bq
 
 
-def _visible(q0, k0, nq, nk, transposed=False):
-    """Causal visibility (query position >= key position) of the score
-    tile of ``nq`` queries from position ``q0`` and ``nk`` keys from
-    ``k0``: (nq, nk), or (nk, nq) when ``transposed``."""
+def _first_live_k(q_idx, bq, bk, window):
+    """First k block a windowed q block sees (its first row's oldest key,
+    ``window - 1`` positions back)."""
+    import jax.numpy as jnp
+
+    return jnp.maximum(q_idx * bq - (window - 1), 0) // bk
+
+
+def _last_live_q(kv_idx, bq, bk, window, n_q):
+    """Last q block that sees a windowed k block (the last row its last
+    key is in the window of)."""
+    import jax.numpy as jnp
+
+    return jnp.minimum((kv_idx * bk + bk + window - 2) // bq, n_q - 1)
+
+
+def _band_blocks(T, bq, bk, window, of_q):
+    """Blocks of the inner axis a windowed pass walks for each block of
+    its outer one: the most k blocks any q block sees (``of_q``: the
+    forward and the dq pass) or the most q blocks that see any k block
+    (the dk/dv pass). The grid's inner axis is this long, not T's
+    blocks: at a window of 512 in 8,192 a dead grid step costs as much
+    as a live tile's arithmetic (chip sweep, PERF.md section 6 PR 33)."""
+    if of_q:
+        return max(((i + 1) * bq - 1) // bk
+                   - max(i * bq - (window - 1), 0) // bk + 1
+                   for i in range(T // bq))
+    return max(min((j * bk + bk + window - 2) // bq, T // bq - 1)
+               - (j * bk) // bq + 1 for j in range(T // bk))
+
+
+def _visible(q0, k0, nq, nk, transposed=False, window=None):
+    """Causal visibility (query position >= key position, and with a
+    ``window`` the key within the query's last ``window`` positions, the
+    query's own among them) of the score tile of ``nq`` queries from
+    position ``q0`` and ``nk`` keys from ``k0``: (nq, nk), or (nk, nq)
+    when ``transposed``."""
     import jax
     import jax.numpy as jnp
 
     shape, q_dim = ((nk, nq), 1) if transposed else ((nq, nk), 0)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (k_pos > q_pos - window)
 
 
 def _has_interior(T, bq, bk):
@@ -162,13 +212,25 @@ def _has_interior(T, bq, bk):
     return (T // bq - 1) * bq >= bk - 1
 
 
-def _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, tile):
+def _has_window_interior(T, bq, bk, window):
+    """The same under a ``window``: is there a tile wholly between the
+    diagonal and the band's far edge? (Tiles as wide as the window have
+    none: every live tile is crossed by one edge or the other.)"""
+    return any(k0 + bk - 1 <= q0 and k0 > q0 + bq - 1 - window
+               for q0 in range(0, T, bq) for k0 in range(0, T, bk))
+
+
+def _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, tile,
+                   window=None, in_range=True):
     """Run ``tile(masked)`` on this grid step's score tile if it is live.
     A causal tile is dead above the diagonal (skipped: its index maps
     are clamped, so it costs no DMA either), ``masked`` where the
     diagonal crosses it, and mask-free below — no iota, compare or
     select on interior tiles (``interior``: whether the grid has any,
-    :func:`_has_interior`)."""
+    :func:`_has_interior`). Under a ``window`` the live tiles are a band:
+    a tile is dead past the band's far edge too, and ``masked`` where
+    either edge crosses it; ``in_range`` is whether the step's block
+    exists at all (a band's last steps may point past the sequence)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -176,6 +238,17 @@ def _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, tile):
         tile(False)
         return
     first_q, first_k = q_idx * bq, kv_idx * bk
+    if window is not None:
+        live = ((first_k <= first_q + bq - 1)
+                & (first_k + bk - 1 > first_q - window) & in_range)
+        if interior:
+            clean = ((first_k + bk - 1 <= first_q)
+                     & (first_k > first_q + bq - 1 - window))
+            pl.when(live & clean)(lambda: tile(False))
+            pl.when(live & jnp.logical_not(clean))(lambda: tile(True))
+        else:
+            pl.when(live)(lambda: tile(True))
+        return
     crosses = first_k + bk - 1 > first_q
     if interior:
         pl.when(jnp.logical_not(crosses))(lambda: tile(False))
@@ -193,7 +266,7 @@ def _sub_block(b, want, interpret):
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-            scale, causal, interior, sub):
+            scale, causal, interior, sub, window=None):
     """One (batch*head, q_block, k_block) forward grid step.
 
     Dots take q, k, v as loaded (bf16 operands, fp32 accumulation; fp32
@@ -205,18 +278,24 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     tile (its max), and no statistic lives in a one-lane column. No row
     is ever fully masked within one call — causal or not, every query
     sees a key in its first live tile — so m is finite from that tile on
-    and the -inf mask needs no guard."""
+    and the -inf mask needs no guard. Under a ``window`` that no longer
+    holds (the first live tile of a q block may lie wholly before a later
+    row's window), so there the exponentials take a running max of 0 in
+    place of -inf: the row's sums stay 0 until its first visible key."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    kv_idx = pl.program_id(2)
+    step = pl.program_id(2)
     q_idx = pl.program_id(1)
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
     W = m_ref.shape[1]
+    # under a window the k axis walks the q block's band alone
+    kv_idx = (step if window is None
+              else _first_live_k(q_idx, bq, bk, window) + step)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -226,7 +305,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         # the tile is worked through ``sub`` rows of q at a time. On a
         # tile that sits ON the diagonal (bq == bk) a chunk's keys end
         # with its own last row: dead sub-blocks are not computed
-        on_diagonal = masked and bq == bk
+        on_diagonal = masked and bq == bk and window is None
         for first in range(0, bq, sub):
             rows = slice(first, first + sub)
             nk = first + sub if on_diagonal else bk
@@ -235,13 +314,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 q, k, _NT, preferred_element_type=jnp.float32) * scale
             if masked:
                 s = jnp.where(_visible(q_idx * bq + first, kv_idx * bk,
-                                       sub, nk), s, -jnp.inf)
+                                       sub, nk, window=window), s, -jnp.inf)
             cols = [s[:, j:j + W] for j in range(0, nk, W)]
             m_prev = m_ref[rows, :]
             m_new = jnp.maximum(m_prev, jnp.max(
                 functools.reduce(jnp.maximum, cols), axis=1, keepdims=True))
-            ps = [jnp.exp(col - m_new) for col in cols]
-            corr = jnp.exp(m_prev - m_new)
+            m_exp = (m_new if window is None
+                     else jnp.where(jnp.isneginf(m_new), 0.0, m_new))
+            ps = [jnp.exp(col - m_exp) for col in cols]
+            corr = jnp.exp(m_prev - m_exp)
             l_ref[rows, :] = (l_ref[rows, :] * corr
                               + functools.reduce(jnp.add, ps))
             p = ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=1)
@@ -252,9 +333,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                                       preferred_element_type=jnp.float32))
             m_ref[rows, :] = m_new
 
-    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile, window)
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         l = jnp.sum(l_ref[...], axis=1, keepdims=True)
         o_ref[0] = (acc_ref[...] * (1.0 / l)).astype(o_ref.dtype)
@@ -264,7 +345,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, scale, causal, interior):
+                   dq_ref, acc_ref, *, scale, causal, interior, window=None):
     """dq pass of the two-pass backward: grid (batch*head, q_block,
     k_block); k is the sequential axis, dq accumulates in fp32 scratch
     across it.
@@ -279,12 +360,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    kv_idx = pl.program_id(2)
+    step = pl.program_id(2)
     q_idx = pl.program_id(1)
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
+    kv_idx = (step if window is None
+              else _first_live_k(q_idx, bq, bk, window) + step)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -293,8 +376,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q_ref[0], k, _NT,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            s = jnp.where(_visible(q_idx * bq, kv_idx * bk, bq, bk), s,
-                          -jnp.inf)
+            s = jnp.where(_visible(q_idx * bq, kv_idx * bk, bq, bk,
+                                   window=window), s, -jnp.inf)
         p = jnp.exp(s - jnp.expand_dims(lse_ref[0, 0], -1))
         dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
                                  preferred_element_type=jnp.float32)
@@ -302,9 +385,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         acc_ref[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile, window)
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         # ds/dq_i = scale * sum_j ds_ij k_j
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
@@ -312,7 +395,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *rest, scale, causal, interior, fused,
-                    sub):
+                    sub, window=None, group=1, n_q=None, n_band=None):
     """dk/dv pass — and, ``fused``, the whole backward: grid (batch*head,
     k_block, q_block); q is the sequential axis, dk and dv accumulate in
     fp32 scratch across it.
@@ -325,7 +408,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     head's dq (T, D) — zeroed at the head's first grid step, cast into
     the resident (1, T, D) output block at its last — so s, p, dp and ds
     are computed once: 5 matmuls and one exp pass a tile where the two
-    passes spend 7 and two."""
+    passes spend 7 and two.
+
+    With ``group`` query heads to a k/v head the batch axis counts k/v
+    heads and the sequential axis runs over the group's heads, each
+    head's ``n_q`` q blocks in turn: one k/v tile stays in VMEM for the
+    whole group, and dk and dv are summed over it in the same scratch.
+    Fused, the dq scratch and block then hold the group's heads one
+    after another, (group * T, D). Under a ``window`` a head's part of
+    the sequential axis is the ``n_band`` q blocks from the k block's
+    first live one on, not all ``n_q``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -334,19 +426,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref, dk_acc, dv_acc, dq_acc = rest
     else:
         dk_acc, dv_acc = rest
-    q_idx = pl.program_id(2)
+    # the sequential axis' step, and the q block of its head it stands for
+    step = pl.program_id(2)
     kv_idx = pl.program_id(1)
     last_q = pl.num_programs(2) - 1
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
+    if window is None:
+        q_idx = step if group == 1 else step % n_q
+        dq_block = step     # of the (group * T, D) dq: head * n_q + q_idx
+    else:
+        q_idx = _first_live_q(kv_idx, bq, bk) + step % n_band
+        dq_block = (step // n_band) * n_q + q_idx
 
-    @pl.when(q_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     if fused:
-        @pl.when((q_idx == 0) & (kv_idx == 0))
+        @pl.when((step == 0) & (kv_idx == 0))
         def _init_dq():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -361,7 +460,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s_t = jnp.where(
                 _visible(q_idx * bq + first, kv_idx * bk + keys.start,
                          bq - first, keys.stop - keys.start,
-                         transposed=True), s_t, -jnp.inf)
+                         transposed=True, window=window), s_t, -jnp.inf)
         p_t = jnp.exp(s_t - lse_ref[0, :, first:])            # (1, .) row
         dp_t = jax.lax.dot_general(v_ref[0, keys, :], do, _NT,
                                    preferred_element_type=jnp.float32)
@@ -373,7 +472,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[keys, :] += jax.lax.dot_general(
             ds_t, q, _NN, preferred_element_type=jnp.float32)
         if fused:
-            rows = pl.ds(pl.multiple_of(q_idx * bq, bq) + first, bq - first)
+            rows = pl.ds(pl.multiple_of(dq_block * bq, bq) + first,
+                         bq - first)
             dq_acc[rows, :] += jax.lax.dot_general(
                 ds_t, k, _TN, preferred_element_type=jnp.float32)
 
@@ -381,32 +481,42 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # on a tile that sits ON the diagonal (bq == bk) a key sub-chunk
         # is seen only from its own first row on: the dead sub-blocks
         # are not computed
-        if masked and bq == bk:
+        if masked and bq == bk and window is None:
             for first in range(0, bk, sub):
                 _chunk(True, slice(first, first + sub), first)
         else:
             _chunk(masked, slice(0, bk), 0)
 
-    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile)
+    _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile, window,
+                   in_range=True if window is None else q_idx < n_q)
 
-    @pl.when(q_idx == last_q)
+    @pl.when(step == last_q)
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     if fused:
-        @pl.when((q_idx == last_q) & (kv_idx == pl.num_programs(1) - 1))
+        @pl.when((step == last_q) & (kv_idx == pl.num_programs(1) - 1))
         def _finish_dq():
             dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+def _kernel_name(window, part):
+    """Kernel names as a trace shows them; a windowed call's hold
+    ``window``, so that it is told from a full layer's."""
+    return "flash_attention_%s%s" % ("window_" if window else "", part)
+
+
 @functools.lru_cache(maxsize=64)
-def _forward_call(causal, scale, block_q, block_k, interpret):
+def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
+                  group=1):
     """The jitted forward ``pallas_call`` of one static configuration, on
     (B*H, T, D) operands. ONE function object a configuration: every
     layer of a model that calls it on the same shapes shares one trace
     and one lowering of the kernel body, where a step of 24 layers would
-    lower 24 identical Mosaic bodies — set-up time, warm cache or cold."""
+    lower 24 identical Mosaic bodies — set-up time, warm cache or cold.
+    With ``group`` query heads to a k/v head, k and v are (B*H/group, T,
+    .) and a q head's index map names its group's k/v blocks."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -418,9 +528,11 @@ def _forward_call(causal, scale, block_q, block_k, interpret):
         # a dead causal step names the block of the last live one: no DMA
         # is issued for a block index that did not change
         def kv_map(b, i, j):
+            if window is not None:      # the band's j-th block
+                j = j + _first_live_k(i, block_q, block_k, window)
             if causal:
                 j = jnp.minimum(j, _last_live_k(i, block_q, block_k))
-            return (b, j, 0)
+            return (b if group == 1 else b // group, j, 0)
 
         sub = _sub_block(block_q, _FWD_SUB_ROWS, interpret)
         # width of the lane-dense statistics: the 128 lanes, which divide
@@ -429,10 +541,14 @@ def _forward_call(causal, scale, block_q, block_k, interpret):
         # what divides them
         lanes = (math.gcd(LANES, block_k, sub)
                  if interpret or block_k % LANES == 0 else block_k)
+        interior = (_has_interior(T, block_q, block_k) if window is None
+                    else _has_window_interior(T, block_q, block_k, window))
         return pallas_call(
             functools.partial(_kernel, scale=scale, causal=causal, sub=sub,
-                              interior=_has_interior(T, block_q, block_k)),
-            grid=(BH, T // block_q, T // block_k),
+                              interior=interior, window=window),
+            grid=(BH, T // block_q,
+                  T // block_k if window is None else _band_blocks(
+                      T, block_q, block_k, window, of_q=True)),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_k, D), kv_map),
@@ -452,19 +568,23 @@ def _forward_call(causal, scale, block_q, block_k, interpret):
                 pltpu.VMEM((block_q, lanes), jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_compiler_params(),
-            name="flash_attention_fwd",
+            compiler_params=_compiler_params(grouped=group > 1),
+            name=_kernel_name(window, "fwd"),
         )(qf, kf, vf)
 
     return jax.jit(forward)
 
 
 @functools.lru_cache(maxsize=64)
-def _backward_call(causal, scale, bq, bk, fused, interpret):
+def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
+                   group=1):
     """The jitted backward of one static configuration — the fused pass,
     or the dk/dv and dq passes — on (B*H, T, D) operands and (B*H, 1, T)
     lse/delta rows; returns (dq, dk, dv). One function object a
-    configuration, as :func:`_forward_call`."""
+    configuration, as :func:`_forward_call`. With ``group`` query heads
+    to a k/v head, k, v, dk and dv are (B*H/group, T, .): the dk/dv
+    pass's batch axis counts k/v heads and its sequential axis walks the
+    group's heads one after another."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -474,38 +594,62 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
         qf, kf, vf = operands[:3]
         BH, T, D = qf.shape
         Dv = vf.shape[-1]       # v, o, do and dv may be narrower
+        n_q = T // bq
+        interior = (_has_interior(T, bq, bk) if window is None
+                    else _has_window_interior(T, bq, bk, window))
+        # q blocks a head takes of the dk/dv pass's sequential axis: all
+        # of them, or under a window the band of a k block
+        n_band = n_q if window is None else _band_blocks(T, bq, bk, window,
+                                                         of_q=False)
         # grid (b, k block, q block): k/v and their gradients follow dim
         # 1, q/do/rows dim 2 — clamped on dead causal steps, which come
-        # first in the scan, to the first live q block
+        # first in the scan, to the first live q block. Under a window
+        # dim 2 counts from the k block's first live q block on, clamped
+        # past the band to its last. A group's heads follow one another
+        # on dim 2, n_band blocks each
         def q_block(j, i):
-            return jnp.maximum(i, _first_live_q(j, bq, bk)) if causal else i
+            if group > 1:
+                i = i % n_band
+            if window is not None:
+                return jnp.minimum(i + _first_live_q(j, bq, bk),
+                                   _last_live_q(j, bq, bk, window, n_q))
+            if causal:
+                i = jnp.maximum(i, _first_live_q(j, bq, bk))
+            return i
+
+        def q_head(b, i):
+            return b if group == 1 else b * group + i // n_band
 
         def q_spec(width):
-            return pl.BlockSpec((1, bq, width),
-                                lambda b, j, i: (b, q_block(j, i), 0))
+            return pl.BlockSpec(
+                (1, bq, width),
+                lambda b, j, i: (q_head(b, i), q_block(j, i), 0))
 
         def k_spec(width):
             return pl.BlockSpec((1, bk, width), lambda b, j, i: (b, j, 0))
 
-        row_spec = pl.BlockSpec((1, 1, bq),
-                                lambda b, j, i: (b, 0, q_block(j, i)))
+        row_spec = pl.BlockSpec(
+            (1, 1, bq), lambda b, j, i: (q_head(b, i), 0, q_block(j, i)))
         out_specs = [k_spec(D), k_spec(Dv)]
-        out_shape = [jax.ShapeDtypeStruct((BH, T, D), kf.dtype),
-                     jax.ShapeDtypeStruct((BH, T, Dv), vf.dtype)]
+        out_shape = [jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+                     jax.ShapeDtypeStruct(vf.shape, vf.dtype)]
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
                    pltpu.VMEM((bk, Dv), jnp.float32)]
         if fused:
-            # dq is one (1, T, D) block per head, resident across both
-            # inner axes — which makes the k axis sequential too
-            out_specs.append(pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((BH, T, D), qf.dtype))
-            scratch.append(pltpu.VMEM((T, D), jnp.float32))
+            # dq is one (1, group * T, D) block per k/v head, resident
+            # across both inner axes — which makes the k axis sequential
+            # too
+            out_specs.append(pl.BlockSpec((1, group * T, D),
+                                          lambda b, j, i: (b, 0, 0)))
+            out_shape.append(jax.ShapeDtypeStruct(
+                (BH // group, group * T, D), qf.dtype))
+            scratch.append(pltpu.VMEM((group * T, D), jnp.float32))
         outs = pallas_call(
             functools.partial(
                 _bwd_dkv_kernel, scale=scale, causal=causal, fused=fused,
-                interior=_has_interior(T, bq, bk),
-                sub=_sub_block(bk, _BWD_SUB_KEYS, interpret)),
-            grid=(BH, T // bk, T // bq),
+                interior=interior, window=window, group=group, n_q=n_q,
+                n_band=n_band, sub=_sub_block(bk, _BWD_SUB_KEYS, interpret)),
+            grid=(BH // group, T // bk, group * n_band),
             in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
                       row_spec, row_spec],
             out_specs=out_specs,
@@ -514,39 +658,46 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
             interpret=interpret,
             compiler_params=_compiler_params(
                 ("parallel", "arbitrary", "arbitrary") if fused
-                else ("parallel", "parallel", "arbitrary")),
-            name="flash_attention_bwd_dqkv" if fused
-            else "flash_attention_bwd_dkv",
+                else ("parallel", "parallel", "arbitrary"),
+                grouped=group > 1),
+            name=_kernel_name(window, "bwd_dqkv" if fused else "bwd_dkv"),
         )(*operands)
         if fused:
             dk, dv, dq = outs
-            return dq, dk, dv
+            return (dq if group == 1 else dq.reshape(qf.shape)), dk, dv
         dk, dv = outs
         # dq pass grid is (b, q block, k block): k/v follow dim 2,
-        # clamped on dead steps to the last live k block
+        # clamped on dead steps to the last (first) live k block
         def k_block(i, j):
-            return jnp.minimum(j, _last_live_k(i, bq, bk)) if causal else j
+            if window is not None:      # the band's j-th block
+                j = j + _first_live_k(i, bq, bk, window)
+            if causal:
+                j = jnp.minimum(j, _last_live_k(i, bq, bk))
+            return j
 
         def q_spec(width):
             return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
 
         def k_spec(width):
-            return pl.BlockSpec((1, bk, width),
-                                lambda b, i, j: (b, k_block(i, j), 0))
+            return pl.BlockSpec(
+                (1, bk, width),
+                lambda b, i, j: (b if group == 1 else b // group,
+                                 k_block(i, j), 0))
 
         row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
         dq = pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                              interior=_has_interior(T, bq, bk)),
-            grid=(BH, T // bq, T // bk),
+                              interior=interior, window=window),
+            grid=(BH, T // bq, T // bk if window is None else _band_blocks(
+                T, bq, bk, window, of_q=True)),
             in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
                       row_spec, row_spec],
             out_specs=q_spec(D),
             out_shape=jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interpret,
-            compiler_params=_compiler_params(),
-            name="flash_attention_bwd_dq",
+            compiler_params=_compiler_params(grouped=group > 1),
+            name=_kernel_name(window, "bwd_dq"),
         )(*operands)
         return dq, dk, dv
 
@@ -555,19 +706,30 @@ def _backward_call(causal, scale, bq, bk, fused, interpret):
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, block_q_bwd=None, block_k_bwd=None,
-                    interpret=False, return_lse=False):
-    """Blocked attention; q/k: (batch, heads, T, d), v: (batch, heads, T,
-    dv) — ``dv`` may differ from ``d`` (latent attention's 192/128), and
-    the output then has v's width; nothing is padded in HBM.
+                    interpret=False, return_lse=False, window=None):
+    """Blocked attention; q: (batch, heads, T, d), k: (batch, kv_heads, T,
+    d), v: (batch, kv_heads, T, dv) — ``dv`` may differ from ``d``
+    (latent attention's 192/128), and the output then has v's width;
+    nothing is padded in HBM. ``kv_heads`` divides ``heads``: query head
+    ``h`` attends k/v head ``h // (heads // kv_heads)`` (grouped-query
+    attention), and no copy of k or v per query head is made — the index
+    maps name the group's k/v blocks, and the dk/dv pass sums over the
+    group in VMEM.
+
+    ``window`` (with ``causal``): query ``i`` sees keys ``i - window <
+    j <= i``, its own among them. The kernels then walk the band of live
+    tiles alone, carry ``window`` in their names, and take the window's
+    tile constants; a window at or past ``T`` is the causal mask.
 
     Block arguments are upper bounds; the largest TPU-legal tiles at or
     below them are used (``_pick_block``: the whole sequence or a
     multiple of 128 dividing T when compiled, any divisor interpreted; a
-    T with no such tile lowers the dense XLA formula). An unset bound
-    is this module's constant: 2048/2048 forward, 1024/1024 backward
-    (the v5e sweep of PR 27, PERF.md section 6). Differentiable: the vjp
-    runs the tiled recompute backward above — fused, or in two passes,
-    by ``_bwd_is_fused``.
+    T with no such tile lowers the dense XLA formula with the same
+    mask). An unset bound is this module's constant: 2048/2048 forward,
+    1024/1024 backward (the v5e sweep of PR 27, PERF.md section 6), and
+    under a window ``_WINDOW_FWD_BLOCK`` / ``_WINDOW_BWD_BLOCK``.
+    Differentiable: the vjp runs the tiled recompute backward above —
+    fused, or in two passes, by ``_bwd_is_fused``.
 
     With ``return_lse`` the per-row logsumexp of the scaled scores is
     returned alongside the output, shape (batch, heads, T) fp32 — the
@@ -581,28 +743,46 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     from ..observability import counter
 
     B, H, T, D = q.shape
+    Hkv = k.shape[1]
     Dv = v.shape[-1]
     if k.shape[-1] != D:
         raise ValueError("flash_attention: q and k widths differ (%d, %d)"
                          % (D, k.shape[-1]))
+    if H % Hkv or v.shape[1] != Hkv:
+        raise ValueError("flash_attention: %d query heads over %d k and %d "
+                         "v heads" % (H, Hkv, v.shape[1]))
+    group = H // Hkv
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: a window needs causal=True")
+        window = int(window) if int(window) < T else None
+    if window is not None:
+        counter("flash_attention.windowed").inc()
+    if group > 1:
+        counter("flash_attention.kv_group").inc(group)
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
     # block sizes are upper bounds; _pick_block turns each into a tile
     # the TPU lowering accepts or declines (no 128-multiple divisor
     # compiled, or prime-ish T with only tiny divisors) — a declined
     # shape lowers the XLA formula instead, a static decision.
-    block_q = _pick_block(T, int(block_q or _FWD_BLOCK), interpret)
-    block_k = _pick_block(T, int(block_k or _FWD_BLOCK), interpret)
-    block_q_bwd = _pick_block(T, int(block_q_bwd or _BWD_BLOCK), interpret)
-    block_k_bwd = _pick_block(T, int(block_k_bwd or _BWD_BLOCK), interpret)
+    fwd_bound, bwd_bound = ((_FWD_BLOCK, _BWD_BLOCK) if window is None
+                            else (_WINDOW_FWD_BLOCK, _WINDOW_BWD_BLOCK))
+    block_q = _pick_block(T, int(block_q or fwd_bound), interpret)
+    block_k = _pick_block(T, int(block_k or fwd_bound), interpret)
+    block_q_bwd = _pick_block(T, int(block_q_bwd or bwd_bound), interpret)
+    block_k_bwd = _pick_block(T, int(block_k_bwd or bwd_bound), interpret)
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
-        out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale)
+        out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale,
+                                   window=window)
         return (out, lse) if return_lse else out
+    def _flat(a):
+        return a.reshape(-1, T, a.shape[-1])
 
     def _flash_fwd_impl(q, k, v):
         if Dv != D:
             counter("flash_attention.dqk_ne_dv").inc()
-        out, lse = _forward_call(causal, scale, block_q, block_k, interpret)(
-            *(a.reshape(B * H, T, a.shape[-1]) for a in (q, k, v)))
+        out, lse = _forward_call(causal, scale, block_q, block_k, interpret,
+                                 window, group)(*(_flat(a) for a in (q, k, v)))
         # named for a recomputing caller's policy: kept, the backward
         # pass does not run this kernel again (an identity otherwise)
         return (checkpoint_name(out.reshape(B, H, T, Dv), "flash_out"),
@@ -616,7 +796,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
         fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd,
-                              q.dtype.itemsize, Dv=Dv)
+                              q.dtype.itemsize, Dv=Dv, group=group)
         counter("flash_attention.bwd_fused" if fused
                 else "flash_attention.bwd_two_pass").inc()
         # per-row residuals ride as lane-dense rows of (B*H, 1, T)
@@ -624,10 +804,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         # TPU block shape. The dq pass turns its row into a column
         # in-kernel; the dk/dv and fused passes use it as a row
         grads = _backward_call(causal, scale, block_q_bwd, block_k_bwd,
-                               fused, interpret)(
-            *(a.reshape(B * H, T, a.shape[-1]) for a in (q, k, v, do)),
+                               fused, interpret, window, group)(
+            *(_flat(a) for a in (q, k, v, do)),
             lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T))
-        return tuple(g.reshape(B, H, T, g.shape[-1]) for g in grads)
+        return tuple(g.reshape(a.shape) for g, a in zip(grads, (q, k, v)))
 
     @jax.custom_vjp
     def _flash(q, k, v):
@@ -816,19 +996,25 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, lengths,
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-def _dense_with_lse(q, k, v, causal=False, scale=None):
+def _dense_with_lse(q, k, v, causal=False, scale=None, window=None):
     """XLA reference returning (out, lse) — the lowering of a T with no
-    legal tile, and the tests' oracle."""
+    legal tile, and the tests' oracle. Fewer k/v heads than query heads
+    are repeated to them; ``window`` as :func:`flash_attention` has it."""
     import jax
     import jax.numpy as jnp
 
     d = q.shape[-1]
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(d))
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         precision="highest").astype(jnp.float32) * scale
     if causal:
         T = q.shape[2]
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, T), bool), -int(window))
         scores = jnp.where(mask, scores, -jnp.inf)
     lse = jax.nn.logsumexp(scores, axis=-1)
     w = jnp.exp(scores - jnp.where(jnp.isneginf(lse), 0.0, lse)[..., None])

@@ -1,12 +1,16 @@
 """Layer kinds a :class:`~.transformer.TransformerParallel` can be built
-from beside its first block: latent attention (``"mla"``), a SwiGLU FFN
-(``"swiglu"``) and a routed expert layer that is told which experts it
-holds (``"moe"``). Each kind is three functions of the architecture's
-widths: its leaves (name -> (shape, init)), and its forward on (B, T, d).
+from beside its first block: latent attention (``"mla"``), grouped-query
+attention whose heads, window and rotary tables are the layer's own
+(``"gqa"``), a SwiGLU FFN (``"swiglu"``) and a routed expert layer that is
+told which experts it holds (``"moe"``). Each kind is three functions of
+the architecture's widths: its leaves (name -> (shape, init)), and its
+forward on (B, T, d).
 
 Every norm here is ``x * rsqrt(mean(x^2) + eps) * w`` with a learned
 ``w``, computed in float32. Positions are rotary (``rope_tables``), in the
-``deepseek_yarn`` scaling where the architecture states one; the pairing
+yarn scaling where the architecture states one (by ``mscale`` ratios as
+DeepSeek writes it, or by an ``attention_factor``), on all of a head's
+channels or on its first ``partial_rotary_factor`` of them; the pairing
 is half-split (``rotate_half``). docs/lm_layers.md has the equations.
 """
 from __future__ import annotations
@@ -21,9 +25,9 @@ from . import moe as _moe
 __all__ = ["ATTENTION_KINDS", "FFN_KINDS", "KEPT_BY_A_RECOMPUTED_LAYER",
            "kept", "recomputed", "layer_table", "rope_tables",
            "yarn_inv_freq", "yarn_mscale", "mla_scale", "rms_norm",
-           "apply_rope"]
+           "apply_rope", "gqa_attention"]
 
-ATTENTION_KINDS = ("mha", "mla")
+ATTENTION_KINDS = ("mha", "mla", "gqa")
 FFN_KINDS = ("soft_moe", "swiglu", "moe")
 #: what a layer that the backward pass recomputes (``remat=True``) keeps of
 #: its forward beside its inputs, by the names :func:`kept` gives the values
@@ -35,8 +39,9 @@ FFN_KINDS = ("soft_moe", "swiglu", "moe")
 KEPT_BY_A_RECOMPUTED_LAYER = (
     "flash_out", "flash_lse",
     "router_logits", "route_idx", "route_weight", "moe_plan", "mla_kva",
+    "gqa_gate", "gqa_k", "gqa_v",
     "attn_residual",
-    "mla_q",
+    "mla_q", "gqa_q",
     "ffn_gate", "ffn_up",
     "shared_gate", "shared_up")
 
@@ -61,6 +66,16 @@ def layer_table(li, kinds, cfg, arch):
         t[p + "wkvb"] = ((r, H * (arch["qk_nope_head_dim"]
                                   + arch["v_head_dim"])), _NORMAL)
         t[p + "wo"] = ((H * arch["v_head_dim"], d), _NORMAL)
+    if attn == "gqa":
+        g = arch["gqa"]
+        H, hd = g["layers"][li]["n_heads"], g["head_dim"]
+        t[p + "attn_norm"] = ((d,), 1.0)
+        t[p + "wq"] = ((d, H * hd), _NORMAL)
+        t[p + "wk"] = ((d, g["n_kv_heads"] * hd), _NORMAL)
+        t[p + "wv"] = ((d, g["n_kv_heads"] * hd), _NORMAL)
+        if g["gate"]:
+            t[p + "wgate"] = ((d, H), _NORMAL)
+        t[p + "wo"] = ((H * hd, d), _NORMAL)
     if ffn == "swiglu":
         f = cfg["d_ff"]
         t[p + "ffn_norm"] = ((d,), 1.0)
@@ -70,13 +85,15 @@ def layer_table(li, kinds, cfg, arch):
     if ffn == "moe":
         m = arch["moe"]
         f = m["d_expert"]
+        fs = m.get("d_shared", f * m["n_shared"])   # the shared width
         lo, hi = m["experts_held"]
         t[p + "ffn_norm"] = ((d,), 1.0)
         t[p + "router"] = ((d, m["n_experts"]), _NORMAL)
-        t[p + "router_bias"] = ((m["n_experts"],), _NORMAL)
-        t[p + "shared_wg"] = ((d, f * m["n_shared"]), _NORMAL)
-        t[p + "shared_wu"] = ((d, f * m["n_shared"]), _NORMAL)
-        t[p + "shared_wd"] = ((f * m["n_shared"], d), _NORMAL)
+        if m.get("router_bias", True):
+            t[p + "router_bias"] = ((m["n_experts"],), _NORMAL)
+        t[p + "shared_wg"] = ((d, fs), _NORMAL)
+        t[p + "shared_wu"] = ((d, fs), _NORMAL)
+        t[p + "shared_wd"] = ((fs, d), _NORMAL)
         t[p + "moe_wg"] = ((hi - lo, d, f), _NORMAL)
         t[p + "moe_wu"] = ((hi - lo, d, f), _NORMAL)
         t[p + "moe_wd"] = ((hi - lo, f, d), _NORMAL)
@@ -121,14 +138,18 @@ def yarn_inv_freq(dim, rope):
 
 
 def rope_tables(T, dim, rope):
-    """(cos, sin), each (T, dim/2) float32, scaled by the yarn ratio
+    """(cos, sin), each (T, dim/2) float32, scaled by the architecture's
+    ``attention_factor`` where it states one, else by the yarn ratio
     ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
     angle = np.arange(T, dtype=np.float64)[:, None] * yarn_inv_freq(
         dim, rope)[None, :]
     factor = float(rope.get("factor", 1.0))
-    ratio = (yarn_mscale(factor, rope.get("mscale", 1.0))
-             / yarn_mscale(factor, rope.get("mscale_all_dim", 0.0) or 0.0)
-             if factor > 1 else 1.0)
+    if "attention_factor" in rope:
+        ratio = float(rope["attention_factor"])
+    else:
+        ratio = (yarn_mscale(factor, rope.get("mscale", 1.0))
+                 / yarn_mscale(factor, rope.get("mscale_all_dim", 0.0) or 0.0)
+                 if factor > 1 else 1.0)
     return ((np.cos(angle) * ratio).astype(np.float32),
             (np.sin(angle) * ratio).astype(np.float32))
 
@@ -225,6 +246,53 @@ def mla_attention(params, li, x, cfg, arch, attend):
                           params[p + "wo"].reshape(H, dv, -1))
 
 
+def gqa_attention(params, li, x, arch, attend):
+    """Grouped-query attention's residual branch on (B, T, d): layer
+    ``li``'s own head count over the model's k/v heads, its rotary tables
+    on the first ``partial_rotary_factor`` of each head's channels, its
+    window (None: causal), and one sigmoid gate a query head on the
+    head's output before the out-projection. ``attend(q, k, v, scale,
+    window)`` takes q (B, H, T, hd) and k, v (B, Hkv, T, hd)."""
+    import jax
+    import jax.numpy as jnp
+
+    p = "l%d_" % li
+    g = arch["gqa"]
+    mine = g["layers"][li]
+    H, Hkv, hd = mine["n_heads"], g["n_kv_heads"], g["head_dim"]
+    T = x.shape[1]
+    with device_scope("l%d/attn/proj" % li):
+        h = rms_norm(x, params[p + "attn_norm"], arch["rms_norm_eps"])
+        q = jnp.einsum("btd,dhe->bhte", h, params[p + "wq"].reshape(-1, H, hd))
+        k = jnp.einsum("btd,dhe->bhte", h,
+                       params[p + "wk"].reshape(-1, Hkv, hd))
+        v = kept(jnp.einsum("btd,dhe->bhte", h,
+                            params[p + "wv"].reshape(-1, Hkv, hd)), "gqa_v")
+    with device_scope("l%d/attn/rope" % li):
+        rot = int(hd * mine["rope"].get("partial_rotary_factor", 1))
+        cos, sin = rope_tables(T, rot, mine["rope"])
+
+        def rotate(a):      # the first ``rot`` channels; the rest pass
+            if rot == hd:
+                return apply_rope(a, cos, sin)
+            return jnp.concatenate(
+                [apply_rope(a[..., :rot], cos, sin), a[..., rot:]], axis=-1)
+
+        q, k = kept(rotate(q), "gqa_q"), kept(rotate(k), "gqa_k")
+    with device_scope("l%d/attn/flash" % li):
+        att = attend(q, k, v, hd ** -0.5, mine["window"])   # (B, H, T, hd)
+    if g["gate"]:
+        with device_scope("l%d/attn/gate" % li):
+            gate = jax.lax.logistic(kept(
+                jnp.einsum("btd,dh->bht", h, params[p + "wgate"]),
+                "gqa_gate").astype(jnp.float32))
+            att = (att.astype(jnp.float32) * gate[..., None]).astype(
+                att.dtype)
+    with device_scope("l%d/attn/out" % li):
+        return jnp.einsum("bhte,hed->btd", att,
+                          params[p + "wo"].reshape(H, hd, -1))
+
+
 def _swiglu(u, wg, wu, wd, whose):
     """``(silu(u wg) * (u wu)) wd`` with the two matmuls' outputs kept
     under ``whose``'s names. ``silu`` is written out: the jitted
@@ -263,7 +331,7 @@ def moe_ffn(params, li, x, arch):
         logits = kept(jnp.dot(rows_in.astype(jnp.float32),
                               params[p + "router"].astype(jnp.float32),
                               precision="highest"), "router_logits")
-        idx = kept(_moe.select(logits, params[p + "router_bias"],
+        idx = kept(_moe.select(logits, params.get(p + "router_bias"),
                                m["top_k"]), "route_idx")
         weight = kept(_moe.weigh(logits, idx, m["scale"]), "route_weight")
     with device_scope("l%d/moe/dispatch" % li):

@@ -485,13 +485,15 @@ def gmm(x, w, plan, block_rows=GMM_BLOCK_ROWS, interpret=None):
 
 # --- the router's two halves ------------------------------------------------
 def select(logits, bias, top_k):
-    """The selection of :func:`route` alone: idx (N, k) int32."""
+    """The selection of :func:`route` alone: idx (N, k) int32. ``bias``
+    None: an architecture without an expert bias selects by score."""
     import jax
     import jax.numpy as jnp
 
-    score = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(score)
-                           + bias.astype(jnp.float32), top_k)
+    score = jax.lax.stop_gradient(jax.nn.sigmoid(logits.astype(jnp.float32)))
+    if bias is not None:
+        score = score + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(score, top_k)
     return idx.astype(jnp.int32)
 
 

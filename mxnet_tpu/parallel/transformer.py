@@ -38,10 +38,13 @@ class TransformerParallel:
     ``n_layers`` of the first block, ``("mha", "soft_moe")``: multi-head
     attention without positions, a weightless RMSNorm and a GELU FFN whose
     experts all run under a softmax gate. The other kinds — ``"mla"``
-    (latent attention with rotary positions), ``"swiglu"`` and ``"moe"``
-    (sigmoid top-k routing over ``arch["moe"]["n_experts"]`` experts of
-    which this rank holds the range ``experts_held``, plus shared experts)
-    — take their widths from ``arch``, have learned norm weights and a
+    (latent attention with rotary positions), ``"gqa"`` (grouped-query
+    attention: each layer its own head count, window and rotary tables
+    under ``arch["gqa"]["layers"]``, a sigmoid gate a head), ``"swiglu"``
+    and ``"moe"`` (sigmoid top-k routing over ``arch["moe"]["n_experts"]``
+    experts of which this rank holds the range ``experts_held``, plus a
+    shared expert) — take their widths from ``arch``, have learned norm
+    weights and a
     learned final norm, and train on dp meshes. ``remat`` recomputes each
     layer in the backward pass, but for what it keeps by name
     (``lm_layers.KEPT_BY_A_RECOMPUTED_LAYER``: the flash output among it).
@@ -78,25 +81,33 @@ class TransformerParallel:
 
     @classmethod
     def from_config(cls, mesh, cfg, dtype=np.float32, remat=False):
-        """A model of MLA layers from a published ``config.json``'s keys
+        """A model from a published ``config.json``'s keys: MLA layers
         (the DeepSeek-MLA family's names, ``model_type: sarvam_mla``
-        among them): the first ``first_k_dense_replace`` layers with a
-        SwiGLU FFN, the rest routed. ``num_experts`` counts the experts
-        HELD; ``cfg["published"]["num_experts"]`` (the router's width) and
-        ``cfg["deployment"]["experts_held"]`` (their range) say of which
-        share, and default to all of them."""
-        n_dense = cfg.get("first_k_dense_replace", 0)
-        layers = [("mla", "swiglu" if li < n_dense else "moe")
-                  for li in range(cfg["num_hidden_layers"])]
+        among them), the first ``first_k_dense_replace`` with a SwiGLU FFN
+        and the rest routed; or, under ``model_type: laguna``, the model
+        its layer lists describe (:func:`_laguna`). ``num_experts`` counts
+        the experts HELD; ``cfg["published"]["num_experts"]`` (the
+        router's width) and ``cfg["deployment"]["experts_held"]`` (their
+        range) say of which share, and default to all of them."""
         held = cfg.get("deployment", {}).get(
             "experts_held", (0, cfg["num_experts"]))
         moe = {"n_experts": cfg.get("published", {}).get(
                    "num_experts", cfg["num_experts"]),
                "top_k": cfg["num_experts_per_tok"],
-               "scale": cfg["routed_scaling_factor"],
                "d_expert": cfg["moe_intermediate_size"],
-               "n_shared": cfg["num_shared_experts"],
                "experts_held": (int(held[0]), int(held[1]))}
+        if cfg.get("model_type") == "laguna":
+            layers, arch = _laguna(cfg, moe)
+            return cls(mesh, vocab=cfg["vocab_size"],
+                       d_model=cfg["hidden_size"],
+                       n_heads=cfg["num_attention_heads"],
+                       d_ff=cfg["intermediate_size"], dtype=dtype,
+                       layers=layers, arch=arch, remat=remat)
+        n_dense = cfg.get("first_k_dense_replace", 0)
+        layers = [("mla", "swiglu" if li < n_dense else "moe")
+                  for li in range(cfg["num_hidden_layers"])]
+        moe.update(scale=cfg["routed_scaling_factor"],
+                   n_shared=cfg["num_shared_experts"])
         arch = {k: cfg[k] for k in (
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
             "kv_lora_rank", "rms_norm_eps")}
@@ -209,8 +220,9 @@ class TransformerParallel:
                                 params[p + "w2"])
         return jnp.einsum("bted,bte->btd", expert_out, gate)
 
-    def _attend(self, q, k, v, scale):
-        return _local_attention(q, k, v, self.mesh, scale=scale)
+    def _attend(self, q, k, v, scale, window=None):
+        return _local_attention(q, k, v, self.mesh, scale=scale,
+                                window=window)
 
     def _layer(self, li, params, x, collect=None):
         """Layer ``li`` on (B, T, d)."""
@@ -232,9 +244,12 @@ class TransformerParallel:
                     att = _local_attention(q, k, v, self.mesh)
                 att = att.transpose(0, 2, 1, 3).reshape(B, T, d)
                 x = x + att @ params[p + "wo"]
-        else:
+        elif attn == "mla":
             x = lm_layers.kept(x + lm_layers.mla_attention(
                 params, li, x, c, self.arch, self._attend), "attn_residual")
+        else:
+            x = lm_layers.kept(x + lm_layers.gqa_attention(
+                params, li, x, self.arch, self._attend), "attn_residual")
         if ffn == "soft_moe":
             # --- MoE FFN: soft top-2-ish gate over ep-sharded experts ---
             with device_scope("l%d/ffn" % li):
@@ -337,8 +352,10 @@ class TransformerParallel:
             raise NotImplementedError(
                 "the serving forwards (prefill/decode/verify) run the "
                 "('mha', 'soft_moe') block only: a latent (MLA) layer needs "
-                "a latent KV cache and a routed layer a serving dispatch, "
-                "which this model does not have yet (ROADMAP Reach A5)")
+                "a latent KV cache, a grouped-query layer a page pool by "
+                "k/v head (and a windowed one pages that are released), "
+                "and a routed layer a serving dispatch, which this model "
+                "does not have yet (ROADMAP Reach A3, A5, A6)")
 
     # --- incremental decode (generation subsystem) ------------------------
     def prefill_forward(self, params, tokens, attend=None):
@@ -578,10 +595,12 @@ def _prefill_attention(q, k, v):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _local_attention(q, k, v, mesh=None, scale=None):
+def _local_attention(q, k, v, mesh=None, scale=None, window=None):
     """Non-sequence-sharded attention: the Pallas flash kernel on TPU
     (forward AND backward tiled — no T x T HBM materialization in
-    training either), XLA reference elsewhere.
+    training either), XLA reference elsewhere. k and v may have fewer
+    heads than q (grouped-query), and ``window`` bounds how far back a
+    query sees (``flash_attention``).
 
     pallas_call has no GSPMD partitioning rule, so on a dp/tp-sharded
     mesh the kernel runs under shard_map: attention is embarrassingly
@@ -596,11 +615,13 @@ def _local_attention(q, k, v, mesh=None, scale=None):
         from .flash_attention import flash_attention
 
         if mesh is None or mesh.devices.size == 1:
-            return flash_attention(q, k, v, causal=True, scale=scale)
+            return flash_attention(q, k, v, causal=True, scale=scale,
+                                   window=window)
         axes = dict(mesh.shape)
         ndp, ntp = axes.get("dp", 1), axes.get("tp", 1)
         sharded = {a for a, s in axes.items() if s > 1}
-        if sharded <= {"dp", "tp"} and B % ndp == 0 and H % ntp == 0:
+        if (sharded <= {"dp", "tp"} and B % ndp == 0 and H % ntp == 0
+                and k.shape[1] % ntp == 0):
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
@@ -608,13 +629,54 @@ def _local_attention(q, k, v, mesh=None, scale=None):
                      "tp" if ntp > 1 else None, None, None)
             fn = shard_map(
                 lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                                scale=scale),
+                                                scale=scale, window=window),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
             return fn(q, k, v)
-    from .ring_attention import attention_reference
+    if window is None and k.shape[1] == H:
+        from .ring_attention import attention_reference
 
-    return attention_reference(q, k, v, causal=True, scale=scale)
+        return attention_reference(q, k, v, causal=True, scale=scale)
+    from .flash_attention import _dense_with_lse
+
+    return _dense_with_lse(q, k, v, causal=True, scale=scale,
+                           window=window)[0]
+
+
+def _laguna(cfg, moe):
+    """(layers, arch) of ``model_type: laguna`` from its published keys:
+    ``layer_types`` and ``num_attention_heads_per_layer`` give each layer
+    its mask (``sliding_window`` positions or all of them), its query
+    heads over ``num_key_value_heads`` k/v heads of ``head_dim``, and its
+    block of ``rope_parameters``; ``mlp_layer_types`` its FFN. The router
+    has no expert bias, and the shared expert its own width."""
+    sliding = {"full_attention": None,
+               "sliding_attention": cfg["sliding_window"]}
+
+    def rope(kind):
+        r = dict(cfg["rope_parameters"][kind])
+        r["theta"] = r.pop("rope_theta")
+        if r.pop("rope_type", "default") == "default":
+            r.pop("factor", None)
+        else:
+            r.setdefault("original_max_position_embeddings", cfg[
+                "rope_parameters"]["original_max_position_embeddings"])
+        return r
+
+    n = cfg["num_hidden_layers"]
+    kinds, heads, ffns = (cfg[k][:n] for k in (
+        "layer_types", "num_attention_heads_per_layer", "mlp_layer_types"))
+    layers = [("gqa", "swiglu" if f == "dense" else "moe") for f in ffns]
+    arch = {"rms_norm_eps": cfg["rms_norm_eps"],
+            "gqa": {"n_kv_heads": cfg["num_key_value_heads"],
+                    "head_dim": cfg["head_dim"], "gate": bool(cfg["gating"]),
+                    "layers": [{"n_heads": h, "window": sliding[k],
+                                "rope": rope(k)}
+                               for k, h in zip(kinds, heads)]},
+            "moe": dict(moe, scale=cfg["moe_routed_scaling_factor"],
+                        n_shared=1, router_bias=False,
+                        d_shared=cfg["shared_expert_intermediate_size"])}
+    return layers, arch
 
 
 def _rms_norm(x):

@@ -104,6 +104,30 @@ def test_flash_with_a_narrower_v_lowers_for_tpu(shape):
     assert names == ["flash_attention_fwd", "flash_attention_bwd_dqkv"]
 
 
+def _grouped_loss(window):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window)
+                       .astype(jnp.float32))
+    return loss
+
+
+@pytest.mark.parametrize("heads,window,names", [
+    # the cell laguna_train_t8192_b2: a full layer and a sliding one
+    (48, None, ["flash_attention_fwd", "flash_attention_bwd_dqkv"]),
+    (64, 512, ["flash_attention_window_fwd",
+               "flash_attention_window_bwd_dqkv"]),
+    (8, 512, ["flash_attention_window_fwd",           # a window, no group
+              "flash_attention_window_bwd_dqkv"]),
+    (128, None, ["flash_attention_fwd",    # 16 heads a group: two passes
+                 "flash_attention_bwd_dkv", "flash_attention_bwd_dq"]),
+])
+def test_grouped_and_windowed_flash_lowers_for_tpu(heads, window, names):
+    q = _aval((2, heads, 8192, 128), "bfloat16")
+    kv = _aval((2, 8, 8192, 128), "bfloat16")
+    assert _tpu_kernels(jax.grad(_grouped_loss(window), argnums=(0, 1, 2)),
+                        q, kv, kv) == names
+
+
 def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16"):
     from mxnet_tpu.parallel import moe
 
@@ -290,6 +314,18 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
     q, v = (_aval((1, 8, 8192, w), "bfloat16") for w in (192, 128))
     assert compiled_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 2
     fn, avals = _gmm_step(8192, 8, 16, 4096, 2048)
+    assert compiled_calls(fn, *avals) == 3
+    # grouped-query heads at the cell laguna_train_t8192_b2's shapes: 48
+    # over 8 causal and 64 over 8 under a window of 512 — the fused
+    # backward's (group * T, D) dq scratch under the grouped calls' limit
+    # is Mosaic's to refuse — and the grouped matmul over 32 held experts
+    # of 512
+    kv = _aval((1, 8, 8192, 128), "bfloat16")
+    for heads, window in ((48, None), (64, 512)):
+        q = _aval((1, heads, 8192, 128), "bfloat16")
+        assert compiled_calls(jax.grad(_grouped_loss(window),
+                                       argnums=(0, 1, 2)), q, kv, kv) == 2
+    fn, avals = _gmm_step(16384, 8, 32, 2048, 512)
     assert compiled_calls(fn, *avals) == 3
     epilogue = (("bias",), ("act", "relu"))
     assert compiled_calls(
