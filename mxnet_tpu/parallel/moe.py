@@ -21,8 +21,8 @@ grouped matmul over those experts as Pallas TPU kernels.
   budget costs time, never a pair.
 - :func:`dispatch` / :func:`combine` move token rows into and out of a
   layout; each is the other's transpose. Into it is a gather by row; out
-  of it is a sum over the pairs (a gather) or over the rows (a sorted
-  segment sum), whichever are fewer.
+  of it is a sum over the pairs (a gather, in XLA) or over the rows (the
+  ``moe_sum_to_tokens`` kernel: a selection matmul), whichever are fewer.
 - :func:`gmm` is the grouped matmul ``rows[i] @ w[expert_of_tile(i)]``;
   its device events are named ``moe_gmm_fwd`` (also the input's gradient,
   with the weight read transposed) and ``moe_gmm_dw`` (the weight's
@@ -166,32 +166,79 @@ def _weight_of_pairs(weight, pair):
     return weight.at[pair // k, pair % k].get(mode="fill", fill_value=0)
 
 
-def _sum_to_tokens(rows, weight, row_of_pair, pair_of_row):
+def _sum_to_tokens(rows, weight, row_of_pair, pair_of_row, block=None):
     """out[t] = sum_j weight[t, j] * rows[row_of_pair[t, j]] (absent pairs
-    add nothing), accumulated in float32. Summed over whichever is fewer:
-    the (token, slot) pairs, each gathering its row (absent pairs a row of
-    zeros), or the layout's rows, taken in token order and added to their
-    tokens (padding and dead rows, whose contents are undefined, to none).
-    On the v5e at 20,480 rows of 4096 against 65,536 pairs the sorted
-    segment sum takes 4.5 ms, an unsorted scatter-add 5.1-5.2, the pairs'
-    gather 6.0 (tools/moe_layout_bench.py; PERF.md, PR 29)."""
+    add nothing; ``weight`` None: ones), accumulated in float32 and rounded
+    once. Summed over whichever is fewer. Where the layout has room for
+    every (token, slot) pair (``N * k <= R``: the worst-case layout) each
+    pair gathers its row, absent pairs a row of zeros, and XLA sums them:
+    6.0 ms on the v5e at sarvam's 65,536 pairs of 4096. Else (the compact
+    layout) the rows are taken in token order, a bf16 gather, and added to
+    their tokens by the ``moe_sum_to_tokens`` kernel below, a matmul with a
+    selection matrix a tile pair (padding and dead rows, whose contents are
+    undefined, sort to the end: the gather stops after the last chunk that
+    holds a live row and the kernel zeroes what follows the live rows,
+    since 0 x NaN is NaN); ``block``: its (token, row) tile bounds, None
+    the file's. XLA's own forms of that sum took 4.5 ms there (sorted
+    segment sum; an unsorted scatter-add 5.1-5.2: PERF.md, PR 29) and 5.3
+    at laguna's 40,960 rows of 2048, where this path takes 2.7-3.1 alone
+    and in the step the live chunks' gather 0.53 ms, its zeros 0.26, the
+    kernel 0.35-0.49, the ids' and weights' gathers 0.6 and the rounding
+    0.3 (tools/moe_layout_bench.py, tools/trace_report.py; PERF.md, PR
+    35). A row or token count with no legal tile takes the pairs' path
+    too."""
     import jax
     import jax.numpy as jnp
 
+    from ..observability import counter
+    from .pallas_common import LANES, aligned_block
+
     N, k = row_of_pair.shape
-    if N * k <= pair_of_row.shape[0]:
+    R = pair_of_row.shape[0]
+    interpret = jax.default_backend() != "tpu"
+    tm, tr = block or _SUM_BLOCK
+    tm = aligned_block(N, tm, 1 if interpret else 16)
+    tr = aligned_block(R, tr, 1 if interpret else LANES)
+    if N * k <= R or tm is None or tr is None:
+        if weight is None:
+            weight = jnp.ones((N, k), jnp.float32)
         picked = _take_rows(rows, row_of_pair.reshape(N * k)).reshape(
             N, k, rows.shape[-1])
         return jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
                           weight.astype(jnp.float32)).astype(rows.dtype)
+    counter("moe.sum_to_tokens_kernel").inc()
     by_token = jnp.argsort(pair_of_row).astype(jnp.int32)
     pair = pair_of_row[by_token]            # ascending; N*k past the held
-    w_row = _weight_of_pairs(weight, pair).astype(jnp.float32)
-    weighted = jnp.where((pair < N * k)[:, None], _take_rows(
-        rows, by_token).astype(jnp.float32) * w_row[:, None], 0.0)
-    return jax.ops.segment_sum(
-        weighted, pair // k, num_segments=N,
-        indices_are_sorted=True).astype(rows.dtype)
+    n_held = jnp.sum(pair < N * k, dtype=jnp.int32)  # the rows before them
+    weights = () if weight is None else (
+        _weight_of_pairs(weight, pair).astype(jnp.float32),)
+    return _sum_call(N, tm, tr, interpret)(
+        _take_the_first(n_held, rows, by_token),
+        (pair // k).astype(jnp.int32), n_held.reshape(1),
+        *weights).astype(rows.dtype)
+
+
+def _take_the_first(n, rows, index):
+    """rows[index] for the first ``n`` of ``index``, a chunk of
+    ``_SUM_GATHER_ROWS`` at a time (the last chunk whole) and zeros after:
+    XLA's gather reads a row in 51 ns on the v5e, wanted or not, so the
+    compact layout's dead half costs as much as its live one."""
+    import jax
+    import jax.numpy as jnp
+
+    from .pallas_common import aligned_block
+
+    R = index.shape[0]
+    chunk = aligned_block(R, _SUM_GATHER_ROWS, 1) or R
+
+    def take_chunk(c, taken):
+        at = (c * chunk).astype(jnp.int32)
+        part = rows.at[jax.lax.dynamic_slice(index, (at,), (chunk,))].get(
+            mode="promise_in_bounds", unique_indices=True)
+        return jax.lax.dynamic_update_slice(taken, part, (at, jnp.int32(0)))
+
+    return jax.lax.fori_loop(0, -(-n // chunk), take_chunk,
+                             jnp.zeros_like(rows))
 
 
 @functools.lru_cache(maxsize=None)
@@ -210,8 +257,7 @@ def _moves():
 
     def dispatch_bwd(res, d_rows):
         row_of_pair, pair_of_row = res
-        ones = jnp.ones(row_of_pair.shape, jnp.float32)
-        return (_sum_to_tokens(d_rows, ones, row_of_pair, pair_of_row),
+        return (_sum_to_tokens(d_rows, None, row_of_pair, pair_of_row),
                 None, None)
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
@@ -481,6 +527,120 @@ def gmm(x, w, plan, block_rows=GMM_BLOCK_ROWS, interpret=None):
     return _gmm_of(int(block_rows), bool(interpret))(
         x, w, {k: plan[k] for k in ("tile_expert", "tile_first",
                                     "tile_last", "n_live")})
+
+
+# --- the rows -> tokens sum ---------------------------------------------------
+#: (token, row) tile bounds of the rows -> tokens sum and the column bound
+#: of its output tile, by tools/moe_layout_bench.py --tile on the v5e
+#: (PERF.md, PR 35)
+_SUM_BLOCK = (128, 256)
+_SUM_BLOCK_COLS = 4096
+#: rows a step of the gather before it (1024 to 4096 take the same time)
+_SUM_GATHER_ROWS = 2048
+
+
+def _sum_kernel(tok_ref, row_ref, first_ref, n_ref, held_ref, t_ref, *refs):
+    """One (column tile, work item) step: a row tile, in token order, added
+    to the token tile that owns some of its rows, as ``S @ rows`` with
+    ``S[m, r] = weight[r]`` where row r is token m's and 0 elsewhere,
+    accumulated in the float32 output tile over the token tile's items.
+    Rows past the ``held_ref[0]`` live ones are undefined and zeroed before
+    the MXU sees them (0 x NaN is NaN). bf16 rows take one MXU pass a bf16 part of the weight (ones: one
+    part, exact; float32 weights: a high and a low part, 16 bits of them);
+    float32 rows one at ``highest``. Items past the live ones do nothing,
+    as in the kernels above."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    *w_ref, x_ref, o_ref = refs
+    i = pl.program_id(1)
+    live = i < n_ref[0]
+    tm, tr = o_ref.shape[0], x_ref.shape[0]
+
+    @pl.when(live & (first_ref[i] == 1))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)  # graftlint: disable=G003 — a Pallas kernel writes its refs
+
+    @pl.when(live)
+    def _():
+        r = row_ref[i] * tr + jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0)
+        x = jnp.where(r < held_ref[0], x_ref[...], jnp.zeros_like(x_ref))
+        mine = (t_ref[...] - tok_ref[i] * tm
+                == jax.lax.broadcasted_iota(jnp.int32, (tm, tr), 0))
+        w = w_ref[0][...] if w_ref else jnp.ones((1, tr), jnp.float32)
+        parts, precision = [w], jax.lax.Precision.HIGHEST
+        if x.dtype == jnp.bfloat16:
+            precision = None
+            if w_ref:
+                high = w.astype(x.dtype).astype(jnp.float32)
+                parts = [high, w - high]
+        for part in parts:
+            o_ref[...] += jax.lax.dot_general(  # graftlint: disable=G003 — a Pallas kernel writes its refs
+                jnp.where(mine, part, 0.0).astype(x.dtype), x, _NN,
+                precision=precision, preferred_element_type=jnp.float32)
+
+
+@functools.lru_cache(maxsize=64)
+def _sum_call(n_tokens, tm, tr, interpret):
+    """The kernel over ``n_tokens`` tokens in (tm, tr) tiles: (rows in
+    token order (R, d); their tokens (R,), ascending and ``n_tokens`` past
+    the live ones; the live rows (1,)[; their float32 weights (R,)]) ->
+    (n_tokens, d) float32, for the caller to round: XLA fuses
+    the rounding into what reads it and moves it out of the layouts'
+    conditional, whose result then is what the segment sum's was (with a
+    bf16 result the step's buffers pack 377 MB worse in
+    ``sarvam_train_t8192_b1``, at the same peak of live bytes: my compiles
+    for the v5e, PERF.md, PR 35)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(rows, token, n_held, *w_row):
+        R, d = rows.shape
+        n_tok, n_row = n_tokens // tm, R // tr
+        tn = _col_block(d, _SUM_BLOCK_COLS, interpret)
+        # the work list: token tile i owns the rows [start[i], start[i+1])
+        # and takes an item a row tile that run meets (one where it is
+        # empty, to write its zeros): n_tok + n_row - 1 items at most
+        def before(ascending, values, side="left"):
+            return jnp.searchsorted(ascending, values, side=side,
+                                    method="compare_all").astype(jnp.int32)
+
+        start = before(token, tm * jnp.arange(n_tok + 1, dtype=jnp.int32))
+        lo = jnp.minimum(start[:-1] // tr, n_row - 1)
+        hi = jnp.maximum(lo, (start[1:] - 1) // tr)
+        end = jnp.cumsum(hi - lo + 1, dtype=jnp.int32)
+        begin = end - (hi - lo + 1)
+        item = jnp.minimum(jnp.arange(n_tok + n_row, dtype=jnp.int32),
+                           end[-1] - 1)
+        tok = before(end, item, "right")
+        row = lo[tok] + item - begin[tok]
+
+        def block(shape, index):
+            return pl.BlockSpec(shape, lambda c, i, tok, row, *_:
+                                index(c, tok[i], row[i]))
+
+        by_row = block((1, tr), lambda c, t, r: (0, r))
+        return pallas_call(
+            _sum_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(d // tn, n_tok + n_row),
+                in_specs=[by_row] * (1 + len(w_row)) + [
+                    block((tr, tn), lambda c, t, r: (r, c))],
+                out_specs=block((tm, tn), lambda c, t, r: (t, c))),
+            out_shape=jax.ShapeDtypeStruct((n_tokens, d), jnp.float32),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            name="moe_sum_to_tokens",
+        )(tok, row, (item == begin[tok]).astype(jnp.int32), end[-1:], n_held,
+          token.reshape(1, R), *(w.reshape(1, R) for w in w_row), rows)
+
+    return jax.jit(call)
 
 
 # --- the router's two halves ------------------------------------------------

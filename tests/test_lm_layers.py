@@ -382,6 +382,153 @@ def test_no_pair_is_dropped_when_every_choice_is_held():
     assert rel(out, want) < 1e-5
 
 
+# --- the rows -> tokens sum as a kernel ---------------------------------------
+BF16 = jnp.bfloat16
+#: (d, experts held, experts): the two routed cells' shape classes, small
+SUM_SHAPES = [(256, 4, 32), (512, 8, 64)]
+KERNEL_COUNTER = "moe.sum_to_tokens_kernel"
+
+
+def _sum_case(d, held, E, N=64, k=8, tile=8):
+    """A compact layout of fewer rows than pairs, with token 0 holding no
+    pair and token 1 and the last token one in every held expert (all k
+    where k experts are held); bf16 rows whose float32 sums are exact in
+    any order, the dead and padding rows among them."""
+    ks = jax.random.split(jax.random.PRNGKey(d), 3)
+    idx = jax.lax.top_k(jax.random.uniform(ks[0], (N, E)), k)[1]
+    most = np.r_[:min(k, held), E - k + min(k, held):E]
+    idx = np.array(idx, np.int32)
+    idx[0], idx[1], idx[N - 1] = np.r_[held:held + k], most, most
+    idx = jnp.asarray(idx)
+    R = moe.compact_row_budget(N, k, held, E, tile)
+    plan = moe.plan_dispatch(idx, (0, held), tile, R)
+    assert N * k > R >= int(plan["n_live"][0]) * tile
+    pairs_of = np.sum(np.asarray(plan["row_of_pair"]) < R, axis=1)
+    assert pairs_of[0] == 0 and pairs_of[1] == pairs_of[-1] == min(k, held)
+    rows = (jax.random.randint(ks[1], (R, d), -128, 129) / 64).astype(BF16)
+    weight = jax.random.uniform(ks[2], (N, k), F32, 0.1, 1.0)
+    return idx, plan, rows, weight
+
+
+def _float32_sum(rows, weight, plan):
+    """The formula, float32 at ``highest``, not rounded."""
+    N, k = weight.shape
+    picked = moe._take_rows(rows.astype(F32),
+                            plan["row_of_pair"].reshape(N * k))
+    return jnp.einsum("nkd,nk->nd", picked.reshape(N, k, -1), weight,
+                      precision="highest")
+
+
+def _kernel_sum(rows, weight, plan, block=(16, 32)):
+    return moe._sum_to_tokens(rows, weight, plan["row_of_pair"],
+                              plan["pair_of_row"], block=block)
+
+
+@pytest.mark.parametrize("d,held,E", SUM_SHAPES)
+@pytest.mark.parametrize("case", ["weighted", "one_tile_pair", "ones",
+                                  "nan_in_dead_rows"])
+def test_the_rows_to_tokens_kernel_against_the_float32_formula(
+        case, d, held, E, monkeypatch):
+    # the gather before the kernel in several chunks, dead ones among them
+    monkeypatch.setattr(moe, "_SUM_GATHER_ROWS", 32)
+    _, plan, rows, weight = _sum_case(d, held, E)
+    if case in ("weighted", "one_tile_pair"):
+        # the file's bounds hold these sizes in one tile pair
+        got = _kernel_sum(rows, weight, plan,
+                          None if case == "one_tile_pair" else (16, 32))
+        want = _float32_sum(rows, weight, plan)
+        assert got.dtype == BF16
+        # one rounding to bf16, half an ulp or 2**-9 of the value at most,
+        # and the weight's two bf16 parts: 2**-17 of each of eight terms
+        gap = jnp.abs(got.astype(F32) - want)
+        assert bool(jnp.all(gap <= 2.0 ** -8 * jnp.abs(want) + 2.0 ** -13))
+        assert not np.asarray(got[0]).any()       # no held pair: zeros
+        assert np.asarray(got[1]).any() and np.asarray(got[-1]).any()
+    elif case == "ones":
+        # weights of one (dispatch's backward): the plain sum, to the bit
+        got = _kernel_sum(rows, None, plan)
+        want = _float32_sum(rows, jnp.ones_like(weight), plan).astype(BF16)
+        np.testing.assert_array_equal(np.asarray(got.astype(F32)),
+                                      np.asarray(want.astype(F32)))
+    else:
+        # what the grouped matmul leaves past the live rows is undefined
+        dead = (plan["pair_of_row"] == weight.size)[:, None]
+        assert 0 < int(dead.sum()) < dead.size
+        for w in (weight, None):
+            got = _kernel_sum(jnp.where(dead, jnp.nan, rows), w, plan)
+            assert bool(jnp.all(jnp.isfinite(got.astype(F32))))
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(F32)),
+                np.asarray(_kernel_sum(rows, w, plan).astype(F32)))
+
+
+def _moved_rows(plan, x, weight, g):
+    """dispatch -> something elementwise -> combine, as a loss."""
+    rows = moe.dispatch(x, plan)
+    out = moe.combine(rows * rows.dtype.type(1.5), weight, plan)
+    return jnp.sum(out.astype(F32) * g)
+
+
+@pytest.mark.parametrize("d,held,E", SUM_SHAPES)
+def test_dispatch_and_combine_differentiate_alike_through_the_kernel(
+        d, held, E, monkeypatch):
+    """The compact layout (the kernel, in several tile pairs) against one
+    with room for every pair (the pairs' gather in XLA: the sum as it
+    was), forward and through ``jax.grad``: within one bf16 ulp."""
+    monkeypatch.setattr(moe, "_SUM_BLOCK", (16, 32))
+    monkeypatch.setattr(moe, "_SUM_GATHER_ROWS", 32)
+    idx, compact, _, weight = _sum_case(d, held, E)
+    N, k = weight.shape
+    worst = moe.plan_dispatch(idx, (0, held), 8, N * k + held * 8)
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    x = jax.random.normal(ks[0], (N, d), BF16)
+    g = jax.random.normal(ks[1], (N, d), F32)
+    obs.set_enabled(True)
+    before = obs.metrics.get_value(KERNEL_COUNTER, 0)
+    want = jax.value_and_grad(_moved_rows, (1, 2))(worst, x, weight, g)
+    assert obs.metrics.get_value(KERNEL_COUNTER, 0) == before
+    got = jax.value_and_grad(_moved_rows, (1, 2))(compact, x, weight, g)
+    # its combine forward and its dispatch backward
+    assert obs.metrics.get_value(KERNEL_COUNTER, 0) == before + 2
+    assert abs(float(got[0]) - float(want[0])) <= 2e-3 * abs(float(want[0]))
+    for a, b, what in zip(got[1], want[1], ("dx", "dweight")):
+        a, b = a.astype(F32), b.astype(F32)
+        ulp = 2.0 ** -7 if what == "dx" else 1e-5
+        assert bool(jnp.all(jnp.abs(a - b) <= ulp * jnp.abs(b))), what
+
+
+@pytest.mark.parametrize("layout,calls", [
+    ("compact", 2), ("room_for_every_pair", 0), ("the_one_that_fits", 3)])
+def test_the_kernel_counter_counts_the_traced_calls(layout, calls):
+    """One a traced call that takes the kernel: a routed block's forward
+    and backward trace its ``combine`` forward and its ``dispatch``
+    backward; under ``in_the_layout_that_fits`` the compact branch's
+    backward traces its own forward besides (whose sum nothing reads),
+    and the worst-case branch, with room for every pair, none."""
+    d, held, E = SUM_SHAPES[1]
+    idx, compact, _, weight = _sum_case(d, held, E)
+    N, k = weight.shape
+    worst = moe.plan_dispatch(idx, (0, held), 8)
+    assert worst["pair_of_row"].shape[0] >= N * k
+    x, g = jnp.ones((N, d), BF16), jnp.ones((N, d), F32)
+
+    def loss(x, weight):
+        if layout == "the_one_that_fits":
+            return moe.in_the_layout_that_fits(
+                lambda plan, x, weight: _moved_rows(plan, x, weight, g),
+                compact, worst, x, weight)
+        return _moved_rows(compact if layout == "compact" else worst,
+                           x, weight, g)
+
+    obs.set_enabled(True)
+    before = obs.metrics.get_value(KERNEL_COUNTER, 0)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, weight).jaxpr
+    assert obs.metrics.get_value(KERNEL_COUNTER, 0) - before == calls
+    kernels = [eqn.params["name"] for eqn in _eqns(jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    assert kernels == ["moe_sum_to_tokens"] * calls
+
+
 @pytest.mark.parametrize("experts", [8, 32])
 def test_routing_stats_and_the_trace_time_counters(experts):
     cfg = small_cfg(3, 1, experts=experts)

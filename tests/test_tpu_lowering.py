@@ -128,11 +128,11 @@ def test_grouped_and_windowed_flash_lowers_for_tpu(heads, window, names):
                         q, kv, kv) == names
 
 
-def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16"):
+def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16", n_rows=None):
     from mxnet_tpu.parallel import moe
 
     def loss(x, w, idx, weight):
-        plan = moe.plan_dispatch(idx, (0, held), moe.GMM_BLOCK_ROWS)
+        plan = moe.plan_dispatch(idx, (0, held), moe.GMM_BLOCK_ROWS, n_rows)
         rows = moe.gmm(moe.dispatch(x, plan), w, plan, interpret=False)
         return jnp.sum(moe.combine(rows, weight, plan).astype(jnp.float32))
 
@@ -142,28 +142,46 @@ def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16"):
              _aval((tokens, top_k), "float32")))
 
 
-def test_grouped_matmul_kernels_lower_for_tpu():
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``dispatch`` / ``combine`` choose the compiled kernel where the
+    backend is a TPU (``gmm`` is told so by its argument)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("n_rows,moved_by", [
+    (None, []),                        # room for every pair: XLA sums
+    # dispatch's backward (the gradient does not read combine's forward)
+    (20480, ["moe_sum_to_tokens"])])
+def test_grouped_matmul_kernels_lower_for_tpu(n_rows, moved_by,
+                                              as_on_the_chip):
     # the cell's expert layer: 8,192 tokens, top 8, 16 experts held,
     # 4096 x 2048. Forward, the input's gradient (the same kernel, the
     # weight read transposed) and the weight's gradient; the readers
-    # match the ``moe_gmm`` prefix
-    fn, avals = _gmm_step(8192, 8, 16, 4096, 2048)
+    # match the ``moe_gmm`` prefix, which the rows -> tokens kernel of the
+    # compact layout stays out of
+    fn, avals = _gmm_step(8192, 8, 16, 4096, 2048, n_rows=n_rows)
     names = _tpu_kernels(fn, *avals)
-    assert sorted(names) == ["moe_gmm_dw", "moe_gmm_fwd", "moe_gmm_fwd"]
+    assert sorted(names) == ["moe_gmm_dw", "moe_gmm_fwd",
+                             "moe_gmm_fwd"] + moved_by
 
 
-def test_the_routed_block_lowers_for_tpu_in_both_layouts():
-    # the cell's routed block (8,192 tokens, top 8, 16 of 128 experts held,
-    # 4096 x 2048), forward and backward: a conditional each, both layouts
-    # in it, and under the ``moe_gmm`` prefix (which the benchmark's
-    # readers sum over) the two kernels and no other
+@pytest.mark.parametrize("N,held,E,d,f,rows", [
+    (8192, 16, 128, 4096, 2048, (20480, 69632)),     # sarvam_train_t8192_b1
+    (16384, 32, 256, 2048, 512, (40960, 139264))])   # laguna_train_t8192_b2
+def test_the_routed_block_lowers_for_tpu_in_both_layouts(N, held, E, d, f,
+                                                         rows,
+                                                         as_on_the_chip):
+    # a cell's routed block (top 8), forward and backward: a conditional
+    # each, both layouts in it; under the ``moe_gmm`` prefix (which the
+    # benchmark's readers sum over) the two grouped-matmul kernels and no
+    # other, and beside them the compact layout's rows -> tokens kernel
     from mxnet_tpu.parallel import moe
 
-    N, k, held, E, d, f = 8192, 8, 16, 128, 4096, 2048
-    tile = moe.GMM_BLOCK_ROWS
+    k, tile = 8, moe.GMM_BLOCK_ROWS
     budgets = (moe.compact_row_budget(N, k, held, E, tile),
                moe.row_budget(N, k, held, tile))
-    assert budgets == (20480, 69632)
+    assert budgets == rows
 
     def block(plan, x, weight, wg, wu, wd):
         rows = moe.dispatch(x, plan)
@@ -189,17 +207,21 @@ def test_the_routed_block_lowers_for_tpu_in_both_layouts():
 
     both = lowered(budgets)
     names = re.findall(r'kernel_name = "([^"]*)"', both)
-    assert set(names) == {"moe_gmm_fwd", "moe_gmm_dw"}
+    assert set(names) == {"moe_gmm_fwd", "moe_gmm_dw", "moe_sum_to_tokens"}
+    assert {n for n in names if n.startswith("moe_gmm")} == {
+        "moe_gmm_fwd", "moe_gmm_dw"}
     assert both.count("stablehlo.case") == 2
-    assert re.search(r"tensor<69632x4096xbf16>", both)
-    # the compact layout alone: no float array as long as the worst-case
-    # layout (69,632 rows) or as the (token, slot) pairs (65,536)
+    assert re.search(r"tensor<%dx%dxbf16>" % (budgets[1], d), both)
+    # the compact layout alone: combine's forward and dispatch's backward
+    # through the kernel, and no float array as long as the worst-case
+    # layout or the (token, slot) pairs
     compact = lowered((budgets[0], budgets[0]))
     assert "stablehlo.case" not in compact
-    wide = re.findall(r"tensor<(?:65536|69632)(?:x\d+)*x(?:bf16|f32)>",
-                      compact)
+    assert compact.count('kernel_name = "moe_sum_to_tokens"') == 2
+    wide = re.findall(r"tensor<(?:%d|%d)(?:x\d+)*x(?:bf16|f32)>"
+                      % (N * k, budgets[1]), compact)
     assert not wide, sorted(set(wide))
-    assert re.search(r"tensor<20480x4096xbf16>", compact)
+    assert re.search(r"tensor<%dx%dxbf16>" % (budgets[0], d), compact)
 
 
 def test_flash_declines_a_length_with_no_legal_block():
@@ -327,6 +349,16 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
                                        argnums=(0, 1, 2)), q, kv, kv) == 2
     fn, avals = _gmm_step(16384, 8, 32, 2048, 512)
     assert compiled_calls(fn, *avals) == 3
+    # the compact layout's rows -> tokens kernel at both routed cells'
+    # shapes, weighted (combine's forward) and not (dispatch's backward)
+    from mxnet_tpu.parallel import moe
+
+    for N, R, d in ((8192, 20480, 4096), (16384, 40960, 2048)):
+        call = moe._sum_call(N, *moe._SUM_BLOCK, False)
+        operands = (_aval((R, d), "bfloat16"), _aval((R,), "int32"),
+                    _aval((1,), "int32"), _aval((R,), "float32"))
+        assert compiled_calls(call, *operands) == 1
+        assert compiled_calls(call, *operands[:3]) == 1
     epilogue = (("bias",), ("act", "relu"))
     assert compiled_calls(
         lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue),

@@ -11,7 +11,15 @@ The forms, all float32 sums cast to the rows' dtype:
 - ``scatter``: the layout's rows, weighted, added into their tokens'
   rows (``.at[token_of_row].add``).
 - ``sorted_scatter``: the rows gathered in token order first, then a
-  segment sum over sorted ids: ``moe._sum_to_tokens`` itself.
+  segment sum over sorted ids: what ``moe._sum_to_tokens`` ran until PR 35.
+- ``mxu``: ``moe._sum_to_tokens`` itself: the rows gathered in token order
+  (bf16), then the ``moe_sum_to_tokens`` kernel, a selection matmul a
+  (token tile, row tile) pair; once a ``--tile`` (``TMxTR``, several
+  allowed), weighted (``combine``'s forward: two MXU passes) and with
+  weights of one (``dispatch``'s backward: one). Its dead and padding rows
+  are filled with NaN here, which no other form would survive. Beside it
+  ``gather.by_token``, the gather before the kernel, alone: of every row,
+  and of the chunks that hold live rows (what the program does).
 - ``token_list``: the rows gathered in token order, each summed with the
   up to k-1 rows after it that belong to the same token, and every token
   reads the row at the head of its run.
@@ -20,7 +28,11 @@ The forms, all float32 sums cast to the rows' dtype:
 
 Beside them the row gather of ``dispatch`` at both row counts, and the
 weight's gradient both ways. One JSON line a measurement; ms are medians
-over ``--reps`` calls, each waited for.
+over ``--reps`` calls, each waited for; ``max_abs_gap_to_float32`` is
+against the float32 formula at ``highest`` before any rounding to bf16.
+The defaults are ``sarvam_train_t8192_b1``'s shapes;
+``laguna_train_t8192_b2``'s are ``--tokens 16384 --held 32 --experts 256
+--width 2048``.
 """
 import argparse
 import json
@@ -41,6 +53,9 @@ def main():
     ap.add_argument("--width", type=int, default=4096)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--tile", nargs="*", default=None, metavar="TMxTR",
+                    help="(token, row) tile bounds of the mxu form to sweep;"
+                         " none: the constants of moe.py")
     args = ap.parse_args()
 
     import jax
@@ -63,7 +78,8 @@ def main():
     live = int(plan_c["n_live"][0]) * B
     print(json.dumps({"worst_rows": worst, "compact_rows": compact,
                       "live_rows": live, "pairs": N * k,
-                      "pairs_held": int(plan_c["counts"].sum())}))
+                      "pairs_held": int(plan_c["counts"].sum()),
+                      "mxu_is_the_kernel": N * k > compact}))
     assert live <= compact
     x = jax.random.normal(keys[1], (N, d), jnp.bfloat16)
     weight = jax.random.uniform(keys[2], (N, k), jnp.float32)
@@ -119,8 +135,16 @@ def main():
                 start.astype(jnp.int32), count.astype(jnp.int32))
 
     def sorted_scatter(rows, weight, plan):
-        return moe._sum_to_tokens(rows, weight, plan["row_of_pair"],
-                                  plan["pair_of_row"])
+        g, w, token, _, _ = token_order(rows, weight, plan)
+        return jax.ops.segment_sum(
+            jnp.where((token < N)[:, None],
+                      g.astype(jnp.float32) * w[:, None], 0.0),
+            token, num_segments=N, indices_are_sorted=True).astype(rows.dtype)
+
+    def mxu(block):
+        return lambda rows, weight, plan: moe._sum_to_tokens(
+            rows, weight, plan["row_of_pair"], plan["pair_of_row"],
+            block=block)
 
     def token_list(rows, weight, plan):
         g, w, token, start, count = token_order(rows, weight, plan)
@@ -154,14 +178,51 @@ def main():
                  jnp.concatenate([rows_c, jnp.zeros((worst - compact, d),
                                                     rows_c.dtype)]),
                  weight, plan_w)
+    ones = jnp.ones_like(weight)
+
+    def exact(weight):
+        with jax.default_matmul_precision("highest"):
+            picked = take(rows_c, plan_c["row_of_pair"].reshape(N * k))
+            return jnp.einsum("nkd,nk->nd", picked.reshape(N, k, d).astype(
+                jnp.float32), weight)
+
+    def gaps(name, got, weight):
+        got = got.astype(jnp.float32)
+        print(json.dumps({
+            "what": name,
+            "max_abs_gap_to_pairs": float(jnp.max(jnp.abs(
+                got - want.astype(jnp.float32)))) if weight is not ones
+            else None,
+            "max_abs_gap_to_float32": float(jnp.max(jnp.abs(
+                got - exact(weight)))),
+            "finite": bool(jnp.all(jnp.isfinite(got)))}), flush=True)
+
     for name, fn in (("pairs", pairs), ("scatter", scatter),
                      ("sorted_scatter", sorted_scatter),
                      ("token_list", token_list), ("windows", windows)):
-        got = timed("sum.%s.compact" % name, fn, rows_c, weight, plan_c)
-        gap = float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                    - want.astype(jnp.float32))))
-        print(json.dumps({"what": "sum.%s.compact" % name,
-                          "max_abs_gap_to_pairs": gap}), flush=True)
+        gaps("sum.%s.compact" % name,
+             timed("sum.%s.compact" % name, fn, rows_c, weight, plan_c),
+             weight)
+
+    def by_token(live_only):
+        def gather(rows, plan):
+            order = jnp.argsort(plan["pair_of_row"]).astype(jnp.int32)
+            n = jnp.sum(plan["pair_of_row"] < N * k, dtype=jnp.int32)
+            return moe._take_the_first(n if live_only else rows.shape[0],
+                                       rows, order)
+        return gather
+
+    timed("gather.by_token.every_row", by_token(False), rows_c, plan_c)
+    timed("gather.by_token.live_chunks", by_token(True), rows_c, plan_c)
+    rows_nan = jnp.where((plan_c["pair_of_row"] == N * k)[:, None], jnp.nan,
+                         rows_c)
+    for tile in args.tile or [None]:
+        block = tile and tuple(int(n) for n in tile.split("x"))
+        for kind, w in (("weighted", weight), ("ones", ones)):
+            name = "sum.mxu.%s.%s" % (tile or "x".join(
+                str(n) for n in moe._SUM_BLOCK), kind)
+            gaps(name, timed(name, mxu(block), rows_nan,
+                             None if w is ones else w, plan_c), w)
 
     # the weight's gradient: the pairs' rows against d_out, or the rows
     # against their tokens' d_out (which d_rows gathers anyway)
