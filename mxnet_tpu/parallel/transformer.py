@@ -40,14 +40,20 @@ class TransformerParallel:
     experts all run under a softmax gate. The other kinds — ``"mla"``
     (latent attention with rotary positions), ``"gqa"`` (grouped-query
     attention: each layer its own head count, window and rotary tables
-    under ``arch["gqa"]["layers"]``, a sigmoid gate a head), ``"swiglu"``
-    and ``"moe"`` (sigmoid top-k routing over ``arch["moe"]["n_experts"]``
-    experts of which this rank holds the range ``experts_held``, plus a
-    shared expert) — take their widths from ``arch``, have learned norm
-    weights and a
-    learned final norm, and train on dp meshes. ``remat`` recomputes each
-    layer in the backward pass, but for what it keeps by name
-    (``lm_layers.KEPT_BY_A_RECOMPUTED_LAYER``: the flash output among it).
+    under ``arch["gqa"]["layers"]``, a sigmoid gate a head), ``"ssm"`` (a
+    state-space mixer), ``"gmu"`` and ``"diff"`` (a gated memory unit and
+    differential attention, which may read the scan output or the k and v
+    that an earlier layer made: ``arch["shared"]`` names the makers),
+    ``"swiglu"`` and ``"moe"`` (sigmoid top-k routing over
+    ``arch["moe"]["n_experts"]`` experts of which this rank holds the
+    range ``experts_held``, plus a shared expert) — take their widths
+    from ``arch``, have learned norm weights and a learned final norm, and
+    train on dp meshes; with ``arch["tied_head"]`` the head is the
+    embedding transposed. ``remat`` recomputes each layer in the backward
+    pass, but for what it keeps by name
+    (``lm_layers.KEPT_BY_A_RECOMPUTED_LAYER``: the flash output among it);
+    a shared value is its maker's output and its readers' input, so no
+    reader makes it again.
     """
 
     def __init__(self, mesh, vocab=64, d_model=32, n_heads=4, n_layers=2,
@@ -84,11 +90,20 @@ class TransformerParallel:
         """A model from a published ``config.json``'s keys: MLA layers
         (the DeepSeek-MLA family's names, ``model_type: sarvam_mla``
         among them), the first ``first_k_dense_replace`` with a SwiGLU FFN
-        and the rest routed; or, under ``model_type: laguna``, the model
-        its layer lists describe (:func:`_laguna`). ``num_experts`` counts
+        and the rest routed; under ``model_type: laguna``, the model its
+        layer lists describe (:func:`_laguna`); under ``model_type:
+        phi4flash``, state-space, differential-attention and gated-memory
+        layers by published index (:func:`_phi4flash`). ``num_experts`` counts
         the experts HELD; ``cfg["published"]["num_experts"]`` (the
         router's width) and ``cfg["deployment"]["experts_held"]`` (their
         range) say of which share, and default to all of them."""
+        if cfg.get("model_type") == "phi4flash":
+            layers, arch = _phi4flash(cfg)
+            return cls(mesh, vocab=cfg["vocab_size"],
+                       d_model=cfg["hidden_size"],
+                       n_heads=cfg["num_attention_heads"],
+                       d_ff=cfg["intermediate_size"], dtype=dtype,
+                       layers=layers, arch=arch, remat=remat)
         held = cfg.get("deployment", {}).get(
             "experts_held", (0, cfg["num_experts"]))
         moe = {"n_experts": cfg.get("published", {}).get(
@@ -132,10 +147,11 @@ class TransformerParallel:
         c = self.cfg
         d, f, e = c["d_model"], c["d_ff"], c["n_experts"]
         std = ("normal", 0.02)
-        table = {"embed": ((c["vocab"], d), std),
-                 "out_w": ((d, c["vocab"]), std)}
+        table = {"embed": ((c["vocab"], d), std)}
+        if not self.arch.get("tied_head"):
+            table["out_w"] = ((d, c["vocab"]), std)
         if not self.classic:
-            table["final_norm"] = ((d,), 1.0)
+            table.update(lm_layers.norm_leaves("final_norm", d, self.arch))
         for li, kinds in enumerate(self.layers):
             p = "l%d_" % li
             if kinds[0] == "mha":
@@ -224,9 +240,12 @@ class TransformerParallel:
         return _local_attention(q, k, v, self.mesh, scale=scale,
                                 window=window)
 
-    def _layer(self, li, params, x, collect=None):
-        """Layer ``li`` on (B, T, d)."""
+    def _layer(self, li, params, x, shared=None, collect=None):
+        """Layer ``li`` on (B, T, d), and the shared values it makes
+        (``lm_layers.publishes``: a dict, empty for most layers).
+        ``shared`` holds the values it reads (``lm_layers.reads``)."""
         c = self.cfg
+        made = {}
         attn, ffn = self.layers[li]
         p = "l%d_" % li
         if attn == "mha":
@@ -247,19 +266,32 @@ class TransformerParallel:
         elif attn == "mla":
             x = lm_layers.kept(x + lm_layers.mla_attention(
                 params, li, x, c, self.arch, self._attend), "attn_residual")
-        else:
+        elif attn == "gqa":
             x = lm_layers.kept(x + lm_layers.gqa_attention(
                 params, li, x, self.arch, self._attend), "attn_residual")
+        else:
+            if attn == "ssm":
+                branch, made["memory"] = lm_layers.ssm_mixer(
+                    params, li, x, self.arch)
+            elif attn == "gmu":
+                branch = lm_layers.gated_memory(
+                    params, li, x, shared["memory"], self.arch)
+            else:
+                branch, made["kv"] = lm_layers.diff_attention(
+                    params, li, x, self.arch, self._attend, shared.get("kv"))
+            x = lm_layers.kept(x + branch, "attn_residual")
+            made = {k: made[k]
+                    for k in lm_layers.publishes(li, attn, self.arch)}
         if ffn == "soft_moe":
             # --- MoE FFN: soft top-2-ish gate over ep-sharded experts ---
             with device_scope("l%d/ffn" % li):
-                return x + self._moe_ffn(params, p, x)
+                return x + self._moe_ffn(params, p, x), made
         if ffn == "swiglu":
-            return x + lm_layers.swiglu_ffn(params, li, x, self.arch)
+            return x + lm_layers.swiglu_ffn(params, li, x, self.arch), made
         out, sent = self._routed_ffn(params, li, x)
         if collect is not None:
             collect.append((li, sent))
-        return x + out
+        return x + out, made
 
     def _routed_ffn(self, params, li, x):
         """Layer ``li``'s routed FFN on (B, T, d) and what the router sent
@@ -292,22 +324,34 @@ class TransformerParallel:
 
     def _forward(self, params, tokens, collect=None):
         import jax
+        import jax.numpy as jnp
 
         with device_scope("embed"):
             x = params["embed"][tokens]  # (B, T, d)
+        # what a layer makes for later layers to read rides beside x: a
+        # recomputed maker hands it out as an output, a recomputed reader
+        # takes it as an input, so no reader's backward makes it again and
+        # its gradient is the readers' summed
+        shared = {}
         for li in range(len(self.layers)):
+            read = {k: shared[k] for k in lm_layers.reads(
+                li, self.layers[li][0], self.arch)}
             if self.remat and collect is None:
                 names = [n for n in params if n.startswith("l%d_" % li)]
-                x = jax.checkpoint(lm_layers.recomputed(
-                    lambda sub, x, li=li: self._layer(li, sub, x)),
+                x, made = jax.checkpoint(lm_layers.recomputed(
+                    lambda sub, x, read, li=li: self._layer(
+                        li, sub, x, read)),
                     policy=jax.checkpoint_policies.save_only_these_names(
                         *lm_layers.KEPT_BY_A_RECOMPUTED_LAYER))(
-                    {n: params[n] for n in names}, x)
+                    {n: params[n] for n in names}, x, read)
             else:
-                x = self._layer(li, params, x, collect)
+                x, made = self._layer(li, params, x, read, collect)
+            shared.update(made)
         with device_scope("head_loss"):
-            ln = (_rms_norm(x) if self.classic else lm_layers.rms_norm(
-                x, params["final_norm"], self.arch["rms_norm_eps"]))
+            ln = (_rms_norm(x) if self.classic else lm_layers.norm(
+                params, "final_norm", x, self.arch))
+            if self.arch.get("tied_head"):
+                return jnp.einsum("btd,vd->btv", ln, params["embed"])
             logits = ln @ params["out_w"]
         return logits
 
@@ -677,6 +721,53 @@ def _laguna(cfg, moe):
                         n_shared=1, router_bias=False,
                         d_shared=cfg["shared_expert_intermediate_size"])}
     return layers, arch
+
+
+def _phi4flash(cfg):
+    """(layers, arch) of ``model_type: phi4flash`` (a decoder-hybrid-
+    decoder) from its published keys. By published index ``l`` of
+    ``published.num_hidden_layers`` = 2h layers, ``mb_per_layer`` 2: even
+    ``l <= h`` a state-space layer; odd ``l < h`` differential attention
+    under ``sliding_window``; ``l = h + 1`` differential attention over the
+    whole prefix; then even ``l`` a gated memory unit on layer ``h``'s scan
+    output and odd ``l`` differential attention onto layer ``h + 1``'s k
+    and v. ``deployment.first_layer`` is the published index of the first
+    layer held here (a cut keeps its layers' kinds and ``lambda_init =
+    0.8 - 0.6 exp(-0.3 l)``); the sizes the published keys lack stand
+    under ``assumed``. Every layer's FFN is a SwiGLU, every norm a
+    LayerNorm, the head the embedding transposed."""
+    import math
+
+    total = cfg.get("published", {}).get("num_hidden_layers",
+                                         cfg["num_hidden_layers"])
+    half, every = total // 2, cfg["mb_per_layer"]
+    first = cfg.get("deployment", {}).get("first_layer", 0)
+    assumed = cfg["assumed"]
+    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
+    kinds, diff, shared = [], {}, {}
+    for li in range(cfg["num_hidden_layers"]):
+        pub = first + li        # the layer's published index
+        if pub % every == 0:
+            kinds.append("ssm" if pub <= half else "gmu")
+            if pub == half:
+                shared["memory"] = li
+            continue
+        kinds.append("diff")
+        diff[li] = {"window": cfg["sliding_window"] if pub < half else None,
+                    "cross": pub > half + 1,
+                    "lambda_init": 0.8 - 0.6 * math.exp(-0.3 * pub)}
+        if pub == half + 1:
+            shared["kv"] = li
+    arch = {"layer_norm_eps": cfg["layer_norm_eps"],
+            "tied_head": bool(cfg["tie_word_embeddings"]),
+            "shared": shared,
+            "ssm": {"d_inner": assumed["mamba_expand"] * d,
+                    "d_state": assumed["mamba_d_state"],
+                    "d_conv": assumed["mamba_d_conv"],
+                    "dt_rank": assumed["mamba_dt_rank"]},
+            "diff": {"n_heads": H, "n_kv_heads": cfg["num_key_value_heads"],
+                     "head_dim": d // H, "subln_eps": 1e-5, "layers": diff}}
+    return [(k, "swiglu") for k in kinds], arch
 
 
 def _rms_norm(x):

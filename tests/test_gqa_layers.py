@@ -187,7 +187,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer(ranks):
             mine = dict(params)
             for n in ("moe_wg", "moe_wu", "moe_wd"):
                 mine["l1_" + n] = params["l1_" + n][held[0]:held[1]]
-            out = jax.jit(lambda p, x, rank=rank: rank._layer(1, p, x))(
+            out = jax.jit(lambda p, x, rank=rank: rank._layer(1, p, x)[0])(
                 mine, x)
             total = total + (out - common)
     assert rel(total, uncut) < 1e-5

@@ -143,7 +143,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer(ranks):
             mine = dict(params)
             for n in ("moe_wg", "moe_wu", "moe_wd"):
                 mine["l0_" + n] = params["l0_" + n][held[0]:held[1]]
-            out = jax.jit(lambda p, x, rank=rank: rank._layer(0, p, x))(
+            out = jax.jit(lambda p, x, rank=rank: rank._layer(0, p, x)[0])(
                 mine, x)
             total = total + (out - common)
     assert rel(total, uncut) < 1e-5

@@ -224,6 +224,73 @@ def test_the_routed_block_lowers_for_tpu_in_both_layouts(N, held, E, d, f,
     assert re.search(r"tensor<%dx%dxbf16>" % (budgets[0], d), compact)
 
 
+def _cell_step_lowered(cell):
+    """The whole train step of a ``from_config`` cell of BENCHMARK.json,
+    lowered for the TPU from shapes alone at the cell's own sizes."""
+    import json
+    import os
+
+    from mxnet_tpu.parallel import make_mesh
+    from mxnet_tpu.parallel.transformer import TransformerParallel
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    entry = next(w for w in bench["workloads"] if w["name"] == cell)
+    config = json.load(open(os.path.join(root, next(
+        c["file"] for c in bench["configs"] if c["name"] == entry["config"]))))
+    sizes = json.load(open(os.path.join(
+        root, "perfbench", "workloads", cell + ".json")))["sizes"]
+    dtype = jnp.dtype(config["compute_dtype"])
+    model = TransformerParallel.from_config(
+        make_mesh({"dp": 1}, devices=jax.devices()[:1]), config,
+        dtype=np.dtype(dtype), remat=config.get("recompute") == "per_layer")
+    model.step_fn(lr=1.0)
+    params = {n: jax.ShapeDtypeStruct(shape, dtype)
+              for n, (shape, _) in model.param_table().items()}
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32)
+    return model._step_jit.trace(params, tokens, tokens, 1.0).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_the_state_space_cells_step_lowers_for_tpu(as_on_the_chip):
+    """``phi4flash_train_t8192_b1`` at 8,192 tokens and the published
+    widths: the scan's kernels in both passes (three state-space layers),
+    the differential layers' flash calls forward and backward, causal and
+    windowed, and no array of a state a token (8192 x 5120 x 16) anywhere
+    in the step."""
+    text = _cell_step_lowered("phi4flash_train_t8192_b1")
+    names = re.findall(r'kernel_name = "([^"]*)"', text)
+    # once a state-space layer forward, and not again in its recomputation
+    # (the layer keeps the scan's output and its chunk states by name)
+    assert names.count("ssm_scan_fwd") == 3
+    assert names.count("ssm_scan_bwd") == 3
+    # the flash calls are jitted functions, written once a shape
+    assert {"flash_attention_fwd", "flash_attention_window_fwd",
+            "flash_attention_bwd_dqkv",
+            "flash_attention_window_bwd_dqkv"} <= set(names)
+    assert not re.search(r"8192x5120x16x|5120x16x8192x|8192x16x5120x", text)
+
+
+@pytest.mark.parametrize("tokens", [8192, 1000])
+def test_scan_kernels_lower_for_tpu(tokens):
+    """The cell's layer (5120 channels, 16 states) at its length and at
+    one that is no multiple of the chunk (padded with delta = 0)."""
+    from mxnet_tpu.parallel.ssm_scan import ssm_scan
+
+    def loss(u, delta, A, Bm, Cm, D):
+        return jnp.sum(ssm_scan(u, delta, A, Bm, Cm, D, interpret=False)
+                       .astype(jnp.float32))
+
+    E, N = 5120, 16
+    avals = (_aval((1, tokens, E), "bfloat16"),
+             _aval((1, tokens, E), "float32"), _aval((E, N), "float32"),
+             _aval((1, tokens, N), "bfloat16"),
+             _aval((1, tokens, N), "bfloat16"), _aval((E,), "bfloat16"))
+    assert _tpu_kernels(jax.grad(loss, argnums=tuple(range(6))),
+                        *avals) == ["ssm_scan_fwd", "ssm_scan_bwd"]
+
+
 def test_flash_declines_a_length_with_no_legal_block():
     # 1100 > the backward bound 1024 and no multiple of 128 divides it: a
     # static decline to the dense formula, not a lowering error
@@ -359,6 +426,28 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
                     _aval((1,), "int32"), _aval((R,), "float32"))
         assert compiled_calls(call, *operands) == 1
         assert compiled_calls(call, *operands[:3]) == 1
+    # the selective scan at the cell phi4flash_train_t8192_b1's layer
+    # (5120 channels, 16 states, 8,192 steps): the backward's chunk of
+    # states in VMEM under the kernels' own limit is Mosaic's to refuse —
+    # and its differential flash calls: q/k 64 wide, V 128 wide, 20 query
+    # head pairs over 10, causal and under the window of 512
+    from mxnet_tpu.parallel.ssm_scan import ssm_scan
+
+    def scan_loss(u, delta, A, Bm, Cm, D):
+        return jnp.sum(ssm_scan(u, delta, A, Bm, Cm, D, interpret=False)
+                       .astype(jnp.float32))
+
+    small = _aval((1, 8192, 16), "bfloat16")
+    assert compiled_calls(
+        jax.grad(scan_loss, argnums=tuple(range(6))),
+        _aval((1, 8192, 5120), "bfloat16"), _aval((1, 8192, 5120), "float32"),
+        _aval((5120, 16), "float32"), small, small,
+        _aval((5120,), "bfloat16")) == 2
+    q, k, v = (_aval((1, heads, 8192, width), "bfloat16")
+               for heads, width in ((20, 64), (10, 64), (10, 128)))
+    for window in (None, 512):
+        assert compiled_calls(jax.grad(_grouped_loss(window),
+                                       argnums=(0, 1, 2)), q, k, v) == 2
     epilogue = (("bias",), ("act", "relu"))
     assert compiled_calls(
         lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue),
