@@ -97,12 +97,16 @@ def _compiler_params(semantics=("parallel", "parallel", "arbitrary"),
 
 
 def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
-                     Dv=None):
+                     Dv=None, rope_half=None):
     """Live VMEM of one grid step (input and output tiles double-buffered
     by the pipeline, fp32 accumulators single-buffered). With ``T``, the
     fused backward: the whole head's fp32 dq scratch (T, D) and its
     resident (1, T, D) output block on top of the dk/dv pass's tiles.
-    ``D`` is the q/k width; ``Dv`` the v/o width where it differs."""
+    ``D`` is the q/k width; ``Dv`` the v/o width where it differs. With
+    ``rope_half`` (the rotary tables' width) the rotation in the kernels:
+    a cos and a sin tile at the q block and at the k block (float32, their
+    lanes padded to 128), the rotated q and k tiles in scratch, and the
+    rotation's float32 intermediates."""
     db = dtype_bytes
     Dv = D if Dv is None else Dv
     if not backward:
@@ -123,6 +127,11 @@ def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
     # and dp^T; the forward works its tile through _FWD_SUB_ROWS rows at
     # a time
     inter = (bq if backward else min(bq, _FWD_SUB_ROWS)) * bk * 4 * 2
+    if rope_half:
+        lanes = -(-rope_half // LANES) * LANES
+        tiles += 2 * (bq + bk) * lanes * 4      # cos, sin at q and at k
+        scratch += (bq + bk) * D * db           # the rotated q and k
+        inter += 4 * max(bq, bk) * D * 4        # x, its swap, C, S (fp32)
     return 2 * tiles + scratch + inter
 
 
@@ -135,13 +144,16 @@ def _pick_block(T, bound, interpret):
     return aligned_block(T, bound, 1 if interpret else LANES)
 
 
-def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None, group=1):
+def _bwd_is_fused(T, D, bq, bk, itemsize, Dv=None, group=1,
+                  rope_half=None):
     """The static rule that picks the backward: one fused pass while the
     whole head's dq — of ``group`` query heads to a k/v head, the whole
     group's — fits the VMEM budget beside the tiles, else two. ``D`` is
-    the q/k width, ``Dv`` the v/o width where it differs."""
+    the q/k width, ``Dv`` the v/o width where it differs, ``rope_half``
+    the rotary tables' width where the kernels rotate q and k."""
     return flash_vmem_bytes(
-        bq, bk, D, itemsize, backward=True, T=group * T, Dv=Dv) <= (
+        bq, bk, D, itemsize, backward=True, T=group * T, Dv=Dv,
+        rope_half=rope_half) <= (
         _FUSED_BWD_VMEM_BUDGET if group == 1
         else _GROUPED_FUSED_BWD_VMEM_BUDGET)
 
@@ -265,8 +277,52 @@ def _sub_block(b, want, interpret):
     return aligned_block(b, want, LANES) or b
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-            scale, causal, interior, sub, window=None):
+def _swap_halves(x, start, half):
+    """``x`` (rows, width) float32 with the two halves of the rotated
+    channels ``start .. start + 2 half`` swapped, by lane rolls on the XLU
+    (a 0/1 permutation matmul on the MXU was no faster: PERF.md section 6
+    PR 37); the lanes outside them carry what the rolls bring, which the
+    rotation multiplies by 0."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    width = x.shape[1]
+    if 2 * half == width:
+        return pltpu.roll(x, half, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane < start + half, pltpu.roll(x, width - half, 1),
+                     pltpu.roll(x, half, 1))
+
+
+def _rotate(x, cos, sin, start, transpose=False):
+    """``x`` (rows, width) rotated, in float32, by the rows' angles on the
+    channels ``start .. start + 2 half`` with (rows, half) tables, the
+    half-split pairing of ``lm_layers.apply_rope``: ``R x = C x + S
+    swap(x)`` with ``C`` cos on both halves and 1 elsewhere, ``S`` -sin on
+    the first half, sin on the second and 0 elsewhere. ``transpose``:
+    ``R^T x = C x - S swap(x)``, what a gradient with respect to the
+    unrotated operand takes."""
+    import jax.numpy as jnp
+
+    rows, width = x.shape
+    half = cos.shape[1]
+    rest = width - start - 2 * half
+
+    def around(first, second, fill):
+        return jnp.concatenate(
+            ([jnp.full((rows, start), fill, jnp.float32)] if start else [])
+            + [first, second]
+            + ([jnp.full((rows, rest), fill, jnp.float32)] if rest else []),
+            axis=1)
+
+    x = x.astype(jnp.float32)
+    c, s = around(cos, cos, 1.0), around(-sin, sin, 0.0)
+    swapped = _swap_halves(x, start, half) * s
+    return x * c - swapped if transpose else x * c + swapped
+
+
+def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
     """One (batch*head, q_block, k_block) forward grid step.
 
     Dots take q, k, v as loaded (bf16 operands, fp32 accumulation; fp32
@@ -281,11 +337,21 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     and the -inf mask needs no guard. Under a ``window`` that no longer
     holds (the first live tile of a q block may lie wholly before a later
     row's window), so there the exponentials take a running max of 0 in
-    place of -inf: the row's sums stay 0 until its first visible key."""
+    place of -inf: the row's sums stay 0 until its first visible key.
+
+    With ``rope`` (the first rotated channel), q and k arrive unrotated
+    with their rows' cos and sin tiles: q is rotated once a q block, at
+    its first step, and k on every live step, each into scratch that the
+    tile then reads in their place."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if rope is None:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        (q_in, k_in, v_ref, cos_q, sin_q, cos_k, sin_k, o_ref, lse_ref,
+         acc_ref, m_ref, l_ref, q_ref, k_ref) = refs
     step = pl.program_id(2)
     q_idx = pl.program_id(1)
     bq = q_ref.shape[1]
@@ -300,8 +366,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
+        if rope is not None:
+            q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
+                               rope).astype(q_ref.dtype)
 
     def _tile(masked):
+        if rope is not None:
+            k_ref[0] = _rotate(k_in[0], cos_k[...], sin_k[...],
+                               rope).astype(k_ref.dtype)
         # the tile is worked through ``sub`` rows of q at a time. On a
         # tile that sits ON the diagonal (bq == bk) a chunk's keys end
         # with its own last row: dead sub-blocks are not computed
@@ -344,8 +416,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l))[:, 0]
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, acc_ref, *, scale, causal, interior, window=None):
+def _bwd_dq_kernel(*refs, scale, causal, interior, window=None, rope=None):
     """dq pass of the two-pass backward: grid (batch*head, q_block,
     k_block); k is the sequential axis, dq accumulates in fp32 scratch
     across it.
@@ -355,11 +426,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     is the forward's true row logsumexp), ds = p * (do.v^T - delta) with
     delta = rowsum(do * o) (+ any lse cotangent, folded into delta by
     the caller). lse/delta arrive as lane-dense (1, bq) rows of
-    (B*H, 1, T) arrays and turn into columns here."""
+    (B*H, 1, T) arrays and turn into columns here.
+
+    With ``rope``: q is rotated once a q block into scratch, k on every
+    live step, and the accumulated dq — the gradient of the rotated q —
+    is turned into the unrotated q's by the transposed rotation, once,
+    before it is written."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if rope is None:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+         acc_ref) = refs
+    else:
+        (q_in, k_ref, v_ref, do_ref, lse_ref, delta_ref, cos_q, sin_q,
+         cos_k, sin_k, dq_ref, acc_ref, q_ref) = refs
     step = pl.program_id(2)
     q_idx = pl.program_id(1)
     bq = q_ref.shape[1]
@@ -370,9 +452,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if rope is not None:
+            q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
+                               rope).astype(q_ref.dtype)
 
     def _tile(masked):
         k = k_ref[0]
+        if rope is not None:
+            k = _rotate(k, cos_k[...], sin_k[...], rope).astype(k.dtype)
         s = jax.lax.dot_general(q_ref[0], k, _NT,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
@@ -390,12 +477,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         # ds/dq_i = scale * sum_j ds_ij k_j
-        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        dq = acc_ref[...] * scale
+        if rope is not None:
+            dq = _rotate(dq, cos_q[...], sin_q[...], rope, transpose=True)
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *rest, scale, causal, interior, fused,
-                    sub, window=None, group=1, n_q=None, n_band=None):
+def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
+                    group=1, n_q=None, n_band=None, rope=None):
     """dk/dv pass — and, ``fused``, the whole backward: grid (batch*head,
     k_block, q_block); q is the sequential axis, dk and dv accumulate in
     fp32 scratch across it.
@@ -417,15 +506,28 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     Fused, the dq scratch and block then hold the group's heads one
     after another, (group * T, D). Under a ``window`` a head's part of
     the sequential axis is the ``n_band`` q blocks from the k block's
-    first live one on, not all ``n_q``."""
+    first live one on, not all ``n_q``.
+
+    With ``rope``: k is rotated once a k block, at its first step, and q
+    on every live step, each into scratch that the tile reads in their
+    place; the accumulated dk — the rotated k's gradient — is turned into
+    the unrotated k's by the transposed rotation once, before it is
+    written, and fused, each q block's rows of dq likewise at the last k
+    block that reaches them."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    rest = refs[6:]
+    if rope is not None:
+        cos_q, sin_q, cos_k, sin_k = rest[:4]
+        q_in, k_in, q_ref, k_ref = q_ref, k_ref, *rest[-2:]
+        rest = rest[4:-2]
     if fused:
-        dq_ref, dk_acc, dv_acc, dq_acc = rest
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = rest
     else:
-        dk_acc, dv_acc = rest
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     # the sequential axis' step, and the q block of its head it stands for
     step = pl.program_id(2)
     kv_idx = pl.program_id(1)
@@ -443,6 +545,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if rope is not None:
+            k_ref[0] = _rotate(k_in[0], cos_k[...], sin_k[...],
+                               rope).astype(k_ref.dtype)
 
     if fused:
         @pl.when((step == 0) & (kv_idx == 0))
@@ -478,6 +583,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds_t, k, _TN, preferred_element_type=jnp.float32)
 
     def _tile(masked):
+        if rope is not None:
+            q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
+                               rope).astype(q_ref.dtype)
         # on a tile that sits ON the diagonal (bq == bk) a key sub-chunk
         # is seen only from its own first row on: the dead sub-blocks
         # are not computed
@@ -486,13 +594,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 _chunk(True, slice(first, first + sub), first)
         else:
             _chunk(masked, slice(0, bk), 0)
+        if fused and rope is not None:
+            # the last k block this q block sees: its rows of dq are whole
+            @pl.when(kv_idx == (_last_live_k(q_idx, bq, bk) if causal
+                                else pl.num_programs(1) - 1))
+            def _unrotate_dq():
+                rows = pl.ds(pl.multiple_of(dq_block * bq, bq), bq)
+                dq_acc[rows, :] = _rotate(dq_acc[rows, :], cos_q[...],
+                                          sin_q[...], rope, transpose=True)
 
     _on_live_tiles(q_idx, kv_idx, bq, bk, causal, interior, _tile, window,
                    in_range=True if window is None else q_idx < n_q)
 
     @pl.when(step == last_q)
     def _finish():
-        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dk = dk_acc[...] * scale
+        if rope is not None:
+            dk = _rotate(dk, cos_k[...], sin_k[...], rope, transpose=True)
+        dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     if fused:
@@ -509,20 +628,22 @@ def _kernel_name(window, part):
 
 @functools.lru_cache(maxsize=64)
 def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
-                  group=1):
+                  group=1, rope=None):
     """The jitted forward ``pallas_call`` of one static configuration, on
     (B*H, T, D) operands. ONE function object a configuration: every
     layer of a model that calls it on the same shapes shares one trace
     and one lowering of the kernel body, where a step of 24 layers would
     lower 24 identical Mosaic bodies — set-up time, warm cache or cold.
     With ``group`` query heads to a k/v head, k and v are (B*H/group, T,
-    .) and a q head's index map names its group's k/v blocks."""
+    .) and a q head's index map names its group's k/v blocks. With
+    ``rope`` (the first rotated channel) it takes the (T, half) cos and
+    sin tables after v, each read at the q block and at the k block."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def forward(qf, kf, vf):
+    def forward(qf, kf, vf, *tables):
         BH, T, D = qf.shape
         Dv = vf.shape[-1]       # v and o may be narrower than q and k
         # a dead causal step names the block of the last live one: no DMA
@@ -543,17 +664,31 @@ def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
                  if interpret or block_k % LANES == 0 else block_k)
         interior = (_has_interior(T, block_q, block_k) if window is None
                     else _has_window_interior(T, block_q, block_k, window))
+        in_specs = [
+            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_k, Dv), kv_map),
+        ]
+        scratch = [
+            pltpu.VMEM((block_q, Dv), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+        ]
+        if rope is not None:
+            half = tables[0].shape[1]
+            in_specs += (
+                [pl.BlockSpec((block_q, half), lambda b, i, j: (i, 0))] * 2
+                + [pl.BlockSpec((block_k, half),
+                                lambda b, i, j: (kv_map(b, i, j)[1], 0))] * 2)
+            scratch += [pltpu.VMEM((1, block_q, D), qf.dtype),
+                        pltpu.VMEM((1, block_k, D), kf.dtype)]
         return pallas_call(
             functools.partial(_kernel, scale=scale, causal=causal, sub=sub,
-                              interior=interior, window=window),
+                              interior=interior, window=window, rope=rope),
             grid=(BH, T // block_q,
                   T // block_k if window is None else _band_blocks(
                       T, block_q, block_k, window, of_q=True)),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_k, Dv), kv_map),
-            ],
+            in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -562,29 +697,26 @@ def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
                 jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype),
                 jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, Dv), jnp.float32),
-                pltpu.VMEM((block_q, lanes), jnp.float32),
-                pltpu.VMEM((block_q, lanes), jnp.float32),
-            ],
+            scratch_shapes=scratch,
             interpret=interpret,
             compiler_params=_compiler_params(grouped=group > 1),
             name=_kernel_name(window, "fwd"),
-        )(qf, kf, vf)
+        )(qf, kf, vf, *tables, *tables)
 
     return jax.jit(forward)
 
 
 @functools.lru_cache(maxsize=64)
 def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
-                   group=1):
+                   group=1, rope=None):
     """The jitted backward of one static configuration — the fused pass,
     or the dk/dv and dq passes — on (B*H, T, D) operands and (B*H, 1, T)
     lse/delta rows; returns (dq, dk, dv). One function object a
     configuration, as :func:`_forward_call`. With ``group`` query heads
     to a k/v head, k, v, dk and dv are (B*H/group, T, .): the dk/dv
     pass's batch axis counts k/v heads and its sequential axis walks the
-    group's heads one after another."""
+    group's heads one after another. With ``rope`` the (T, half) cos and
+    sin tables follow the delta rows, as :func:`_forward_call` takes them."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -630,6 +762,15 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
 
         row_spec = pl.BlockSpec(
             (1, 1, bq), lambda b, j, i: (q_head(b, i), 0, q_block(j, i)))
+        in_specs = [q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
+                    row_spec, row_spec]
+        tables = operands[6:]
+        if rope is not None:
+            half = tables[0].shape[1]
+            in_specs += (
+                [pl.BlockSpec((bq, half),
+                              lambda b, j, i: (q_block(j, i), 0))] * 2
+                + [pl.BlockSpec((bk, half), lambda b, j, i: (j, 0))] * 2)
         out_specs = [k_spec(D), k_spec(Dv)]
         out_shape = [jax.ShapeDtypeStruct(kf.shape, kf.dtype),
                      jax.ShapeDtypeStruct(vf.shape, vf.dtype)]
@@ -644,14 +785,17 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
             out_shape.append(jax.ShapeDtypeStruct(
                 (BH // group, group * T, D), qf.dtype))
             scratch.append(pltpu.VMEM((group * T, D), jnp.float32))
+        if rope is not None:
+            scratch += [pltpu.VMEM((1, bq, D), qf.dtype),
+                        pltpu.VMEM((1, bk, D), kf.dtype)]
         outs = pallas_call(
             functools.partial(
                 _bwd_dkv_kernel, scale=scale, causal=causal, fused=fused,
                 interior=interior, window=window, group=group, n_q=n_q,
-                n_band=n_band, sub=_sub_block(bk, _BWD_SUB_KEYS, interpret)),
+                n_band=n_band, sub=_sub_block(bk, _BWD_SUB_KEYS, interpret),
+                rope=rope),
             grid=(BH // group, T // bk, group * n_band),
-            in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
-                      row_spec, row_spec],
+            in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
@@ -661,7 +805,7 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
                 else ("parallel", "parallel", "arbitrary"),
                 grouped=group > 1),
             name=_kernel_name(window, "bwd_dqkv" if fused else "bwd_dkv"),
-        )(*operands)
+        )(*operands[:6], *tables, *tables)
         if fused:
             dk, dv, dq = outs
             return (dq if group == 1 else dq.reshape(qf.shape)), dk, dv
@@ -685,20 +829,28 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
                                  k_block(i, j), 0))
 
         row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        in_specs = [q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
+                    row_spec, row_spec]
+        scratch = [pltpu.VMEM((bq, D), jnp.float32)]
+        if rope is not None:
+            in_specs += (
+                [pl.BlockSpec((bq, half), lambda b, i, j: (i, 0))] * 2
+                + [pl.BlockSpec((bk, half),
+                                lambda b, i, j: (k_block(i, j), 0))] * 2)
+            scratch.append(pltpu.VMEM((1, bq, D), qf.dtype))
         dq = pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                              interior=interior, window=window),
+                              interior=interior, window=window, rope=rope),
             grid=(BH, T // bq, T // bk if window is None else _band_blocks(
                 T, bq, bk, window, of_q=True)),
-            in_specs=[q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv),
-                      row_spec, row_spec],
+            in_specs=in_specs,
             out_specs=q_spec(D),
             out_shape=jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            scratch_shapes=scratch,
             interpret=interpret,
             compiler_params=_compiler_params(grouped=group > 1),
             name=_kernel_name(window, "bwd_dq"),
-        )(*operands)
+        )(*operands[:6], *tables, *tables)
         return dq, dk, dv
 
     return jax.jit(backward)
@@ -706,7 +858,8 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, block_q_bwd=None, block_k_bwd=None,
-                    interpret=False, return_lse=False, window=None):
+                    interpret=False, return_lse=False, window=None,
+                    rope=None):
     """Blocked attention; q: (batch, heads, T, d), k: (batch, kv_heads, T,
     d), v: (batch, kv_heads, T, dv) — ``dv`` may differ from ``d``
     (latent attention's 192/128), and the output then has v's width;
@@ -720,6 +873,17 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     j <= i``, its own among them. The kernels then walk the band of live
     tiles alone, carry ``window`` in their names, and take the window's
     tile constants; a window at or past ``T`` is the causal mask.
+
+    ``rope``: ``(cos, sin)`` or ``(cos, sin, start)``, the (T, half)
+    float32 tables of ``lm_layers.rope_tables``: q and k arrive UNROTATED
+    and the kernels rotate channels ``start .. start + 2 half`` of each
+    tile (half-split pairing, float32, rounded to the operands' dtype
+    before the dot: ``lm_layers.apply_rope``'s arithmetic) — q once a q
+    block and k every step in the forward, k once a k block and q every
+    step in the backward — and turn dq and dk into the gradients of the
+    unrotated q and k by the transposed rotation before writing them.
+    The rotated q and k never reach HBM. Off the kernels (a length with
+    no legal tile) the same rotation precedes the dense formula.
 
     Block arguments are upper bounds; the largest TPU-legal tiles at or
     below them are used (``_pick_block``: the whole sequence or a
@@ -760,6 +924,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         counter("flash_attention.windowed").inc()
     if group > 1:
         counter("flash_attention.kv_group").inc(group)
+    tables, start = (), None
+    if rope is not None:
+        cos, sin, start = (tuple(rope) + (0,))[:3]
+        tables = (jnp.asarray(cos, jnp.float32),
+                  jnp.asarray(sin, jnp.float32))
+        if (tables[0].shape != (T, tables[0].shape[1]) or tables[1].shape
+                != tables[0].shape or start + 2 * tables[0].shape[1] > D):
+            raise ValueError("flash_attention: rotary tables %s from "
+                             "channel %d for q/k of (T, D) = (%d, %d)"
+                             % (tables[0].shape, start, T, D))
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
     # block sizes are upper bounds; _pick_block turns each into a tile
     # the TPU lowering accepts or declines (no 128-multiple divisor
@@ -772,31 +946,37 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     block_q_bwd = _pick_block(T, int(block_q_bwd or bwd_bound), interpret)
     block_k_bwd = _pick_block(T, int(block_k_bwd or bwd_bound), interpret)
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
+        if rope is not None:
+            q, k = rotated(q, rope), rotated(k, rope)
         out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale,
                                    window=window)
         return (out, lse) if return_lse else out
+    if rope is not None:
+        counter("flash_attention.rotary").inc()
     def _flat(a):
         return a.reshape(-1, T, a.shape[-1])
 
-    def _flash_fwd_impl(q, k, v):
+    def _flash_fwd_impl(q, k, v, tables):
         if Dv != D:
             counter("flash_attention.dqk_ne_dv").inc()
         out, lse = _forward_call(causal, scale, block_q, block_k, interpret,
-                                 window, group)(*(_flat(a) for a in (q, k, v)))
+                                 window, group, start)(
+            *(_flat(a) for a in (q, k, v)), *tables)
         # named for a recomputing caller's policy: kept, the backward
         # pass does not run this kernel again (an identity otherwise)
         return (checkpoint_name(out.reshape(B, H, T, Dv), "flash_out"),
                 checkpoint_name(lse.reshape(B, H, T), "flash_lse"))
 
-    def _flash_bwd_impl(q, k, v, o, lse, do, dlse):
+    def _flash_bwd_impl(q, k, v, tables, o, lse, do, dlse):
         # delta_i = rowsum(do_i * o_i); an lse cotangent adds
         # glse_i * p_ij to ds_ij, which folds in as delta - glse
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
-        fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd,
-                              q.dtype.itemsize, Dv=Dv, group=group)
+        fused = _bwd_is_fused(
+            T, D, block_q_bwd, block_k_bwd, q.dtype.itemsize, Dv=Dv,
+            group=group, rope_half=tables[0].shape[1] if tables else None)
         counter("flash_attention.bwd_fused" if fused
                 else "flash_attention.bwd_two_pass").inc()
         # per-row residuals ride as lane-dense rows of (B*H, 1, T)
@@ -804,26 +984,28 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         # TPU block shape. The dq pass turns its row into a column
         # in-kernel; the dk/dv and fused passes use it as a row
         grads = _backward_call(causal, scale, block_q_bwd, block_k_bwd,
-                               fused, interpret, window, group)(
+                               fused, interpret, window, group, start)(
             *(_flat(a) for a in (q, k, v, do)),
-            lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T))
-        return tuple(g.reshape(a.shape) for g, a in zip(grads, (q, k, v)))
+            lse.reshape(B * H, 1, T), delta.reshape(B * H, 1, T), *tables)
+        # the tables are constants of the caller's: no gradient of theirs
+        return (tuple(g.reshape(a.shape) for g, a in zip(grads, (q, k, v)))
+                + (tuple(jnp.zeros_like(t) for t in tables),))
 
     @jax.custom_vjp
-    def _flash(q, k, v):
-        return _flash_fwd_impl(q, k, v)
+    def _flash(q, k, v, tables):
+        return _flash_fwd_impl(q, k, v, tables)
 
-    def _fwd(q, k, v):
-        out, lse = _flash_fwd_impl(q, k, v)
+    def _fwd(q, k, v, tables):
+        out, lse = _flash_fwd_impl(q, k, v, tables)
         # O(T)-per-head residuals — no T x T tensor survives the forward
-        return (out, lse), (q, k, v, out, lse)
+        return (out, lse), (q, k, v, tables, out, lse)
 
     def _bwd(res, g):
         return _flash_bwd_impl(*res, *g)
 
     _flash.defvjp(_fwd, _bwd)
 
-    out, lse = _flash(q, k, v)
+    out, lse = _flash(q, k, v, tables)
     return (out, lse) if return_lse else out
 
 
@@ -994,6 +1176,21 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, lengths,
     else:
         _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+def rotated(x, rope):
+    """``x`` (..., T, D) with the channels ``rope`` names rotated as
+    :func:`flash_attention` rotates them in its kernels, in XLA
+    (``lm_layers.apply_rope`` on the range): the dense formulas' path."""
+    import jax.numpy as jnp
+
+    from .lm_layers import apply_rope
+
+    cos, sin, start = (tuple(rope) + (0,))[:3]
+    stop = start + 2 * cos.shape[-1]
+    parts = (x[..., :start], apply_rope(x[..., start:stop], cos, sin),
+             x[..., stop:])
+    return jnp.concatenate([a for a in parts if a.shape[-1]], axis=-1)
 
 
 def _dense_with_lse(q, k, v, causal=False, scale=None, window=None):

@@ -285,7 +285,9 @@ def kept(value, name):
 # --- forwards ----------------------------------------------------------
 def mla_attention(params, li, x, cfg, arch, attend):
     """Latent attention's residual branch on (B, T, d); ``attend(q, k, v,
-    scale)`` takes (B, H, T, .) operands."""
+    scale, rope=)`` takes (B, H, T, .) operands, q and k unrotated, and
+    rotates their last ``qk_rope_head_dim`` channels by ``rope``'s tables
+    (``flash_attention``'s operand)."""
     import jax.numpy as jnp
 
     p = "l%d_" % li
@@ -297,24 +299,21 @@ def mla_attention(params, li, x, cfg, arch, attend):
     eps = arch["rms_norm_eps"]
     with device_scope("l%d/attn/proj" % li):
         h = rms_norm(x, params[p + "attn_norm"], eps)
-        q = jnp.einsum("btd,dhe->bhte", h,
-                       params[p + "wq"].reshape(-1, H, dn + dr))
+        q = kept(jnp.einsum("btd,dhe->bhte", h,
+                            params[p + "wq"].reshape(-1, H, dn + dr)),
+                 "mla_q")                  # unrotated: the kernel rotates
         kva = kept(h @ params[p + "wkva"], "mla_kva")
         c_kv = rms_norm(kva[..., :r], params[p + "kv_norm"], eps)
         kvb = jnp.einsum("btr,rhe->bhte", c_kv,
                          params[p + "wkvb"].reshape(r, H, dn + dv))
-    with device_scope("l%d/attn/rope" % li):
-        cos, sin = rope_tables(T, dr, arch["rope"])
-        q = kept(jnp.concatenate(
-            [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1),
-            "mla_q")                       # as the kernel takes it
-        k_rope = apply_rope(kva[..., r:], cos, sin)        # (B, T, dr)
         k = jnp.concatenate(
             [kvb[..., :dn],
-             jnp.broadcast_to(k_rope[:, None], (B, H, T, dr))], axis=-1)
+             jnp.broadcast_to(kva[:, None, :, r:], (B, H, T, dr))], axis=-1)
         v = kvb[..., dn:]
+    cos, sin = rope_tables(T, dr, arch["rope"])
     with device_scope("l%d/attn/flash" % li):
-        att = attend(q, k, v, mla_scale(arch))             # (B, H, T, dv)
+        att = attend(q, k, v, mla_scale(arch),
+                     rope=(cos, sin, dn))                  # (B, H, T, dv)
     with device_scope("l%d/attn/out" % li):
         return jnp.einsum("bhte,hed->btd", att,
                           params[p + "wo"].reshape(H, dv, -1))
@@ -326,7 +325,9 @@ def gqa_attention(params, li, x, arch, attend):
     on the first ``partial_rotary_factor`` of each head's channels, its
     window (None: causal), and one sigmoid gate a query head on the
     head's output before the out-projection. ``attend(q, k, v, scale,
-    window)`` takes q (B, H, T, hd) and k, v (B, Hkv, T, hd)."""
+    window, rope=)`` takes q (B, H, T, hd) and k, v (B, Hkv, T, hd), q
+    and k unrotated, and rotates their first ``rot`` channels by
+    ``rope``'s tables (``flash_attention``'s operand)."""
     import jax
     import jax.numpy as jnp
 
@@ -337,24 +338,18 @@ def gqa_attention(params, li, x, arch, attend):
     T = x.shape[1]
     with device_scope("l%d/attn/proj" % li):
         h = rms_norm(x, params[p + "attn_norm"], arch["rms_norm_eps"])
-        q = jnp.einsum("btd,dhe->bhte", h, params[p + "wq"].reshape(-1, H, hd))
-        k = jnp.einsum("btd,dhe->bhte", h,
-                       params[p + "wk"].reshape(-1, Hkv, hd))
+        q = kept(jnp.einsum("btd,dhe->bhte", h,
+                            params[p + "wq"].reshape(-1, H, hd)), "gqa_q")
+        k = kept(jnp.einsum("btd,dhe->bhte", h,
+                            params[p + "wk"].reshape(-1, Hkv, hd)), "gqa_k")
         v = kept(jnp.einsum("btd,dhe->bhte", h,
                             params[p + "wv"].reshape(-1, Hkv, hd)), "gqa_v")
-    with device_scope("l%d/attn/rope" % li):
-        rot = int(hd * mine["rope"].get("partial_rotary_factor", 1))
-        cos, sin = rope_tables(T, rot, mine["rope"])
-
-        def rotate(a):      # the first ``rot`` channels; the rest pass
-            if rot == hd:
-                return apply_rope(a, cos, sin)
-            return jnp.concatenate(
-                [apply_rope(a[..., :rot], cos, sin), a[..., rot:]], axis=-1)
-
-        q, k = kept(rotate(q), "gqa_q"), kept(rotate(k), "gqa_k")
+    # the first ``rot`` channels of q and k turn; the rest pass
+    rot = int(hd * mine["rope"].get("partial_rotary_factor", 1))
+    cos, sin = rope_tables(T, rot, mine["rope"])
     with device_scope("l%d/attn/flash" % li):
-        att = attend(q, k, v, hd ** -0.5, mine["window"])   # (B, H, T, hd)
+        att = attend(q, k, v, hd ** -0.5, mine["window"],
+                     rope=(cos, sin, 0))                    # (B, H, T, hd)
     if g["gate"]:
         with device_scope("l%d/attn/gate" % li):
             gate = jax.lax.logistic(kept(

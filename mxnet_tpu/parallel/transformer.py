@@ -236,9 +236,9 @@ class TransformerParallel:
                                 params[p + "w2"])
         return jnp.einsum("bted,bte->btd", expert_out, gate)
 
-    def _attend(self, q, k, v, scale, window=None):
+    def _attend(self, q, k, v, scale, window=None, rope=None):
         return _local_attention(q, k, v, self.mesh, scale=scale,
-                                window=window)
+                                window=window, rope=rope)
 
     def _layer(self, li, params, x, shared=None, collect=None):
         """Layer ``li`` on (B, T, d), and the shared values it makes
@@ -639,12 +639,13 @@ def _prefill_attention(q, k, v):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _local_attention(q, k, v, mesh=None, scale=None, window=None):
+def _local_attention(q, k, v, mesh=None, scale=None, window=None, rope=None):
     """Non-sequence-sharded attention: the Pallas flash kernel on TPU
     (forward AND backward tiled — no T x T HBM materialization in
     training either), XLA reference elsewhere. k and v may have fewer
-    heads than q (grouped-query), and ``window`` bounds how far back a
-    query sees (``flash_attention``).
+    heads than q (grouped-query), ``window`` bounds how far back a
+    query sees, and ``rope`` rotates q and k — in the kernels on the TPU,
+    in XLA before the formula elsewhere (``flash_attention``).
 
     pallas_call has no GSPMD partitioning rule, so on a dp/tp-sharded
     mesh the kernel runs under shard_map: attention is embarrassingly
@@ -660,7 +661,7 @@ def _local_attention(q, k, v, mesh=None, scale=None, window=None):
 
         if mesh is None or mesh.devices.size == 1:
             return flash_attention(q, k, v, causal=True, scale=scale,
-                                   window=window)
+                                   window=window, rope=rope)
         axes = dict(mesh.shape)
         ndp, ntp = axes.get("dp", 1), axes.get("tp", 1)
         sharded = {a for a, s in axes.items() if s > 1}
@@ -673,10 +674,15 @@ def _local_attention(q, k, v, mesh=None, scale=None, window=None):
                      "tp" if ntp > 1 else None, None, None)
             fn = shard_map(
                 lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                                scale=scale, window=window),
+                                                scale=scale, window=window,
+                                                rope=rope),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)
             return fn(q, k, v)
+    if rope is not None:
+        from .flash_attention import rotated
+
+        q, k = rotated(q, rope), rotated(k, rope)
     if window is None and k.shape[1] == H:
         from .ring_attention import attention_reference
 

@@ -21,6 +21,7 @@ sys.path.insert(0, ROOT)
 
 from mxnet_tpu import observability as obs  # noqa: E402
 from mxnet_tpu.parallel import lm_layers, make_mesh, moe  # noqa: E402
+from mxnet_tpu.parallel.flash_attention import rotated  # noqa: E402
 from mxnet_tpu.parallel.transformer import TransformerParallel  # noqa: E402
 from perfbench.reference import gqa_moe as ref  # noqa: E402
 
@@ -237,10 +238,12 @@ def test_yarn_by_attention_factor_on_half_of_the_channels():
 @pytest.mark.parametrize("position", [0, 1, 511, 8191])
 @pytest.mark.parametrize("layer", ["full", "sliding"])
 def test_a_layer_rotates_its_own_channels_by_its_own_angles(layer, position):
-    """Through ``gqa_attention``'s own rotation (read off the value it
-    keeps as ``gqa_q``): a full layer turns the first 64 channels by the
-    yarn angles times ``attention_factor`` and passes the other 64; a
-    sliding layer turns all 128 at theta 10,000."""
+    """Through the rotation ``gqa_attention`` hands ``attend`` (q and k
+    unrotated, the tables and their first channel, which the flash
+    kernels apply; ``rotated`` is the same in XLA): a
+    full layer turns the first 64 channels by the yarn angles times
+    ``attention_factor`` and passes the other 64; a sliding layer turns
+    all 128 at theta 10,000."""
     hd, T = 128, 8192
     rope = (LAGUNA_FULL if layer == "full"
             else {"theta": 10000, "partial_rotary_factor": 1})
@@ -253,8 +256,8 @@ def test_a_layer_rotates_its_own_channels_by_its_own_angles(layer, position):
     x = jax.random.normal(jax.random.PRNGKey(position), (1, T, hd), F32)
     seen = {}
 
-    def attend(q, k, v, scale, window):
-        seen.update(q=q, k=k, scale=scale, window=window)
+    def attend(q, k, v, scale, window, rope):
+        seen.update(q=q, k=k, scale=scale, window=window, rope=rope)
         return q
 
     lm_layers.gqa_attention(params, 0, x, arch, attend)
@@ -269,7 +272,10 @@ def test_a_layer_rotates_its_own_channels_by_its_own_angles(layer, position):
     want = np.concatenate([z.real, z.imag, h[rot:]])
     for name in ("q", "k"):
         np.testing.assert_allclose(
-            np.asarray(seen[name])[0, 0, position], want, atol=3e-5)
+            np.asarray(seen[name])[0, 0, position], h, atol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(rotated(seen[name], seen["rope"]))[0, 0, position],
+            want, atol=3e-5)
 
 
 def test_select_without_a_bias_takes_the_largest_scores():
@@ -297,8 +303,11 @@ def test_scopes_counters_and_routing_stats_of_the_new_model():
     text = jax.jit(jax.grad(model.loss_fn)).lower(
         params, tok, tgt).as_text(debug_info=True)
     for li in range(5):
-        for part in ("proj", "rope", "flash", "gate", "out"):
+        for part in ("proj", "flash", "gate", "out"):
             assert "l%d/attn/%s" % (li, part) in text, (li, part)
+        # the rotation is the flash kernels' (off the TPU: in the flash
+        # scope, before the formula)
+        assert "l%d/attn/rope" % li not in text
     assert "l0/ffn" in text and "l1/moe/router" in text
     assert "l4/moe/shared" in text and "l0/moe" not in text
     budget = moe.row_budget(tok.size, 2, 4, moe.GMM_BLOCK_ROWS)

@@ -128,6 +128,43 @@ def test_grouped_and_windowed_flash_lowers_for_tpu(heads, window, names):
                         q, kv, kv) == names
 
 
+def _rotary_loss(window, rot, start):
+    """The cells' rotated calls: tables of ``rot`` channels from
+    ``start`` at T = 8192, q and k unrotated."""
+    from mxnet_tpu.parallel.lm_layers import rope_tables
+
+    cos, sin = rope_tables(8192, rot, {"theta": 10000})
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       rope=(cos, sin, start))
+                       .astype(jnp.float32))
+    return loss
+
+
+#: (heads, k/v heads, q/k width, v width, window, rotated channels, from)
+ROTARY_CALLS = {
+    "laguna_full": (48, 8, 128, 128, None, 64, 0),
+    "laguna_sliding": (64, 8, 128, 128, 512, 128, 0),
+    "sarvam": (8, 8, 192, 128, None, 64, 128),      # 8 of its 64 heads
+}
+
+
+@pytest.mark.parametrize("call", sorted(ROTARY_CALLS))
+def test_rotary_flash_keeps_its_kernels_and_their_names(call):
+    """The rotation inside the kernels adds no kernel and renames none:
+    the readers of the trace count them by name."""
+    H, Hkv, D, Dv, window, rot, start = ROTARY_CALLS[call]
+    names = _tpu_kernels(
+        jax.grad(_rotary_loss(window, rot, start), argnums=(0, 1, 2)),
+        _aval((2, H, 8192, D), "bfloat16"),
+        _aval((2, Hkv, 8192, D), "bfloat16"),
+        _aval((2, Hkv, 8192, Dv), "bfloat16"))
+    part = "window_" if window else ""
+    assert names == ["flash_attention_%sfwd" % part,
+                     "flash_attention_%sbwd_dqkv" % part]
+
+
 def _gmm_step(tokens, top_k, held, d, f, dtype="bfloat16", n_rows=None):
     from mxnet_tpu.parallel import moe
 
@@ -416,6 +453,15 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
                                        argnums=(0, 1, 2)), q, kv, kv) == 2
     fn, avals = _gmm_step(16384, 8, 32, 2048, 512)
     assert compiled_calls(fn, *avals) == 3
+    # the same three shapes with the rotary tables: q and k rotated in
+    # VMEM, dq and dk turned back — Mosaic's lane rolls and concatenations
+    # at 128 and 192 lanes, and the tables' VMEM, are its to refuse
+    for H, Hkv, D, Dv, window, rot, start in ROTARY_CALLS.values():
+        assert compiled_calls(
+            jax.grad(_rotary_loss(window, rot, start), argnums=(0, 1, 2)),
+            _aval((1, H, 8192, D), "bfloat16"),
+            _aval((1, Hkv, 8192, D), "bfloat16"),
+            _aval((1, Hkv, 8192, Dv), "bfloat16")) == 2
     # the compact layout's rows -> tokens kernel at both routed cells'
     # shapes, weighted (combine's forward) and not (dispatch's backward)
     from mxnet_tpu.parallel import moe

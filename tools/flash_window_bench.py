@@ -13,7 +13,15 @@ group holds ``group * T`` rows of dq in VMEM, under the grouped calls' own
 budget and scoped limit). One JSON line a reading; the winners are
 constants of ``parallel/flash_attention.py``. ``--tokens 256 --window 64``
 rehearses on the CPU in the interpreter (never a reading).
-"""
+
+``--rope``: the rotary rotation inside the kernels (``flash_attention``'s
+``rope`` operand) against the rotation in XLA before them, at laguna's
+full layer (48 over 8 heads, the first 64 of 128 channels, yarn scaled by
+``attention_factor``), its sliding layer (64 over 8, window 512, all 128)
+and sarvam's latent heads (64 heads of 192 / 128, channels 128-191), T =
+8,192: forward and forward + backward ms of both; with ``--check`` out
+and the gradients with respect to the UNROTATED q, k, v of both against
+the dense formula in float32 (two k/v heads' groups)."""
 import argparse
 import importlib
 import json
@@ -32,7 +40,10 @@ def main(argv=None):
     ap.add_argument("--window", type=int, default=512)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--repeats", type=int, default=5)
+    ap.add_argument("--rope", action="store_true")
     args = ap.parse_args(argv)
+    if args.rope:
+        return rope_main(args)
 
     import jax
     import jax.numpy as jnp
@@ -153,6 +164,105 @@ def main(argv=None):
                                       "fused": fused, "block": b, "ms": ms,
                                       "forward_ms": fwd_ms}), flush=True)
             fa._GROUPED_FUSED_BWD_VMEM_BUDGET = budget
+    return 0
+
+
+def rope_main(args):
+    """``--rope``: see the module's docstring."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import mxnet_tpu  # noqa: F401
+
+    fa = importlib.import_module("mxnet_tpu.parallel.flash_attention")
+    lm = importlib.import_module("mxnet_tpu.parallel.lm_layers")
+    on_chip = jax.default_backend() == "tpu"
+    T = args.tokens
+    device = jax.devices()[0]
+    print(json.dumps({"device": device.device_kind,
+                      "platform": device.platform}),
+          flush=True)
+    full = {"theta": 500000, "factor": 64, "beta_fast": 64, "beta_slow": 1,
+            "original_max_position_embeddings": 4096,
+            "attention_factor": 1.4158883083359672}
+    sarvam = {"theta": 10000, "factor": 40, "beta_fast": 32,
+              "beta_slow": 1, "original_max_position_embeddings": 4096,
+              "mscale": 1.0, "mscale_all_dim": 1.0}
+    # name, batch, heads, k/v heads, q/k and v widths, rotated channels
+    # from, their count, window, rotary block
+    shapes = [("laguna_full", args.batch, 48, 8, 128, 128, 0, 64, None,
+               full),
+              ("laguna_sliding", args.batch, 64, 8, 128, 128, 0, 128,
+               args.window, {"theta": 10000}),
+              ("sarvam", 1, 64, 64, 192, 128, 128, 64, None, sarvam)]
+
+    def operands(B, H, Hkv, D, Dv, seed=0):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q, do = (jax.random.normal(k, (B, H, T, w), jnp.float32)
+                 .astype(jnp.bfloat16) for k, w in ((ks[0], D), (ks[3], Dv)))
+        k, v = (jax.random.normal(key, (B, Hkv, T, w), jnp.float32)
+                .astype(jnp.bfloat16) for key, w in ((ks[1], D), (ks[2], Dv)))
+        return q, k, v, do
+
+    def paths(rope, window):
+        def in_kernel(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True, window=window,
+                                      rope=rope, interpret=not on_chip)
+
+        def in_xla(q, k, v):     # the rotation as the callers made it
+            return fa.flash_attention(
+                fa.rotated(q, rope), fa.rotated(k, rope), v, causal=True,
+                window=window, interpret=not on_chip)
+        return {"kernel": in_kernel, "xla": in_xla}
+
+    def rel(a, b):
+        a, b = (np.asarray(x, np.float64) for x in (a, b))
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    def seconds(fn, *ops):
+        jax.block_until_ready(fn(*ops))      # compiles
+        times = []
+        for _ in range(args.repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*ops))
+            times.append(time.perf_counter() - t0)
+        return sorted(times)[len(times) // 2]
+
+    for name, B, H, Hkv, D, Dv, start, rot, window, block in shapes:
+        cos, sin = lm.rope_tables(T, rot, block)
+        rope = (cos, sin, start)
+        fns = paths(rope, window)
+        if args.check:
+            G = H // Hkv
+            q, k, v, do = operands(1, 2 * G, 2, D, Dv, seed=1)
+
+            @jax.jit
+            def dense(q, k, v, do):
+                with jax.default_matmul_precision("highest"):
+                    o, vjp = jax.vjp(lambda q, k, v: fa._dense_with_lse(
+                        fa.rotated(q, rope), fa.rotated(k, rope), v,
+                        causal=True, window=window)[0], q, k, v)
+                    return (o,) + vjp(do)
+
+            want = dense(*(a.astype(jnp.float32) for a in (q, k, v, do)))
+            for path, fn in fns.items():
+                o, vjp = jax.vjp(fn, q, k, v)
+                got = (o,) + vjp(do)
+                print(json.dumps({"check": name, "path": path, **{
+                    n: rel(g, w) for n, g, w in zip(
+                        ("out", "dq", "dk", "dv"), got, want)}}), flush=True)
+        q, k, v, do = operands(B, H, Hkv, D, Dv)
+        for path, fn in fns.items():
+            # one compile a path: what the bench times
+            fwd = 1e3 * seconds(jax.jit(fn), q, k, v)  # graftlint: disable=G002
+            both = 1e3 * seconds(jax.jit(jax.grad(  # graftlint: disable=G002
+                lambda q, k, v, fn=fn: jnp.sum(
+                    fn(q, k, v).astype(jnp.float32) * do), (0, 1, 2))),
+                q, k, v)
+            print(json.dumps({"time": name, "path": path,
+                              "forward_ms": fwd, "fwd_bwd_ms": both}),
+                  flush=True)
     return 0
 
 
