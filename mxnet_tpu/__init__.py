@@ -6,6 +6,9 @@ and CUDA kernels are replaced by XLA compilation; NDArray wraps jax.Array;
 Symbol graphs lower to single jitted XLA programs; KVStore data-parallelism
 becomes in-program ICI collectives over a jax.sharding.Mesh.
 """
+from time import perf_counter as _perf_counter
+
+_import_start = _perf_counter()  # the mxnet_tpu.import span's start
 
 __version__ = "1.0.0"
 
@@ -110,3 +113,7 @@ def __getattr__(attr):
         return mod
     raise AttributeError("module %r has no attribute %r"
                          % (__name__, attr))
+
+
+observability.instruments.note_import(_import_start, _perf_counter())
+del _import_start, _perf_counter

@@ -33,9 +33,16 @@ import jax
 from .. import profiler
 from . import metrics
 
-__all__ = ["trace_span", "device_scope"]
+__all__ = ["trace_span", "device_scope", "open_span"]
 
 _open = threading.local()  # .stack: this thread's open, recording spans
+
+
+def open_span():
+    """The innermost span recording on this thread, or None: the parent
+    of what the compile listeners record after the fact."""
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else None
 
 
 class _Span:

@@ -291,8 +291,7 @@ class ShardedTrainer:
         with trace_span("sharded_trainer.step", "parallel",
                         step=state["step"]):
             if self._step_fn is None:
-                with trace_span("sharded_trainer.build", "parallel"):
-                    self._step_fn = self._build_step()
+                self._step_fn = self._build_step()
             lr = self._lr(state["step"]) if callable(self._lr) else self._lr
             with trace_span("sharded_trainer.enqueue", "parallel"):
                 params, aux, opt, outs = self._step_fn(

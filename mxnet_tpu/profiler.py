@@ -189,15 +189,22 @@ def record_raw(ev):
     _append(dict(ev))
 
 
-def events_tail(n=256):
+def events_tail(n=256, compiles=False):
     """Copy of the most recent ``n`` recorded events (the flight
     recorder embeds this tail in its crash dump). Collected from the
-    ring's right end — O(n), never an O(ring-capacity) copy under the
-    lock recording threads contend on."""
+    ring's right end — O(n) and the events passed over, never an
+    O(ring-capacity) copy under the lock recording threads contend on.
+    The compile listeners' spans (category ``jit``: JAX reports them by
+    the hundred wherever a program is traced, a reference's or an eager
+    loop's) are passed over unless ``compiles``, so that a tail of the
+    program's own spans stays theirs."""
     import itertools
 
     with _lock:
-        tail = list(itertools.islice(reversed(_events), max(0, int(n))))
+        found = reversed(_events)
+        if not compiles:
+            found = (ev for ev in found if ev.get("cat") != "jit")
+        tail = list(itertools.islice(found, max(0, int(n))))
     tail.reverse()
     return tail
 
