@@ -105,8 +105,12 @@ def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
     ``D`` is the q/k width; ``Dv`` the v/o width where it differs. With
     ``rope_half`` (the rotary tables' width) the rotation in the kernels:
     a cos and a sin tile at the q block and at the k block (float32, their
-    lanes padded to 128), the rotated q and k tiles in scratch, and the
-    rotation's float32 intermediates."""
+    lanes padded to 128), the rotated operands in scratch, and the
+    rotation's float32 intermediates. The rotated operands are a q and a k
+    tile in the two-pass backward (no ``T``); with ``T`` the rows rotated
+    once a call and held: the forward's q tile and its whole head's k
+    (T, D), the fused backward's k tile and its whole group's q (``T``
+    rows, as dq)."""
     db = dtype_bytes
     Dv = D if Dv is None else Dv
     if not backward:
@@ -130,7 +134,9 @@ def flash_vmem_bytes(bq, bk, D, dtype_bytes, backward=False, T=None,
     if rope_half:
         lanes = -(-rope_half // LANES) * LANES
         tiles += 2 * (bq + bk) * lanes * 4      # cos, sin at q and at k
-        scratch += (bq + bk) * D * db           # the rotated q and k
+        # the rotated q and k: a tile each, or one tile and T rows held
+        scratch += ((bq + bk) if T is None
+                    else (bk if backward else bq) + T) * D * db
         inter += 4 * max(bq, bk) * D * 4        # x, its swap, C, S (fp32)
     return 2 * tiles + scratch + inter
 
@@ -322,7 +328,8 @@ def _rotate(x, cos, sin, start, transpose=False):
     return x * c - swapped if transpose else x * c + swapped
 
 
-def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
+def _kernel(*refs, scale, causal, interior, sub, window=None, group=1,
+            rope=None):
     """One (batch*head, q_block, k_block) forward grid step.
 
     Dots take q, k, v as loaded (bf16 operands, fp32 accumulation; fp32
@@ -341,8 +348,10 @@ def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
 
     With ``rope`` (the first rotated channel), q and k arrive unrotated
     with their rows' cos and sin tiles: q is rotated once a q block, at
-    its first step, and k on every live step, each into scratch that the
-    tile then reads in their place."""
+    its first step, into a scratch tile; k once a call, a k block at the
+    first step that sees it — of the group's first query head, ``group``
+    heads reading one k/v head — into the (T, D) scratch that holds the
+    head's rotated k for every later step and head of the group."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -351,11 +360,11 @@ def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     else:
         (q_in, k_in, v_ref, cos_q, sin_q, cos_k, sin_k, o_ref, lse_ref,
-         acc_ref, m_ref, l_ref, q_ref, k_ref) = refs
+         acc_ref, m_ref, l_ref, q_ref, k_held) = refs
     step = pl.program_id(2)
     q_idx = pl.program_id(1)
     bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
+    bk = v_ref.shape[1]
     W = m_ref.shape[1]
     # under a window the k axis walks the q block's band alone
     kv_idx = (step if window is None
@@ -370,10 +379,28 @@ def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
             q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
                                rope).astype(q_ref.dtype)
 
+    if rope is None:
+        def k_rows(nk):
+            return k_ref[0, :nk, :]
+    else:
+        k_first = pl.multiple_of(kv_idx * bk, bk)
+        # the first q block that sees this k block (its first key's row,
+        # causally, under a window too; the first of all else), of the
+        # group's first head
+        seen = q_idx == (_first_live_q(kv_idx, bq, bk) if causal else 0)
+        if group > 1:
+            seen &= pl.program_id(0) % group == 0
+
+        def k_rows(nk):
+            return k_held[pl.ds(k_first, nk), :]
+
     def _tile(masked):
         if rope is not None:
-            k_ref[0] = _rotate(k_in[0], cos_k[...], sin_k[...],
-                               rope).astype(k_ref.dtype)
+            @pl.when(seen)
+            def _hold_k():
+                k_held[pl.ds(k_first, bk), :] = _rotate(
+                    k_in[0], cos_k[...], sin_k[...],
+                    rope).astype(k_held.dtype)
         # the tile is worked through ``sub`` rows of q at a time. On a
         # tile that sits ON the diagonal (bq == bk) a chunk's keys end
         # with its own last row: dead sub-blocks are not computed
@@ -381,7 +408,7 @@ def _kernel(*refs, scale, causal, interior, sub, window=None, rope=None):
         for first in range(0, bq, sub):
             rows = slice(first, first + sub)
             nk = first + sub if on_diagonal else bk
-            q, k, v = q_ref[0, rows, :], k_ref[0, :nk, :], v_ref[0, :nk, :]
+            q, k, v = q_ref[0, rows, :], k_rows(nk), v_ref[0, :nk, :]
             s = jax.lax.dot_general(
                 q, k, _NT, preferred_element_type=jnp.float32) * scale
             if masked:
@@ -508,12 +535,15 @@ def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
     the sequential axis is the ``n_band`` q blocks from the k block's
     first live one on, not all ``n_q``.
 
-    With ``rope``: k is rotated once a k block, at its first step, and q
-    on every live step, each into scratch that the tile reads in their
-    place; the accumulated dk — the rotated k's gradient — is turned into
-    the unrotated k's by the transposed rotation once, before it is
-    written, and fused, each q block's rows of dq likewise at the last k
-    block that reaches them."""
+    With ``rope``: k is rotated once a k block, at its first step, into a
+    scratch tile. Fused, q is rotated once a call, a q block at the first
+    k block that reaches it, into a (group * T, D) scratch laid out as
+    the dq scratch, which holds it for every later k block; in the dk/dv
+    pass of two, q is rotated on every live step into a scratch tile.
+    The tile reads the scratch in their place. The accumulated dk — the
+    rotated k's gradient — is turned into the unrotated k's by the
+    transposed rotation once, before it is written, and fused, each q
+    block's rows of dq likewise at the last k block that reaches them."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -532,7 +562,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
     step = pl.program_id(2)
     kv_idx = pl.program_id(1)
     last_q = pl.num_programs(2) - 1
-    bq = q_ref.shape[1]
+    bq = do_ref.shape[1]
     bk = k_ref.shape[1]
     if window is None:
         q_idx = step if group == 1 else step % n_q
@@ -554,10 +584,20 @@ def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
         def _init_dq():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
+    held = fused and rope is not None     # q_ref: the group's rotated q
+    if held:
+        q_first = pl.multiple_of(dq_block * bq, bq)
+
+        def q_rows(first):
+            return q_ref[pl.ds(q_first + first, bq - first), :]
+    else:
+        def q_rows(first):
+            return q_ref[0, first:, :]
+
     def _chunk(masked, keys, first):
         """Keys ``keys`` (a slice of the tile's rows) against the tile's
         queries from ``first`` on."""
-        q, do = q_ref[0, first:, :], do_ref[0, first:, :]
+        q, do = q_rows(first), do_ref[0, first:, :]
         k = k_ref[0, keys, :]
         s_t = jax.lax.dot_general(k, q, _NT,
                                   preferred_element_type=jnp.float32) * scale
@@ -583,7 +623,15 @@ def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
                 ds_t, k, _TN, preferred_element_type=jnp.float32)
 
     def _tile(masked):
-        if rope is not None:
+        if held:
+            # the first k block that reaches this q block: the first of
+            # all, or under a window its first row's oldest key's
+            @pl.when(kv_idx == (0 if window is None
+                                else _first_live_k(q_idx, bq, bk, window)))
+            def _hold_q():
+                q_ref[pl.ds(q_first, bq), :] = _rotate(
+                    q_in[0], cos_q[...], sin_q[...], rope).astype(q_ref.dtype)
+        elif rope is not None:
             q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
                                rope).astype(q_ref.dtype)
         # on a tile that sits ON the diagonal (bq == bk) a key sub-chunk
@@ -637,7 +685,11 @@ def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
     With ``group`` query heads to a k/v head, k and v are (B*H/group, T,
     .) and a q head's index map names its group's k/v blocks. With
     ``rope`` (the first rotated channel) it takes the (T, half) cos and
-    sin tables after v, each read at the q block and at the k block."""
+    sin tables after v, each read at the q block and at the k block, and
+    holds the rotated k of a k/v head in a (T, D) scratch that later q
+    blocks read — and, with ``group > 1``, the group's later heads: the
+    q axis is then sequential, and with a group the head axis too (the
+    v5e has one TensorCore, so no core sits idle for it)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -675,16 +727,39 @@ def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
             pltpu.VMEM((block_q, lanes), jnp.float32),
         ]
         if rope is not None:
+            # unrotated k and its table rows are read only where a k block
+            # is rotated: a causal q block's steps name the k blocks it is
+            # the first to see (or, seeing none, one block throughout), the
+            # group's other heads the last block: no DMA where nothing is
+            # read
+            def fill_map(b, i, j):
+                kv, j, _ = kv_map(b, i, j)
+                if causal:
+                    j = jnp.minimum(
+                        jnp.maximum(j, (i * block_q + block_k - 1) // block_k),
+                        ((i + 1) * block_q - 1) // block_k)
+                if group > 1:
+                    j = jnp.where(b % group == 0, j, T // block_k - 1)
+                return kv, j, 0
+
             half = tables[0].shape[1]
+            in_specs[1] = pl.BlockSpec((1, block_k, D), fill_map)
             in_specs += (
                 [pl.BlockSpec((block_q, half), lambda b, i, j: (i, 0))] * 2
                 + [pl.BlockSpec((block_k, half),
-                                lambda b, i, j: (kv_map(b, i, j)[1], 0))] * 2)
+                                lambda b, i, j: (fill_map(b, i, j)[1], 0))]
+                * 2)
             scratch += [pltpu.VMEM((1, block_q, D), qf.dtype),
-                        pltpu.VMEM((1, block_k, D), kf.dtype)]
+                        pltpu.VMEM((T, D), kf.dtype)]
+            params = _compiler_params(
+                ("arbitrary" if group > 1 else "parallel", "arbitrary",
+                 "arbitrary"), grouped=group > 1)
+        else:
+            params = _compiler_params(grouped=group > 1)
         return pallas_call(
             functools.partial(_kernel, scale=scale, causal=causal, sub=sub,
-                              interior=interior, window=window, rope=rope),
+                              interior=interior, window=window, group=group,
+                              rope=rope),
             grid=(BH, T // block_q,
                   T // block_k if window is None else _band_blocks(
                       T, block_q, block_k, window, of_q=True)),
@@ -699,7 +774,7 @@ def _forward_call(causal, scale, block_q, block_k, interpret, window=None,
             ],
             scratch_shapes=scratch,
             interpret=interpret,
-            compiler_params=_compiler_params(grouped=group > 1),
+            compiler_params=params,
             name=_kernel_name(window, "fwd"),
         )(qf, kf, vf, *tables, *tables)
 
@@ -786,7 +861,9 @@ def _backward_call(causal, scale, bq, bk, fused, interpret, window=None,
                 (BH // group, group * T, D), qf.dtype))
             scratch.append(pltpu.VMEM((group * T, D), jnp.float32))
         if rope is not None:
-            scratch += [pltpu.VMEM((1, bq, D), qf.dtype),
+            # fused, the group's rotated q is held whole, laid out as dq
+            scratch += [pltpu.VMEM((group * T, D) if fused else (1, bq, D),
+                                   qf.dtype),
                         pltpu.VMEM((1, bk, D), kf.dtype)]
         outs = pallas_call(
             functools.partial(
@@ -878,12 +955,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     float32 tables of ``lm_layers.rope_tables``: q and k arrive UNROTATED
     and the kernels rotate channels ``start .. start + 2 half`` of each
     tile (half-split pairing, float32, rounded to the operands' dtype
-    before the dot: ``lm_layers.apply_rope``'s arithmetic) — q once a q
-    block and k every step in the forward, k once a k block and q every
-    step in the backward — and turn dq and dk into the gradients of the
-    unrotated q and k by the transposed rotation before writing them.
-    The rotated q and k never reach HBM. Off the kernels (a length with
-    no legal tile) the same rotation precedes the dense formula.
+    before the dot: ``lm_layers.apply_rope``'s arithmetic) — each row once
+    a call, held in VMEM for every later step that reads it: q a q block
+    and k a k block in the forward and the fused backward (the dk/dv pass
+    of two rotates q on every step) — and turn dq and dk into the
+    gradients of the unrotated q and k by the transposed rotation before
+    writing them. The rotated q and k never reach HBM. Off the kernels (a
+    length with no legal tile) the same rotation precedes the dense
+    formula.
 
     Block arguments are upper bounds; the largest TPU-legal tiles at or
     below them are used (``_pick_block``: the whole sequence or a
@@ -951,8 +1030,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale,
                                    window=window)
         return (out, lse) if return_lse else out
+    fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd, q.dtype.itemsize,
+                          Dv=Dv, group=group,
+                          rope_half=tables[0].shape[1] if tables else None)
     if rope is not None:
         counter("flash_attention.rotary").inc()
+        # the forward always holds its rotated k; the backward holds the
+        # group's rotated q where it is the fused pass
+        if fused:
+            counter("flash_attention.rotary_held").inc()
     def _flat(a):
         return a.reshape(-1, T, a.shape[-1])
 
@@ -974,9 +1060,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                         axis=-1)
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
-        fused = _bwd_is_fused(
-            T, D, block_q_bwd, block_k_bwd, q.dtype.itemsize, Dv=Dv,
-            group=group, rope_half=tables[0].shape[1] if tables else None)
         counter("flash_attention.bwd_fused" if fused
                 else "flash_attention.bwd_two_pass").inc()
         # per-row residuals ride as lane-dense rows of (B*H, 1, T)

@@ -8,8 +8,12 @@ through the fused and the two-pass backward, at the three forms the
 models take — every channel of a 128-wide head under groups of 8, the
 first 64 of 128 scaled by an ``attention_factor`` under a window, and
 channels 128-191 of a 192-wide head over a 128-wide v (latent attention).
-Then the counter ``flash_attention.rotary`` in a traced step of each LM
-family: one a rotated layer in the two that rotate, none elsewhere."""
+Each row is rotated once a call and held in VMEM (the forward's k, the
+fused backward's q): the cases take groups of 3 and unequal q and k tiles,
+so that rows are held off the diagonal. Then the counters
+``flash_attention.rotary`` and ``flash_attention.rotary_held`` in a traced
+step of each LM family: one a rotated layer in the two that rotate, none
+elsewhere, and none held where the backward takes two passes."""
 import importlib
 import math
 
@@ -32,14 +36,27 @@ def rel(a, b):
     return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
 
 
-# name: (D, Dv, group, rotated channels, their first, window, rotary block)
-CASES = {
-    "full_grouped": (128, 128, 8, 128, 0, None, {"theta": 10000}),
-    "partial_window": (128, 128, 1, 64, 0, 23, {
-        "theta": 500000, "factor": 64, "beta_fast": 64, "beta_slow": 1,
+YARN = {"theta": 500000, "factor": 64, "beta_fast": 64, "beta_slow": 1,
         "original_max_position_embeddings": 16,
-        "attention_factor": 1.4158883083359672}),
-    "latent": (192, 128, 1, 64, 128, None, {"theta": 10000}),
+        "attention_factor": 1.4158883083359672}
+#: the kernels' tiles where a case names none: four q and k blocks
+SQUARE = dict(block_q=16, block_k=16, block_q_bwd=16, block_k_bwd=16)
+# name: (D, Dv, group, rotated channels, their first, window, rotary block,
+# tiles). The held rows are filled at the first step that sees them: off
+# the diagonal where the forward's q and k tiles differ — a q block that
+# is the first to see two k blocks, or none
+CASES = {
+    "full_grouped": (128, 128, 8, 128, 0, None, {"theta": 10000}, SQUARE),
+    "partial_window": (128, 128, 1, 64, 0, 23, YARN, SQUARE),
+    "latent": (192, 128, 1, 64, 128, None, {"theta": 10000}, SQUARE),
+    "group3_wide_q": (128, 128, 3, 128, 0, None, {"theta": 10000}, dict(
+        block_q=32, block_k=16, block_q_bwd=16, block_k_bwd=32)),
+    "group3_wide_k": (128, 128, 3, 64, 0, None, YARN, dict(
+        block_q=16, block_k=32, block_q_bwd=32, block_k_bwd=16)),
+    "group3_window": (128, 128, 3, 128, 0, 23, {"theta": 10000}, dict(
+        block_q=32, block_k=16, block_q_bwd=16, block_k_bwd=16)),
+    "latent_group3": (192, 128, 3, 64, 128, 40, {"theta": 10000}, dict(
+        block_q=16, block_k=32, block_q_bwd=16, block_k_bwd=16)),
 }
 
 
@@ -47,18 +64,18 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_rotation_in_the_kernels_against_the_rotation_before_them(
         case, two_pass, monkeypatch):
-    D, Dv, group, rot, start, window, block = CASES[case]
+    D, Dv, group, rot, start, window, block, tiles = CASES[case]
     if two_pass:
         monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_BUDGET", 0)
         monkeypatch.setattr(fa, "_GROUPED_FUSED_BWD_VMEM_BUDGET", 0)
     ks = jax.random.split(jax.random.PRNGKey(D + rot + group), 4)
-    q = jax.random.normal(ks[0], (1, group, T, D), F32)
-    k = jax.random.normal(ks[1], (1, 1, T, D), F32)
-    v = jax.random.normal(ks[2], (1, 1, T, Dv), F32)
-    do = jax.random.normal(ks[3], (1, group, T, Dv), F32)
+    # two k/v heads: the second group's rows are held anew
+    q = jax.random.normal(ks[0], (1, 2 * group, T, D), F32)
+    k = jax.random.normal(ks[1], (1, 2, T, D), F32)
+    v = jax.random.normal(ks[2], (1, 2, T, Dv), F32)
+    do = jax.random.normal(ks[3], (1, 2 * group, T, Dv), F32)
     cos, sin = lm_layers.rope_tables(T, rot, block)
     rope = (cos, sin, start)
-    tiles = dict(block_q=16, block_k=16, block_q_bwd=16, block_k_bwd=16)
     scale = 1.1 / math.sqrt(D)
 
     def in_kernels(q, k, v):
@@ -99,19 +116,47 @@ def test_tables_that_do_not_fit_the_heads_are_refused():
 
 def test_the_vmem_count_holds_the_tables_and_the_rotated_tiles():
     """Both routed cells' backwards stay fused with the rotation's VMEM
-    counted: laguna's layers under the grouped budget, sarvam's 192-wide
-    heads under the plain one."""
+    counted — the group's rotated q held whole beside dq: laguna's layers
+    under the grouped budget, sarvam's 192-wide heads under the plain one
+    — and the forwards, which hold a head's rotated k, fit the scoped
+    limit."""
     plain = fa.flash_vmem_bytes(1024, 1024, 192, 2, backward=True, T=8192,
                                 Dv=128)
     rotary = fa.flash_vmem_bytes(1024, 1024, 192, 2, backward=True, T=8192,
                                  Dv=128, rope_half=32)
     # cos and sin tiles at q and at k, lanes padded to 128, two buffers;
-    # the rotated q and k; four float32 (1024, 192) intermediates
-    assert rotary - plain == (2 * 2 * 2048 * 128 * 4 + 2048 * 192 * 2
+    # the rotated k tile and the head's 8192 rotated q rows held; four
+    # float32 (1024, 192) intermediates
+    assert rotary - plain == (2 * 2 * 2048 * 128 * 4
+                              + (1024 + 8192) * 192 * 2
                               + 4 * 1024 * 192 * 4)
+    # the group's q: laguna's full layer holds 6 * 8192 rows
+    grouped = fa.flash_vmem_bytes(1024, 1024, 128, 2, backward=True,
+                                  T=6 * 8192, rope_half=32)
+    assert grouped - fa.flash_vmem_bytes(
+        1024, 1024, 128, 2, backward=True, T=6 * 8192) == (
+            2 * 2 * 2048 * 128 * 4 + (1024 + 6 * 8192) * 128 * 2
+            + 4 * 1024 * 128 * 4)
+    # the two-pass dk/dv pass still rotates a q and a k tile
+    assert fa.flash_vmem_bytes(1024, 1024, 192, 2, backward=True, Dv=128,
+                               rope_half=32) - fa.flash_vmem_bytes(
+        1024, 1024, 192, 2, backward=True, Dv=128) == (
+            2 * 2 * 2048 * 128 * 4 + 2048 * 192 * 2 + 4 * 1024 * 192 * 4)
     assert fa._bwd_is_fused(8192, 192, 1024, 1024, 2, Dv=128, rope_half=32)
     assert fa._bwd_is_fused(8192, 128, 1024, 1024, 2, group=6, rope_half=32)
     assert fa._bwd_is_fused(8192, 128, 512, 512, 2, group=8, rope_half=64)
+    # the forward: a q tile rotated and the head's (8192, D) k held
+    for bq, D, Dv, half, limit in ((2048, 192, 128, 32, fa._VMEM_LIMIT),
+                                   (2048, 128, 128, 32,
+                                    fa._GROUPED_VMEM_LIMIT),
+                                   (512, 128, 128, 64,
+                                    fa._GROUPED_VMEM_LIMIT)):
+        fwd = fa.flash_vmem_bytes(bq, bq, D, 2, T=8192, Dv=Dv,
+                                  rope_half=half)
+        assert fwd - fa.flash_vmem_bytes(bq, bq, D, 2, Dv=Dv) == (
+            2 * 2 * 2 * bq * 128 * 4 + (bq + 8192) * D * 2
+            + 4 * bq * D * 4)
+        assert fwd < limit
 
 
 def _laguna():
@@ -140,20 +185,29 @@ def _one_chip():
     return make_mesh({"dp": 1}, devices=jax.devices()[:1])
 
 
-@pytest.mark.parametrize("family,rotated_calls", [
-    (_laguna, 5), (_sarvam, 5), (_phi4flash, 0), (_first_lm, 0)],
-    ids=["laguna", "sarvam", "phi4flash", "first_lm"])
+@pytest.mark.parametrize("family,rotated_calls,held_calls,two_pass", [
+    (_laguna, 5, 5, False), (_sarvam, 5, 5, False), (_phi4flash, 0, 0, False),
+    (_first_lm, 0, 0, False), (_laguna, 5, 0, True), (_sarvam, 5, 0, True)],
+    ids=["laguna", "sarvam", "phi4flash", "first_lm", "laguna_two_pass",
+         "sarvam_two_pass"])
 def test_a_traced_step_counts_its_rotated_flash_calls(
-        family, rotated_calls, monkeypatch):
+        family, rotated_calls, held_calls, two_pass, monkeypatch):
     """Traced as on the chip (the flash path is the TPU's, T = 128): one
     count a layer whose flash call takes the tables, however often the
-    recomputed layers run."""
+    recomputed layers run — and ``flash_attention.rotary_held`` one a
+    call whose backward is the fused pass, which holds the rotated rows
+    as the forward does; a call that falls to the two passes counts
+    none."""
+    if two_pass:
+        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_BUDGET", 0)
+        monkeypatch.setattr(fa, "_GROUPED_FUSED_BWD_VMEM_BUDGET", 0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     obs.set_enabled(True)
     model = family()
     params = model.init(3)
     tokens = jnp.zeros((2, 128), jnp.int32)
-    before = obs.metrics.get_value("flash_attention.rotary", 0)
+    names = ("flash_attention.rotary", "flash_attention.rotary_held")
+    before = [obs.metrics.get_value(n, 0) for n in names]
     jax.make_jaxpr(jax.grad(model.loss_fn))(params, tokens, tokens)
-    assert (obs.metrics.get_value("flash_attention.rotary", 0) - before
-            == rotated_calls)
+    assert [obs.metrics.get_value(n, 0) - b for n, b in zip(
+        names, before)] == [rotated_calls, held_calls]

@@ -15,13 +15,16 @@ constants of ``parallel/flash_attention.py``. ``--tokens 256 --window 64``
 rehearses on the CPU in the interpreter (never a reading).
 
 ``--rope``: the rotary rotation inside the kernels (``flash_attention``'s
-``rope`` operand) against the rotation in XLA before them, at laguna's
-full layer (48 over 8 heads, the first 64 of 128 channels, yarn scaled by
-``attention_factor``), its sliding layer (64 over 8, window 512, all 128)
-and sarvam's latent heads (64 heads of 192 / 128, channels 128-191), T =
-8,192: forward and forward + backward ms of both; with ``--check`` out
-and the gradients with respect to the UNROTATED q, k, v of both against
-the dense formula in float32 (two k/v heads' groups)."""
+``rope`` operand, each row rotated once a call and held in VMEM: path
+``held``) against the rotation in XLA before them (path ``xla``), at
+laguna's full layer (48 over 8 heads, the first 64 of 128 channels, yarn
+scaled by ``attention_factor``), its sliding layer (64 over 8, window
+512, all 128) and sarvam's latent heads (64 heads of 192 / 128, channels
+128-191), T = 8,192: forward, forward + backward and their difference
+(the backward kernel's) ms of both, and whether the call's backward is
+the fused pass that holds the rotated q (``held_bwd``); with ``--check``
+out and the gradients with respect to the UNROTATED q, k, v of both
+against the dense formula in float32 (two k/v heads' groups)."""
 import argparse
 import importlib
 import json
@@ -206,7 +209,7 @@ def rope_main(args):
         return q, k, v, do
 
     def paths(rope, window):
-        def in_kernel(q, k, v):
+        def held(q, k, v):
             return fa.flash_attention(q, k, v, causal=True, window=window,
                                       rope=rope, interpret=not on_chip)
 
@@ -214,7 +217,7 @@ def rope_main(args):
             return fa.flash_attention(
                 fa.rotated(q, rope), fa.rotated(k, rope), v, causal=True,
                 window=window, interpret=not on_chip)
-        return {"kernel": in_kernel, "xla": in_xla}
+        return {"held": held, "xla": in_xla}
 
     def rel(a, b):
         a, b = (np.asarray(x, np.float64) for x in (a, b))
@@ -253,6 +256,10 @@ def rope_main(args):
                     n: rel(g, w) for n, g, w in zip(
                         ("out", "dq", "dk", "dv"), got, want)}}), flush=True)
         q, k, v, do = operands(B, H, Hkv, D, Dv)
+        bound = fa._WINDOW_BWD_BLOCK if window else fa._BWD_BLOCK
+        tile = fa._pick_block(T, bound, not on_chip)
+        held_bwd = fa._bwd_is_fused(T, D, tile, tile, 2, Dv=Dv,
+                                    group=H // Hkv, rope_half=rot // 2)
         for path, fn in fns.items():
             # one compile a path: what the bench times
             fwd = 1e3 * seconds(jax.jit(fn), q, k, v)  # graftlint: disable=G002
@@ -261,8 +268,9 @@ def rope_main(args):
                     fn(q, k, v).astype(jnp.float32) * do), (0, 1, 2))),
                 q, k, v)
             print(json.dumps({"time": name, "path": path,
-                              "forward_ms": fwd, "fwd_bwd_ms": both}),
-                  flush=True)
+                              "forward_ms": fwd, "fwd_bwd_ms": both,
+                              "backward_ms": both - fwd,
+                              "held_bwd": held_bwd}), flush=True)
     return 0
 
 
