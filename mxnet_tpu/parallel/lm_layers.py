@@ -1,13 +1,13 @@
 """Layer kinds a :class:`~.transformer.TransformerParallel` can be built
 from beside its first block: latent attention (``"mla"``), grouped-query
-attention whose heads, window and rotary tables are the layer's own
-(``"gqa"``), a state-space mixer (``"ssm"``: a selective scan behind a
-causal convolution), a gated memory unit (``"gmu"``: a gate on another
-layer's scan output), differential attention (``"diff"``: the difference
-of two softmax maps, on the layer's own k and v or on another layer's), a
-SwiGLU FFN (``"swiglu"``) and a routed expert layer that is told which
-experts it holds (``"moe"``). Each kind is its leaves (name -> (shape,
-init)) and its forward on (B, T, d).
+attention whose heads, window, rotary tables and q/k norms are the layer's
+own (``"gqa"``), a state-space mixer (``"ssm"``: a selective scan behind a
+causal convolution), a gated memory unit (``"gmu"``), differential
+attention (``"diff"``: two softmax maps, on the layer's own k and v or on
+another layer's), a gated short convolution (``"conv"``), a SwiGLU FFN
+(``"swiglu"``) and a routed expert layer that is told which experts it
+holds, with or without a shared expert (``"moe"``). Each kind is its leaves
+(name -> (shape, init)) and its forward on (B, T, d).
 
 A norm is ``x * rsqrt(mean(x^2) + eps) * w`` with a learned ``w`` — or,
 where the architecture states ``layer_norm_eps``, a LayerNorm with weight
@@ -32,7 +32,7 @@ __all__ = ["ATTENTION_KINDS", "FFN_KINDS", "KEPT_BY_A_RECOMPUTED_LAYER",
            "layer_norm", "norm", "apply_rope", "gqa_attention", "ssm_mixer",
            "gated_memory", "diff_attention", "reads", "publishes"]
 
-ATTENTION_KINDS = ("mha", "mla", "gqa", "ssm", "gmu", "diff")
+ATTENTION_KINDS = ("mha", "mla", "gqa", "ssm", "gmu", "diff", "conv")
 FFN_KINDS = ("soft_moe", "swiglu", "moe")
 #: what a layer that the backward pass recomputes (``remat=True``) keeps of
 #: its forward beside its inputs, by the names :func:`kept` gives the values
@@ -50,7 +50,7 @@ KEPT_BY_A_RECOMPUTED_LAYER = (
     "ffn_gate", "ffn_up",
     "shared_gate", "shared_up",
     "ssm_y", "ssm_starts", "ssm_in", "gmu_gate",
-    "diff_q", "diff_k", "diff_v")
+    "diff_q", "diff_k", "diff_v", "conv_bcx")
 
 _NORMAL = ("normal", 0.02)
 #: a state-space layer's step bias at the start: softplus^-1(0.01)
@@ -73,7 +73,7 @@ def layer_table(li, kinds, cfg, arch):
     p = "l%d_" % li
     d = cfg["d_model"]
     H = cfg["n_heads"]
-    t = {}
+    t = newer_leaves(li, attn, cfg, arch)   # the conv kind, q/k norms
     if attn == "ssm":
         m = arch["ssm"]
         E, N, R = m["d_inner"], m["d_state"], m["dt_rank"]
@@ -145,9 +145,9 @@ def layer_table(li, kinds, cfg, arch):
         t[p + "router"] = ((d, m["n_experts"]), _NORMAL)
         if m.get("router_bias", True):
             t[p + "router_bias"] = ((m["n_experts"],), _NORMAL)
-        t[p + "shared_wg"] = ((d, fs), _NORMAL)
-        t[p + "shared_wu"] = ((d, fs), _NORMAL)
-        t[p + "shared_wd"] = ((fs, d), _NORMAL)
+        if m["n_shared"]:       # n_shared 0: no shared expert, no leaves
+            t.update({p + "shared_" + n: (s, _NORMAL) for n, s in (
+                ("wg", (d, fs)), ("wu", (d, fs)), ("wd", (fs, d)))})
         t[p + "moe_wg"] = ((hi - lo, d, f), _NORMAL)
         t[p + "moe_wu"] = ((hi - lo, d, f), _NORMAL)
         t[p + "moe_wd"] = ((hi - lo, f, d), _NORMAL)
@@ -321,13 +321,13 @@ def mla_attention(params, li, x, cfg, arch, attend):
 
 def gqa_attention(params, li, x, arch, attend):
     """Grouped-query attention's residual branch on (B, T, d): layer
-    ``li``'s own head count over the model's k/v heads, its rotary tables
-    on the first ``partial_rotary_factor`` of each head's channels, its
-    window (None: causal), and one sigmoid gate a query head on the
-    head's output before the out-projection. ``attend(q, k, v, scale,
-    window, rope=)`` takes q (B, H, T, hd) and k, v (B, Hkv, T, hd), q
-    and k unrotated, and rotates their first ``rot`` channels by
-    ``rope``'s tables (``flash_attention``'s operand)."""
+    ``li``'s own head count over the model's k/v heads, q and k normed a
+    head (``qk_norm``), rotary tables on the first ``partial_rotary_factor``
+    of each head's channels, its window (None: causal), and one sigmoid
+    gate a query head on the head's output before the out-projection.
+    ``attend(q, k, v, scale, window, rope=)`` takes q (B, H, T, hd) and k,
+    v (B, Hkv, T, hd), q and k unrotated, and rotates their first ``rot``
+    channels by ``rope``'s tables (``flash_attention``'s operand)."""
     import jax
     import jax.numpy as jnp
 
@@ -344,7 +344,7 @@ def gqa_attention(params, li, x, arch, attend):
                             params[p + "wk"].reshape(-1, Hkv, hd)), "gqa_k")
         v = kept(jnp.einsum("btd,dhe->bhte", h,
                             params[p + "wv"].reshape(-1, Hkv, hd)), "gqa_v")
-    # the first ``rot`` channels of q and k turn; the rest pass
+    q, k = qk_norm(params, li, q, k, arch)   # then ``rot`` channels turn
     rot = int(hd * mine["rope"].get("partial_rotary_factor", 1))
     cos, sin = rope_tables(T, rot, mine["rope"])
     with device_scope("l%d/attn/flash" % li):
@@ -570,9 +570,68 @@ def moe_ffn(params, li, x, arch):
     out = _moe.in_the_layout_that_fits(
         routed, compact, plan, rows_in, weight,
         *(params[p + n] for n in ("moe_wg", "moe_wu", "moe_wd")))
-    with device_scope("l%d/moe/shared" % li):
-        shared = _swiglu(u, params[p + "shared_wg"], params[p + "shared_wu"],
-                         params[p + "shared_wd"], "shared")
+    with device_scope("l%d/moe/shared" % li):   # none where n_shared is 0
+        shared = 0 if not m["n_shared"] else _swiglu(u, *(
+            params[p + "shared_" + n] for n in ("wg", "wu", "wd")), "shared")
     with device_scope("l%d/moe/combine" % li):
         return (shared + out.reshape(B, T, d),
                 {"counts": plan["counts"], "live_tiles": plan["n_live"]})
+
+
+# --- kinds added after the table's own blocks ----------------------------
+# (kept below every forward above: a Mosaic kernel's compiled payload keys
+# on its call stack's lines, and the frames above the kernels stay put)
+def newer_leaves(li, attn, cfg, arch):
+    """The leaves that lead layer ``li``'s table for kinds added after
+    :func:`layer_table`'s own blocks: the ``conv`` mixer's, and a ``gqa``
+    layer's q/k norm weights (a head wide) where ``arch["gqa"]["qk_norm"]``."""
+    p = "l%d_" % li
+    d = cfg["d_model"]
+    if attn == "conv":
+        return {p + "attn_norm": ((d,), 1.0),
+                p + "conv_in": ((d, 3 * d), _NORMAL),
+                p + "conv_w": ((arch["conv"]["taps"], d), _NORMAL),
+                p + "conv_out": ((d, d), _NORMAL)}
+    if attn == "gqa" and arch["gqa"].get("qk_norm"):
+        hd = arch["gqa"]["head_dim"]
+        return {p + "q_norm": ((hd,), 1.0), p + "k_norm": ((hd,), 1.0)}
+    return {}
+
+
+def qk_norm(params, li, q, k, arch):
+    """q and k normed a head over their channels (RMS, learned weight a
+    channel) where layer ``li`` has the leaves (:func:`newer_leaves`), as
+    they are elsewhere. Both come and go unrotated."""
+    p = "l%d_" % li
+    if p + "q_norm" not in params:
+        return q, k
+    with device_scope("l%d/attn/proj" % li):
+        eps = arch["rms_norm_eps"]
+        return (rms_norm(q, params[p + "q_norm"], eps),
+                rms_norm(k, params[p + "k_norm"], eps))
+
+
+def short_conv_mixer(params, li, x, arch, attend=None):
+    """A gated short convolution's residual branch on (B, T, d): the
+    normed input's in-projection to ``[B | C | x~]`` (B, T, 3d), kept by
+    name, then ``C * conv_w(B * x~)`` (a depthwise causal filter of
+    ``arch["conv"]["taps"]`` taps: ``short_conv``) and the out-projection.
+    ``attend`` is not used: the signature is :data:`MIXERS`'."""
+    from .short_conv import short_conv
+
+    p = "l%d_" % li
+    counter("lm_layers.short_conv").inc()
+    with device_scope("l%d/conv/proj" % li):
+        h = rms_norm(x, params[p + "attn_norm"], arch["rms_norm_eps"])
+        bcx = kept(h @ params[p + "conv_in"], "conv_bcx")
+    with device_scope("l%d/conv/mix" % li):
+        y = short_conv(bcx, params[p + "conv_w"])
+    with device_scope("l%d/conv/out" % li):
+        return y @ params[p + "conv_out"]
+
+
+#: kind -> the residual branch ``(params, li, x, arch, attend)`` of the
+#: kinds that neither hand on nor read a shared value
+MIXERS = {"gqa": gqa_attention, "conv": short_conv_mixer}
+
+__all__ += ["newer_leaves", "qk_norm", "short_conv_mixer", "MIXERS"]

@@ -90,13 +90,13 @@ class TransformerParallel:
         """A model from a published ``config.json``'s keys: MLA layers
         (the DeepSeek-MLA family's names, ``model_type: sarvam_mla``
         among them), the first ``first_k_dense_replace`` with a SwiGLU FFN
-        and the rest routed; under ``model_type: laguna``, the model its
-        layer lists describe (:func:`_laguna`); under ``model_type:
-        phi4flash``, state-space, differential-attention and gated-memory
-        layers by published index (:func:`_phi4flash`). ``num_experts`` counts
-        the experts HELD; ``cfg["published"]["num_experts"]`` (the
-        router's width) and ``cfg["deployment"]["experts_held"]`` (their
-        range) say of which share, and default to all of them."""
+        and the rest routed; under ``model_type: laguna`` or ``lfm2_moe``,
+        the model its layer lists describe (:func:`_laguna`, :func:`_lfm2`);
+        under ``phi4flash``, state-space, differential-attention and gated-
+        memory layers by published index (:func:`_phi4flash`).
+        ``num_experts`` counts the experts HELD; ``cfg["published"]
+        ["num_experts"]`` (the router's width) and ``cfg["deployment"]
+        ["experts_held"]`` (their range) say of which share, default all."""
         if cfg.get("model_type") == "phi4flash":
             layers, arch = _phi4flash(cfg)
             return cls(mesh, vocab=cfg["vocab_size"],
@@ -111,8 +111,8 @@ class TransformerParallel:
                "top_k": cfg["num_experts_per_tok"],
                "d_expert": cfg["moe_intermediate_size"],
                "experts_held": (int(held[0]), int(held[1]))}
-        if cfg.get("model_type") == "laguna":
-            layers, arch = _laguna(cfg, moe)
+        if cfg.get("model_type") in _ROUTED_FAMILIES:   # laguna, lfm2_moe
+            layers, arch = _ROUTED_FAMILIES[cfg["model_type"]](cfg, moe)
             return cls(mesh, vocab=cfg["vocab_size"],
                        d_model=cfg["hidden_size"],
                        n_heads=cfg["num_attention_heads"],
@@ -190,7 +190,7 @@ class TransformerParallel:
         import jax.numpy as jnp
 
         table = self.param_table()
-        dtype = jnp.dtype(self.dtype)
+        dtype = jnp.dtype(self.arch.get("master_dtype") or self.dtype)
 
         def make(key):
             out = {}
@@ -266,8 +266,8 @@ class TransformerParallel:
         elif attn == "mla":
             x = lm_layers.kept(x + lm_layers.mla_attention(
                 params, li, x, c, self.arch, self._attend), "attn_residual")
-        elif attn == "gqa":
-            x = lm_layers.kept(x + lm_layers.gqa_attention(
+        elif attn in lm_layers.MIXERS:             # "gqa", "conv"
+            x = lm_layers.kept(x + lm_layers.MIXERS[attn](
                 params, li, x, self.arch, self._attend), "attn_residual")
         else:
             if attn == "ssm":
@@ -369,7 +369,7 @@ class TransformerParallel:
         if self._stats_jit is None:
             def stats(params, tokens):
                 collect = []
-                self._forward(params, tokens, collect)
+                self._forward(_compute_copy(self, params), tokens, collect)
                 return dict(collect)
 
             self._stats_jit = jax.jit(stats)
@@ -545,7 +545,7 @@ class TransformerParallel:
                 return new_params, loss
 
             self._step_jit = jax.jit(
-                step, donate_argnums=(0,),
+                _master_step(self) or step, donate_argnums=(0,),
                 out_shardings=(self.param_shardings(), None))
         if lr not in self._step_cache:
             step_jit = self._step_jit
@@ -784,3 +784,85 @@ def _rms_norm(x):
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                           + 1e-6)
     return (x32 * scale).astype(x.dtype)
+
+
+def _lfm2(cfg, moe):
+    """(layers, arch) of ``model_type: lfm2_moe`` from its published keys:
+    ``layer_types`` gives each layer its mixer, a gated short convolution
+    (``conv``: ``conv_L_cache`` taps, no bias) or grouped-query attention
+    (``full_attention``: ``num_attention_heads`` over
+    ``num_key_value_heads`` heads of ``hidden_size / num_attention_heads``,
+    q and k normed a head, rotary positions at ``rope_theta`` on every
+    channel, no gate); the first ``num_dense_layers`` have a SwiGLU FFN and
+    the rest route, by ``use_expert_bias`` with a bias, with no shared
+    expert. Every norm is an RMS norm of ``norm_eps``; the last is the
+    published ``embedding_norm``, and the head is the embedding transposed
+    (the family ships tied embeddings: ``assumed`` in the file). An
+    ``optimizer`` block naming ``master_weights`` gives the step master
+    weights of that dtype (:func:`_master_step`)."""
+    if cfg["conv_bias"] or not cfg["norm_topk_prob"]:
+        raise NotImplementedError("lfm2_moe: conv biases, or routing "
+                                  "weights that are not normalised")
+    n = cfg["num_hidden_layers"]
+    mixer = {"conv": "conv", "full_attention": "gqa"}
+    kinds = [mixer[kind] for kind in cfg["layer_types"][:n]]
+    layers = [(kind, "swiglu" if li < cfg["num_dense_layers"] else "moe")
+              for li, kind in enumerate(kinds)]
+    H = cfg["num_attention_heads"]
+    rope = {"theta": cfg["rope_parameters"]["rope_theta"]}
+    arch = {"rms_norm_eps": cfg["norm_eps"], "tied_head": True,
+            "conv": {"taps": cfg["conv_L_cache"]},
+            "gqa": {"n_kv_heads": cfg["num_key_value_heads"],
+                    "head_dim": cfg["hidden_size"] // H, "gate": False,
+                    "qk_norm": True,
+                    "layers": {li: {"n_heads": H, "window": None,
+                                    "rope": rope}
+                               for li, kind in enumerate(kinds)
+                               if kind == "gqa"}},
+            "moe": dict(moe, scale=cfg["routed_scaling_factor"], n_shared=0,
+                        router_bias=bool(cfg["use_expert_bias"])),
+            "master_dtype": cfg.get("optimizer", {}).get("master_weights")}
+    return layers, arch
+
+
+#: ``model_type`` -> what makes a routed family's (layers, arch)
+_ROUTED_FAMILIES = {"laguna": _laguna, "lfm2_moe": _lfm2}
+
+
+def _compute_copy(model, params):
+    """``params`` in the model's compute dtype: the master weights'
+    rounded copy where the model keeps them (:func:`_master_step`), else
+    ``params`` themselves."""
+    if not model.arch.get("master_dtype"):
+        return params
+    with device_scope("master_copy"):
+        return {k: v.astype(model.dtype) for k, v in params.items()}
+
+
+def _master_step(model):
+    """The train step of a model with master weights (``arch
+    ["master_dtype"]``, e.g. ``"float32"``), or None for one without.
+
+    The caller holds every leaf in the master dtype. The forward and
+    backward passes run on its copy in the compute dtype, so they cost
+    what they cost without master weights, and the update ``w - lr * g``
+    is made in the master dtype from the copy's gradient (the gradient of
+    the cast is the cast back, done inside the update's own pass). Plain
+    SGD on bfloat16 weights rounds an update of under half a weight's last
+    place away (a norm weight at 1.0 moves only by 2**-9 or more), so
+    small leaves of a large model stop learning; here the update is kept
+    whole. The cost is the master copy (4 bytes a parameter beside the
+    copy's 2), the cast a step and the update's wider reads and writes."""
+    if not model.arch.get("master_dtype"):
+        return None
+    import jax
+
+    def step(params, tokens, targets, lr):
+        loss, grads = jax.value_and_grad(model.loss_fn)(
+            _compute_copy(model, params), tokens, targets)
+        with device_scope("update"):
+            new_params = {k: params[k] - lr * grads[k].astype(params[k].dtype)
+                          for k in params}
+        return new_params, loss
+
+    return step

@@ -281,6 +281,8 @@ def test_split_op_name(op_name, expected):
     assert trace_report.program_scope("head_loss/log_softmax") == "head_loss"
     assert (trace_report.program_scope("l2/moe/dispatch/segment_sum")
             == "l*/moe/dispatch")
+    assert (trace_report.program_scope("l6/conv/mix/short_conv_bwd")
+            == "l*/conv/mix")
 
 
 XSPACE = """

@@ -147,6 +147,7 @@ ROTARY_CALLS = {
     "laguna_full": (48, 8, 128, 128, None, 64, 0),
     "laguna_sliding": (64, 8, 128, 128, 512, 128, 0),
     "sarvam": (8, 8, 192, 128, None, 64, 128),      # 8 of its 64 heads
+    "lfm2": (32, 8, 64, 64, None, 64, 0),          # every channel turns
 }
 
 
@@ -328,6 +329,25 @@ def test_scan_kernels_lower_for_tpu(tokens):
                         *avals) == ["ssm_scan_fwd", "ssm_scan_bwd"]
 
 
+@pytest.mark.parametrize("shape", [(2, 8192, 6144), (1, 1000, 768)],
+                         ids=["lfm2_train_t8192_b2", "a_length_of_no_tile"])
+def test_short_conv_kernels_lower_for_tpu(shape):
+    """The cell's conv layer (2 x 8,192 tokens, 2048 channels, 3 taps),
+    forward and backward, under the names the benchmark's readers match;
+    a length no tile of 16 divides takes the formula, and no kernel."""
+    from mxnet_tpu.parallel.short_conv import short_conv
+
+    def loss(bcx, w):
+        return jnp.sum(short_conv(bcx, w, interpret=False)
+                       .astype(jnp.float32) ** 2)
+
+    names = _tpu_kernels(jax.grad(loss, argnums=(0, 1)),
+                         _aval(shape, "bfloat16"),
+                         _aval((3, shape[2] // 3), "bfloat16"))
+    assert names == (["short_conv_fwd", "short_conv_bwd"]
+                     if shape[1] % 16 == 0 else [])
+
+
 def test_flash_declines_a_length_with_no_legal_block():
     # 1100 > the backward bound 1024 and no multiple of 128 divides it: a
     # static decline to the dense formula, not a lowering error
@@ -494,6 +514,16 @@ def test_kernels_compile_for_v5e_ahead_of_time(monkeypatch):
     for window in (None, 512):
         assert compiled_calls(jax.grad(_grouped_loss(window),
                                        argnums=(0, 1, 2)), q, k, v) == 2
+    # the gated short convolution at the cell lfm2_train_t8192_b2's layer
+    # (2 x 8,192 tokens, 3 x 2048 gates): its tiles' VMEM under the
+    # kernels' own limit, and the sublane rolls, are Mosaic's to refuse
+    from mxnet_tpu.parallel.short_conv import short_conv
+
+    assert compiled_calls(
+        jax.grad(lambda bcx, w: jnp.sum(short_conv(
+            bcx, w, interpret=False).astype(jnp.float32) ** 2),
+            argnums=(0, 1)),
+        _aval((2, 8192, 6144), "bfloat16"), _aval((3, 2048), "bfloat16")) == 2
     epilogue = (("bias",), ("act", "relu"))
     assert compiled_calls(
         lambda x, w, b: fused_matmul(x, w, extras=[b], epilogue=epilogue),

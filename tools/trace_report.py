@@ -648,11 +648,11 @@ def program_scope(scope):
     """The part of a scope path the program itself named: a graph node
     (``bn0``), or ``l17/attn`` as ``l*/attn`` (one row for the same scope
     of every layer) and ``l3/moe/experts`` as ``l*/moe/experts`` (the
-    parts an attention, routed or state-space layer names); what jax.numpy adds
-    below it (an einsum's spec, ``log_softmax``, a kernel's name) is left
-    to the per-operation rows."""
+    parts an attention, routed, state-space or short-convolution layer
+    names); what jax.numpy adds below it (an einsum's spec,
+    ``log_softmax``, a kernel's name) is left to the per-operation rows."""
     layer = re.match(r"l\d+/(attn/(?:proj|rope|flash|diff|out)(?=/|$)"
-                     r"|moe/[^/]+|ssm/[^/]+|[^/]+)", scope)
+                     r"|moe/[^/]+|ssm/[^/]+|conv/[^/]+|[^/]+)", scope)
     return "l*/" + layer.group(1) if layer else scope.split("/")[0]
 
 
