@@ -22,13 +22,13 @@ and the backward recomputes block scores on the fly, each tile once:
 Every pass accumulates in fp32 VMEM scratch and follows the causal
 diagonal the same way: tiles above it are skipped (their index maps name
 the neighbouring live block, so they cost no DMA), tiles it crosses are
-masked — and a square tile ON it is worked through in sub-chunks that
-stop at the diagonal, so its dead part is not computed — and tiles below
-it run mask-free. Dots take their operands in the
-input's dtype (bf16 in, fp32 accumulation; fp32 inputs keep fp32 dots)
-with p and ds cast down for the second dot of each pair; scores, softmax
-statistics, lse, delta and all accumulators stay fp32. No pass ever
-materializes a T x T tensor in HBM.
+masked — and a square tile ON it is walked in sub-chunks that stop at
+the diagonal (the backward's, under a window, at either edge of the
+band), so its dead part is not computed — and tiles below it run
+mask-free. Dots take their operands in the input's dtype (bf16 in, fp32
+accumulation; fp32 inputs keep fp32 dots) with p and ds cast down for
+the second dot of each pair; scores, softmax statistics, lse, delta and
+all accumulators stay fp32. No pass ever materializes a T x T tensor.
 
 Standard flash-attention recurrence (Dao et al. 2022, public algorithm);
 the kernel implementation is original. Falls back to the XLA reference
@@ -72,8 +72,8 @@ _VMEM_LIMIT = 64 * 2 ** 20
 _GROUPED_FUSED_BWD_VMEM_BUDGET = 96 * 2 ** 20
 _GROUPED_VMEM_LIMIT = 112 * 2 ** 20
 #: rows of q a forward tile is worked through at a time, and keys a
-#: backward tile ON the diagonal is: the grain at which a tile follows
-#: the diagonal (chip sweep, PERF.md §6 PR 27)
+#: backward tile is where an edge crosses it: the grain at which a tile
+#: follows the diagonal, or in the backward a window's edges (PERF.md §6)
 _FWD_SUB_ROWS = 256
 _BWD_SUB_KEYS = 128
 #: the same bounds under a window: a tile wider than the window computes
@@ -634,10 +634,10 @@ def _bwd_dkv_kernel(*refs, scale, causal, interior, fused, sub, window=None,
         elif rope is not None:
             q_ref[0] = _rotate(q_in[0], cos_q[...], sin_q[...],
                                rope).astype(q_ref.dtype)
-        # on a tile that sits ON the diagonal (bq == bk) a key sub-chunk
-        # is seen only from its own first row on: the dead sub-blocks
-        # are not computed
-        if masked and bq == bk and window is None:
+        if masked and _follows_edges(bq, bk, window):
+            _dkv_edge_tile(refs, rope, fused, q_idx, kv_idx, dq_block,
+                           scale=scale, sub=sub, window=window)
+        elif masked and bq == bk and window is None:    # ON the diagonal
             for first in range(0, bk, sub):
                 _chunk(True, slice(first, first + sub), first)
         else:
@@ -1014,10 +1014,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                              "channel %d for q/k of (T, D) = (%d, %d)"
                              % (tables[0].shape, start, T, D))
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
-    # block sizes are upper bounds; _pick_block turns each into a tile
-    # the TPU lowering accepts or declines (no 128-multiple divisor
-    # compiled, or prime-ish T with only tiny divisors) — a declined
-    # shape lowers the XLA formula instead, a static decision.
+    # bound -> tile (_pick_block); where the TPU lowering has none (no
+    # 128-multiple divisor, a prime-ish T) the call lowers the XLA formula
     fwd_bound, bwd_bound = ((_FWD_BLOCK, _BWD_BLOCK) if window is None
                             else (_WINDOW_FWD_BLOCK, _WINDOW_BWD_BLOCK))
     block_q = _pick_block(T, int(block_q or fwd_bound), interpret)
@@ -1030,6 +1028,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         out, lse = _dense_with_lse(q, k, v, causal=causal, scale=scale,
                                    window=window)
         return (out, lse) if return_lse else out
+    if _follows_edges(block_q_bwd, block_k_bwd, window):
+        counter("flash_attention.window_edges").inc()
     fused = _bwd_is_fused(T, D, block_q_bwd, block_k_bwd, q.dtype.itemsize,
                           Dv=Dv, group=group,
                           rope_half=tables[0].shape[1] if tables else None)
@@ -1302,3 +1302,82 @@ def _dense_with_lse(q, k, v, causal=False, scale=None, window=None):
     out = jnp.einsum("bhqk,bhkd->bhqd", w.astype(q.dtype), v,
                      precision="highest")
     return out, lse
+
+
+# The backward's tiles an edge of a window's band crosses, walked in
+# sub-chunks of that edge. This sits below the kernels: a kernel body
+# carries the source lines of its call stack, so lines moved above would
+# give every call without a window a new compile-cache key. It folds back
+# into _bwd_dkv_kernel's _chunk (a start and a stop for its queries) once
+# the kernels' locations leave that key (ROADMAP Speed 9e).
+
+def _follows_edges(bq, bk, window):
+    """The static rule under which a windowed backward walks its edge tiles
+    in sub-chunks: square tiles and a window of whole tiles. The far-edge
+    tile of q block ``i`` is then k block ``i - window // bk`` and its
+    diagonal tile k block ``i``; every live tile between is mask-free, so
+    every masked live tile is one of the two."""
+    return window is not None and bq == bk and window % bk == 0
+
+
+def _dkv_edge_tile(refs, rope, fused, q_idx, kv_idx, dq_block, *, scale,
+                   sub, window):
+    """A backward tile an edge of the band crosses (:func:`_follows_edges`),
+    transposed (keys by queries), ``sub`` keys at a time; ``refs``,
+    ``rope``, ``fused`` and the blocks as :func:`_bwd_dkv_kernel` has
+    them. On the diagonal, keys ``f .. f + sub`` are seen by the queries
+    ``f .. bq``; on the far edge only by the queries before them in the
+    tile, ``0 .. f + sub``. The mask is applied in each sub-chunk; the
+    sub-blocks wholly outside the band are not computed. Each sub-chunk
+    adds to dk, dv and, fused, its queries' rows of dq as the kernel's
+    ``_chunk`` does."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    rest = refs[6:]
+    if rope is not None:        # the tables first, the rotated q and k last
+        q_ref, k_ref = rest[-2:]
+        rest = rest[4:-2]
+    dk_acc, dv_acc, dq_acc = rest[-3:] if fused else (*rest[-2:], None)
+    bq, bk = do_ref.shape[1], k_ref.shape[1]
+    held = fused and rope is not None     # q_ref: the group's rotated q
+    if fused:
+        q_first = pl.multiple_of(dq_block * bq, bq)
+
+    def chunk(keys, first, stop):
+        """Keys ``keys`` (a slice of the tile's) against the tile's queries
+        ``first .. stop``."""
+        n = stop - first
+        q = (q_ref[pl.ds(q_first + first, n), :] if held
+             else q_ref[0, first:stop, :])
+        do, k = do_ref[0, first:stop, :], k_ref[0, keys, :]
+        s_t = jax.lax.dot_general(k, q, _NT,
+                                  preferred_element_type=jnp.float32) * scale
+        s_t = jnp.where(
+            _visible(q_idx * bq + first, kv_idx * bk + keys.start, n,
+                     keys.stop - keys.start, transposed=True, window=window),
+            s_t, -jnp.inf)
+        p_t = jnp.exp(s_t - lse_ref[0, :, first:stop])       # (1, .) row
+        dp_t = jax.lax.dot_general(v_ref[0, keys, :], do, _NT,
+                                   preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta_ref[0, :, first:stop])).astype(q.dtype)
+        dv_acc[keys, :] += jax.lax.dot_general(
+            p_t.astype(do.dtype), do, _NN,
+            preferred_element_type=jnp.float32)
+        dk_acc[keys, :] += jax.lax.dot_general(
+            ds_t, q, _NN, preferred_element_type=jnp.float32)
+        if fused:
+            dq_acc[pl.ds(q_first + first, n), :] += jax.lax.dot_general(
+                ds_t, k, _TN, preferred_element_type=jnp.float32)
+
+    @pl.when(kv_idx == q_idx)
+    def _diagonal():
+        for first in range(0, bk, sub):
+            chunk(slice(first, first + sub), first, bq)
+
+    @pl.when(kv_idx != q_idx)
+    def _far_edge():
+        for first in range(0, bk, sub):
+            chunk(slice(first, first + sub), 0, first + sub)

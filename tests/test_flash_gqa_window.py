@@ -2,7 +2,9 @@
 against the dense formula: out, lse and all three gradients, in the
 Pallas interpreter on float32. T = 64 in 16-wide tiles (or unequal ones),
 so tiles the diagonal crosses, tiles the band's far edge crosses, tiles
-between the two, and dead tiles on both sides all occur."""
+between the two, and dead tiles on both sides all occur. At square tiles
+and a window of whole tiles the two edge tiles are walked in sub-chunks
+of their edge (``_follows_edges``): windows of one tile and of two."""
 import importlib
 import math
 
@@ -12,6 +14,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu.observability as obs
+from test_tpu_lowering import _cell_step_lowered, as_on_the_chip  # noqa: F401
 
 fa = importlib.import_module("mxnet_tpu.parallel.flash_attention")
 F32 = jnp.float32
@@ -64,8 +67,8 @@ def _check(group, window, tiles, two_pass, monkeypatch, T=T):
 
 
 # window: none; smaller than T and a multiple of the tile; not a multiple
-# of the tile; one position; equal to T; larger than T
-WINDOWS = [None, 32, 23, 1, T, 3 * T]
+# of the tile; one position; equal to T; larger than T; one tile
+WINDOWS = [None, 32, 23, 1, T, 3 * T, 16]
 
 
 @pytest.mark.parametrize("two_pass", [False, True], ids=["fused", "two_pass"])
@@ -82,6 +85,32 @@ def test_a_window_under_unequal_tiles(tiles, two_pass, monkeypatch):
     """q and k tiles of different heights, and one tile a head: the band's
     live range is read off both tile heights."""
     _check(3, 20, tiles, two_pass, monkeypatch)
+
+
+@pytest.mark.parametrize("window", [16, 32])
+def test_rotated_edge_tiles_against_the_dense_formula(window):
+    """The rotation in the kernels (``rope``) with both edge tiles walked
+    in sub-chunks: the forward's held k read from a sub-chunk's first key,
+    the fused backward's held q from its first query, against the dense
+    formula on q and k rotated in XLA."""
+    from mxnet_tpu.parallel import lm_layers
+
+    q, k, v, do, dlse = _operands(3)
+    cos, sin = lm_layers.rope_tables(T, q.shape[-1], {"theta": 10000})
+    rope = (cos, sin)
+    scale = 1.1 / math.sqrt(q.shape[-1])
+    (_, (out, lse)), grads = _value_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, scale=scale, window=window, rope=rope,
+            block_q=16, block_k=16, block_q_bwd=16, block_k_bwd=16,
+            interpret=True, return_lse=True), q, k, v, do, dlse)
+    (_, (want_out, want_lse)), want = _value_and_grads(
+        lambda q, k, v: fa._dense_with_lse(
+            fa.rotated(q, rope), fa.rotated(k, rope), v, causal=True,
+            scale=scale, window=window), q, k, v, do, dlse)
+    assert rel(out, want_out) < 2e-5 and rel(lse, want_lse) < 2e-5
+    for got, ref, what in zip(grads, want, ("dq", "dk", "dv")):
+        assert rel(got, ref) < 5e-5, what
 
 
 def test_the_dense_oracle_masks_what_the_equations_say():
@@ -123,6 +152,46 @@ def test_the_counters_count_windowed_calls_and_group_sizes():
     fa.flash_attention(q[:, :2], k, v, window=8, **kw)  # windowed, group 1
     assert read("windowed") - before[0] == 2
     assert read("kv_group") - before[1] == 18
+
+
+def test_window_edges_counts_the_calls_that_walk_their_edges():
+    """``flash_attention.window_edges``: a windowed call whose backward
+    has square tiles and a window of whole tiles, and no other."""
+    obs.set_enabled(True)
+    read = lambda: obs.metrics.get_value("flash_attention.window_edges", 0)
+    q, k, v, _, _ = _operands(2)
+
+    def counted(window, bq, bk, bwd=None):
+        before = read()
+        bq_bwd, bk_bwd = bwd or (bq, bk)
+        fa.flash_attention(q, k, v, causal=True, window=window, block_q=bq,
+                           block_k=bk, block_q_bwd=bq_bwd, block_k_bwd=bk_bwd,
+                           interpret=True)
+        return read() - before
+
+    assert counted(16, 16, 16) == 1 and counted(32, 16, 16) == 1
+    # the backward's tiles decide: the forward keeps its whole tiles
+    assert counted(32, 32, 16, bwd=(16, 16)) == 1
+    assert counted(32, 16, 16, bwd=(32, 16)) == 0
+    assert counted(23, 16, 16) == 0                 # not whole tiles
+    assert counted(20, 32, 16) == 0 and counted(32, 32, 16) == 0  # unequal
+    assert counted(8, 16, 16) == 0                  # under one tile
+    assert counted(None, 16, 16) == 0 and counted(T, 16, 16) == 0
+    assert fa._follows_edges(512, 512, 512)         # laguna, phi4flash
+    assert not fa._follows_edges(512, 512, None)
+
+
+def test_a_windowed_cells_step_walks_the_edges_of_every_window(
+        as_on_the_chip):
+    """The whole train step of ``laguna_train_t8192_b2``, lowered for the
+    TPU at its own sizes (512-wide tiles, window 512): one count for each
+    of its three sliding layers (phi4flash's step is counted where
+    ``test_tpu_lowering`` lowers it)."""
+    obs.set_enabled(True)
+    before = obs.metrics.get_value("flash_attention.window_edges", 0)
+    _cell_step_lowered("laguna_train_t8192_b2")
+    assert obs.metrics.get_value(
+        "flash_attention.window_edges", 0) - before == 3
 
 
 def test_the_kernels_of_a_windowed_call_hold_window_in_their_names(

@@ -291,13 +291,20 @@ def _cell_step_lowered(cell):
         lowering_platforms=("tpu",)).as_text()
 
 
-def test_the_state_space_cells_step_lowers_for_tpu(as_on_the_chip):
+def test_the_state_space_cells_step_lowers_for_tpu(as_on_the_chip,
+                                                    monkeypatch):
     """``phi4flash_train_t8192_b1`` at 8,192 tokens and the published
     widths: the scan's kernels in both passes (three state-space layers),
     the differential layers' flash calls forward and backward, causal and
-    windowed, and no array of a state a token (8192 x 5120 x 16) anywhere
-    in the step."""
+    windowed, each windowed call's backward walking the band's edges in
+    sub-chunks (two windowed layers of two calls), and no array of a
+    state a token (8192 x 5120 x 16) anywhere in the step."""
+    from mxnet_tpu.observability import metrics
+
+    monkeypatch.setattr(metrics, "_enabled", True)
+    edges = metrics.get_value("flash_attention.window_edges", 0)
     text = _cell_step_lowered("phi4flash_train_t8192_b1")
+    assert metrics.get_value("flash_attention.window_edges", 0) - edges == 4
     names = re.findall(r'kernel_name = "([^"]*)"', text)
     # once a state-space layer forward, and not again in its recomputation
     # (the layer keeps the scan's output and its chunk states by name)

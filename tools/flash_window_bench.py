@@ -24,7 +24,8 @@ scaled by ``attention_factor``), its sliding layer (64 over 8, window
 (the backward kernel's) ms of both, and whether the call's backward is
 the fused pass that holds the rotated q (``held_bwd``); with ``--check``
 out and the gradients with respect to the UNROTATED q, k, v of both
-against the dense formula in float32 (two k/v heads' groups)."""
+against the dense formula in float32 (two k/v heads' groups). ``--only``
+names the shapes to run, ``--paths`` the paths."""
 import argparse
 import importlib
 import json
@@ -44,6 +45,8 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--rope", action="store_true")
+    ap.add_argument("--only", nargs="+", default=None)
+    ap.add_argument("--paths", nargs="+", default=("held", "xla"))
     args = ap.parse_args(argv)
     if args.rope:
         return rope_main(args)
@@ -233,9 +236,12 @@ def rope_main(args):
         return sorted(times)[len(times) // 2]
 
     for name, B, H, Hkv, D, Dv, start, rot, window, block in shapes:
+        if args.only and name not in args.only:
+            continue
         cos, sin = lm.rope_tables(T, rot, block)
         rope = (cos, sin, start)
-        fns = paths(rope, window)
+        fns = {path: fn for path, fn in paths(rope, window).items()
+               if path in args.paths}
         if args.check:
             G = H // Hkv
             q, k, v, do = operands(1, 2 * G, 2, D, Dv, seed=1)
